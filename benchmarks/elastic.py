@@ -16,10 +16,11 @@ transition forced through rung 2 with an injected resize poison) so the
 ladder's two recovery paths are directly comparable.
 
 Prints one JSON document {"runs": [...]} to stdout; progress lines go to
-stderr.  Spawned by ``benchmarks/run.py --only elastic`` with 8 forced
-host devices (same harness note as benchmarks/scaling.py: forced host
-devices share one CPU, so steps/sec validates the path and the overhead
-trend, not real-hardware scaling).
+stderr.  ``benchmarks/run.py --only elastic`` calls ``run`` in its own
+process; on the CPU the caller's environment supplies forced host devices
+(same harness note as benchmarks/scaling.py: forced host devices share one
+CPU, so steps/sec validates the path and the overhead trend, not
+real-hardware scaling).
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m benchmarks.elastic [--quick]
@@ -66,17 +67,8 @@ def _steps_per_s(fn, state, pipe, mesh, worker, start, n_supersteps):
     return state, s, (n_supersteps * SUPERSTEP) / dt
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--quick", action="store_true")
-    args = ap.parse_args()
-    n_meas = 3 if args.quick else 10
-
-    # the parent parses this process's ENTIRE stdout as one JSON document,
-    # but the ResizeController/CheckpointManager narrate to stdout — route
-    # everything through stderr and keep the real stdout for the payload
-    payload_out = sys.stdout
-    sys.stdout = sys.stderr
+def run(quick: bool = False) -> dict:
+    n_meas = 3 if quick else 10
 
     import repro.configs as C
     from repro.checkpoint.manager import CheckpointManager
@@ -87,8 +79,9 @@ def main():
     from repro.launch.mesh import make_host_mesh
     from repro.train.step import (init_worker_state, make_optimizer,
                                   make_worker_superstep)
-    from benchmarks.scaling import build_worker_cell
+    from benchmarks.scaling import build_worker_cell, require_devices
 
+    require_devices(4)
     cfg = C.get("chaos-small")
     sync = SyncConfig("bsp", axis_name="workers")
     opt = make_optimizer(cfg, total_steps=512)
@@ -146,10 +139,22 @@ def main():
         transitions([4, 2], "ckpt-restore",
                     fault=FaultPlan.from_spec("resizefail@0"), ckpt_dir=d)
 
-    print(json.dumps({"runs": runs, "batch": BATCH,
-                      "superstep": SUPERSTEP,
-                      "logical_shards": LOGICAL_SHARDS}),
-          file=payload_out, flush=True)
+    return {"runs": runs, "batch": BATCH, "superstep": SUPERSTEP,
+            "logical_shards": LOGICAL_SHARDS}
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--quick", action="store_true")
+    args = ap.parse_args()
+    # stdout carries one JSON document, but the ResizeController and
+    # CheckpointManager narrate to stdout — route them to stderr
+    payload_out, sys.stdout = sys.stdout, sys.stderr
+    try:
+        doc = run(args.quick)
+    finally:
+        sys.stdout = payload_out
+    print(json.dumps(doc), flush=True)
 
 
 if __name__ == "__main__":

@@ -58,8 +58,9 @@ def apply_flash_kernel_adjustment(info, arch, shape_name):
     r = dict(info["roofline"])
     r["bytes_per_dev"] = max(r["bytes_per_dev"] - d_bytes, 0.0)
     r["flops_per_dev"] = max(r["flops_per_dev"] - d_flops, 0.0)
-    r["memory_s"] = r["bytes_per_dev"] / RL.HBM_BW
-    r["compute_s"] = r["flops_per_dev"] / RL.PEAK_FLOPS
+    pk = RL.peaks(RL.V5E)     # the dry run models a v5e pod
+    r["memory_s"] = r["bytes_per_dev"] / pk.hbm_bw
+    r["compute_s"] = r["flops_per_dev"] / pk.flops
     terms = {"compute": r["compute_s"], "memory": r["memory_s"],
              "collective": r["collective_s"]}
     r["dominant"] = max(terms, key=terms.get)

@@ -25,9 +25,9 @@ bytes the collective analysis says move.
 Grid: Table-2 nets × workers ∈ {1, 2, 4} × delay ∈ {0} ∪ DELAYS ×
 both schedules, layerwise bsp + plain SGD (the paper's update rule).
 Prints one JSON document (stdout); progress goes to stderr.  Must run with
-enough visible devices — the parent (``benchmarks/run.py --only overlap``)
-spawns this module with ``XLA_FLAGS=--xla_force_host_platform_device_
-count=8``:
+enough visible devices; ``benchmarks/run.py --only overlap`` calls ``run``
+in its own process, and on the CPU the caller's environment supplies
+forced host devices:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m benchmarks.overlap [--quick]
@@ -47,8 +47,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-import jax
 
 BATCH = 8          # global batch (fixed logical_shards=8 micro-shards)
 SUPERSTEP = 4      # K steps per dispatch
@@ -111,13 +109,8 @@ def measure(net: str, n_workers: int, interleave: bool, delay: float,
     }
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--quick", action="store_true",
-                    help="CI smoke: chaos-small, workers {1,2}, one delay")
-    args = ap.parse_args()
-
-    if args.quick:
+def run(quick: bool = False) -> dict:
+    if quick:
         nets = ["chaos-small"]
         worker_counts = [1, 2]
         delays = QUICK_DELAYS
@@ -131,12 +124,8 @@ def main():
         net_measured = {"chaos-small": 6, "chaos-medium": 2,
                         "chaos-large": 2}
 
-    n_dev = len(jax.devices())
-    if max(worker_counts) > n_dev:
-        print(f"error: need {max(worker_counts)} devices, have {n_dev}; "
-              f"set XLA_FLAGS=--xla_force_host_platform_device_count="
-              f"{max(worker_counts)}", file=sys.stderr)
-        sys.exit(2)
+    from benchmarks.scaling import require_devices
+    require_devices(max(worker_counts))
 
     runs = []
     for net in nets:
@@ -176,7 +165,15 @@ def main():
                           f"exchange={r['exchange_us']:.0f}us "
                           f"predicted={r['predicted_exchange_us']}",
                           file=sys.stderr, flush=True)
-    json.dump({"runs": runs}, sys.stdout)
+    return {"runs": runs}
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--quick", action="store_true",
+                    help="CI smoke: chaos-small, workers {1,2}, one delay")
+    args = ap.parse_args()
+    json.dump(run(args.quick), sys.stdout)
     print(flush=True)
 
 

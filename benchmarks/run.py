@@ -15,6 +15,7 @@ import json
 import os
 import sys
 import time
+import traceback
 
 import jax
 import jax.numpy as jnp
@@ -38,27 +39,6 @@ def _timeit(fn, *args, n=10, warmup=2):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / n * 1e6  # us
-
-
-_KERNEL_OK = None
-
-
-def _kernel_path_available():
-    """Probe the Pallas interpret path once (tiny conv launch): on hosts
-    where ``jax.experimental.pallas`` is missing or broken, the kernel
-    sections/cells skip with an actionable row instead of erroring the
-    harness — the XLA rows still measure."""
-    global _KERNEL_OK
-    if _KERNEL_OK is None:
-        try:
-            from repro.kernels import ops as kops
-            jax.block_until_ready(kops.conv2d_valid(
-                jnp.zeros((1, 6, 6, 1), jnp.float32),
-                jnp.zeros((3, 3, 1, 2), jnp.float32)))
-            _KERNEL_OK = (True, "")
-        except Exception as e:  # noqa: BLE001 — any failure means "skip"
-            _KERNEL_OK = (False, repr(e)[:200])
-    return _KERNEL_OK
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +120,7 @@ def bench_sync_modes(quick=False):
 
 # ---------------------------------------------------------------------------
 # Kernel microbenchmarks (paper Listing 1: vectorised conv loops) — tuned
-# vs the hard-coded batch_block=8 whole-map baseline, per Table-2 net
+# vs the heuristic default block config, per Table-2 net
 # ---------------------------------------------------------------------------
 # (B, H, W, Cin, K, Cout) conv shapes of the paper's three Table-2 nets
 NET_CONV_SHAPES = {
@@ -151,13 +131,6 @@ NET_CONV_SHAPES = {
 
 
 def bench_kernels(quick=False):
-    ok, why = _kernel_path_available()
-    if not ok:
-        row("kernel/SKIPPED", 0.0,
-            f"pallas_unavailable_{why[:80]}_install_jax_with_pallas_or_"
-            f"set_REPRO_PALLAS_INTERPRET=1")
-        return {"skipped": True, "reason": why}
-
     from repro.kernels import autotune as AT
     from repro.kernels import conv2d as CK
     from repro.kernels import ops as kops
@@ -194,8 +167,8 @@ def bench_kernels(quick=False):
                 f"{rep['baseline_us'] / rep['best_us']:.2f}x_cfg={cfg}")
             row(f"kernel/bwd_fused/{shp}/default", brep["baseline_us"],
                 f"vs_tuned_{brep['baseline_us'] / brep['best_us']:.2f}x")
-            # best_us <= baseline_us by construction: the batch_block=8
-            # baseline is always in the measured candidate set
+            # best_us <= baseline_us by construction: the heuristic
+            # default is always in the measured candidate set
             detail.append({
                 "net": net,
                 "shape": [B, H, W, Cin, K, Cout],
@@ -311,10 +284,6 @@ def bench_train(quick=False):
     imgs, labels = make_dataset(512, seed=0)
     detail = []
     kernel_modes = (False, True)
-    ok, why = _kernel_path_available()
-    if not ok:
-        row("train/kernel_SKIPPED", 0.0, f"pallas_unavailable_{why[:80]}")
-        kernel_modes = (False,)
     for net in nets:
         base_cfg = C.get(net)
         for use_kernel in kernel_modes:
@@ -369,12 +338,11 @@ def bench_train(quick=False):
 
 # ---------------------------------------------------------------------------
 # Result 3 / Tables 7-9 measured analogue: CHAOS worker scaling.  Runs the
-# worker-mesh superstep path (shard_map over forced host devices) for the
-# three Table-2 nets x 3 sync modes x workers {1,2,4,8} x kernels on/off in
-# ONE subprocess (XLA_FLAGS must be set before jax initialises), then puts
-# measured speedup next to the paper's performance-model prediction.
+# worker-mesh superstep path (shard_map over the visible devices) for the
+# three Table-2 nets x 3 sync modes x workers {1,2,4,8} x kernels on/off,
+# then puts measured speedup next to the paper's performance-model
+# prediction.  The grids run in this process: a chip belongs to one process.
 # ---------------------------------------------------------------------------
-SCALING_DEVICES = 8
 PAPER_ARCH = {"chaos-small": "small", "chaos-medium": "medium",
               "chaos-large": "large"}
 
@@ -397,38 +365,9 @@ def _model_speedup(r: dict) -> float:
     return pm.predict_speedup(key, r["workers"])
 
 
-def _run_grid_subprocess(module: str, quick: bool) -> list:
-    """Run a worker-mesh benchmark module in its own process with
-    ``SCALING_DEVICES`` forced host devices (XLA_FLAGS must be set before
-    jax initialises) and return its ``runs`` list.  stdout (the JSON
-    document) is captured; stderr is inherited so per-cell progress lines
-    stream live — a full grid runs for a long time and silent buffering
-    would hide all progress."""
-    import re
-    import subprocess
-
-    env = dict(os.environ)
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                   env.get("XLA_FLAGS", "")).strip()
-    env["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_device_count="
-                        f"{SCALING_DEVICES}").strip()
-    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", module]
-    if quick:
-        cmd.append("--quick")
-    out = subprocess.run(cmd, stdout=subprocess.PIPE, text=True, env=env,
-                         cwd=os.path.join(os.path.dirname(__file__), ".."),
-                         timeout=14000)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"{module} subprocess failed with rc={out.returncode} "
-            f"(its stderr streamed above)")
-    return json.loads(out.stdout)["runs"]
-
-
 def bench_scaling(quick=False):
-    runs = _run_grid_subprocess("benchmarks.scaling", quick)
+    from benchmarks import scaling
+    runs = scaling.run(quick)["runs"]
     base = {(r["net"], r["mode"], r["use_kernel"]): r["steps_per_s"]
             for r in runs if r["workers"] == 1}
     for r in runs:
@@ -446,7 +385,6 @@ def bench_scaling(quick=False):
             f"{r['speedup_vs_1']:.2f}x_model={r['model_speedup']:.2f}x")
     return {"runs": runs, "batch": runs[0]["batch"] if runs else None,
             "superstep": runs[0]["superstep"] if runs else None,
-            "forced_devices": SCALING_DEVICES,
             "note": "forced host devices share one CPU; speedup_vs_1 "
                     "validates the worker path + overhead trend, "
                     "model_speedup is the paper's Listing-2 prediction "
@@ -462,7 +400,8 @@ def bench_scaling(quick=False):
 # model prediction per worker count.
 # ---------------------------------------------------------------------------
 def bench_staleness(quick=False):
-    runs = _run_grid_subprocess("benchmarks.staleness", quick)
+    from benchmarks import staleness
+    runs = staleness.run(quick)["runs"]
     # baselines are keyed WITHIN a layerwise flavour (τ=0 layerwise bsp is
     # the layerwise rows' synchronous baseline); speedup_vs_batched then
     # compares each layerwise row against its batched twin — the
@@ -493,7 +432,7 @@ def bench_staleness(quick=False):
             f"{r['steps_per_s']:.1f}steps_per_s_err={r['final_error']:.4f}"
             f"_derr={r['error_delta_vs_tau0']:+.4f}"
             f"_speedup_tau0={r['speedup_vs_tau0']:.2f}x")
-    return {"runs": runs, "forced_devices": SCALING_DEVICES,
+    return {"runs": runs,
             "note": "tau=0 IS bsp (the chaos strategy resolves to the bsp "
                     "object at staleness 0); error columns are hardware-"
                     "independent; forced host devices share one CPU, so "
@@ -507,14 +446,15 @@ def bench_staleness(quick=False):
 # (DESIGN.md §7; CI's preemption-injection smoke uploads this section)
 # ---------------------------------------------------------------------------
 def bench_elastic(quick=False):
-    runs = _run_grid_subprocess("benchmarks.elastic", quick)
+    from benchmarks import elastic
+    runs = elastic.run(quick)["runs"]
     for r in runs:
         row(f"elastic/{r['label']}/{r['from']}to{r['to']}",
             r["latency_s"] * 1e6,
             f"path={r['path']}_first_superstep="
             f"{r['first_superstep_s'] * 1e3:.0f}ms_steps_per_s="
             f"{r['steps_per_s_before']:.1f}->{r['steps_per_s_after']:.1f}")
-    return {"runs": runs, "forced_devices": SCALING_DEVICES,
+    return {"runs": runs,
             "note": "latency_s is the re-slot + rebuild cost from "
                     "ResizeOutcome; the first post-resize superstep "
                     "carries the recompile and is reported separately; "
@@ -529,7 +469,8 @@ def bench_elastic(quick=False):
 # roofline collective-bytes model as the predicted blocking cost.
 # ---------------------------------------------------------------------------
 def bench_overlap(quick=False):
-    runs = _run_grid_subprocess("benchmarks.overlap", quick)
+    from benchmarks import overlap
+    runs = overlap.run(quick)["runs"]
     base = {(r["net"], r["workers"], r["schedule"]): r["us_per_step"]
             for r in runs if r["delay_ns_per_byte"] == 0}
     collect = {(r["net"], r["workers"], r["delay_ns_per_byte"]): r
@@ -566,7 +507,7 @@ def bench_overlap(quick=False):
             f"exchange={r['exchange_us']:.0f}us"
             f"_roofline={r['exchange_vs_roofline']:.2f}x"
             f"_vs_collect={r['speedup_vs_collect']:.2f}x")
-    return {"runs": runs, "forced_devices": SCALING_DEVICES,
+    return {"runs": runs,
             "note": "layerwise bsp+SGD worker path; exchange_us = "
                     "us_per_step minus the same schedule's delay-0 cell; "
                     "predicted_exchange_us = compiled-HLO effective "
@@ -604,19 +545,8 @@ def bench_roofline(quick=False):
 # Serving: continuous batching under a Poisson trace (DESIGN.md §9)
 # ---------------------------------------------------------------------------
 def bench_serve(quick=False):
-    import subprocess
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", "benchmarks.serve"]
-    if quick:
-        cmd.append("--quick")
-    out = subprocess.run(cmd, stdout=subprocess.PIPE, text=True, env=env,
-                         cwd=os.path.join(os.path.dirname(__file__), ".."),
-                         timeout=7200)
-    if out.returncode != 0:
-        raise RuntimeError(f"benchmarks.serve failed rc={out.returncode}")
-    doc = json.loads(out.stdout)
+    from benchmarks import serve
+    doc = serve.run(quick)
     for r in doc["runs"]:
         sampling = r.get("sampling", "greedy")
         name = (f"serve/{r['arch']}/{r['mode']}"
@@ -637,9 +567,12 @@ def bench_serve(quick=False):
 
 
 def _write_section_json(out_dir, section, rows, extra, quick):
+    dev = jax.devices()[0]
     payload = {
         "section": section,
         "backend": jax.default_backend(),
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
         "quick": bool(quick),
         "timestamp": time.time(),
         "rows": [{"name": n, "us_per_call": us, "derived": d}
@@ -685,10 +618,11 @@ def main():
     ap.add_argument("--trace-out", default=None,
                     help="attach the obs tracer (DESIGN.md §11) and write a "
                          "Perfetto trace.json of the harness run here: one "
-                         "span per section plus whatever in-process cells "
-                         "emit (subprocess grids trace separately)")
+                         "span per section plus whatever its cells emit")
     args = ap.parse_args()
     os.makedirs(args.out, exist_ok=True)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     tracer = None
     if args.trace_out:
         from repro.obs import Tracer
@@ -696,6 +630,7 @@ def main():
         tracer = Tracer("bench")
         obs_trace.set_tracer(tracer)
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in SECTIONS.items():
         if args.only and name != args.only:
             continue
@@ -706,14 +641,17 @@ def main():
                     extra = fn(quick=args.quick)
             else:
                 extra = fn(quick=args.quick)
-        except Exception as e:  # keep the harness robust
-            extra = {"error": repr(e)[:500]}
-            row(f"{name}/ERROR", 0.0, repr(e)[:120])
+        except Exception:  # run the other sections, then fail
+            traceback.print_exc()
+            failed.append(name)
+            continue
         _write_section_json(args.out, name, ROWS[start:], extra, args.quick)
     if tracer is not None:
         from repro.obs import trace as obs_trace
         obs_trace.set_tracer(None)
         tracer.write(args.trace_out)
+    if failed:
+        sys.exit(f"benchmark section(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

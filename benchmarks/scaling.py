@@ -4,11 +4,10 @@ Runs the worker-mesh superstep path end-to-end for the three Table-2 nets
 x sync modes x worker counts x Pallas kernels on/off, and prints one JSON
 document (stdout) with steps/sec per cell; progress goes to stderr.
 
-MUST run with enough visible devices for the largest worker count — the
-parent (``benchmarks/run.py --only scaling``) spawns this module with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so meshes of
-1/2/4/8 workers can all be built from one process (``make_host_mesh(n)``
-takes the first n devices).
+MUST run with enough visible devices for the largest worker count
+(``make_host_mesh(n)`` takes the first n devices).  ``benchmarks/run.py
+--only scaling`` calls ``run`` in its own process; on the CPU the caller's
+environment supplies forced host devices, e.g.
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m benchmarks.scaling [--quick]
@@ -154,19 +153,86 @@ def measure(net: str, mode: str, n_workers: int, use_kernel: bool,
     return r
 
 
-def kernel_path_ok():
-    """Probe the Pallas interpret path with one tiny launch: on hosts
-    where ``jax.experimental.pallas`` is missing or broken the kernel
-    cells skip with a stderr note instead of failing the whole grid."""
-    try:
-        from repro.kernels import ops as kops
-        import jax.numpy as jnp
-        jax.block_until_ready(kops.conv2d_valid(
-            jnp.zeros((1, 6, 6, 1), jnp.float32),
-            jnp.zeros((3, 3, 1, 2), jnp.float32)))
-        return True, ""
-    except Exception as e:  # noqa: BLE001 — any failure means "skip"
-        return False, repr(e)[:200]
+def require_devices(need: int) -> None:
+    """Fail a grid that needs more devices than are visible, with advice."""
+    from repro.launch.mesh import cpu_device_hint
+    devs = jax.devices()
+    if need > len(devs):
+        raise RuntimeError(
+            f"this grid needs {need} devices, {len(devs)} "
+            f"{devs[0].platform} device(s) are visible"
+            + cpu_device_hint(need))
+
+
+def run(quick: bool = False, modes=("bsp", "chaos", "localsgd"),
+        nets=None) -> dict:
+    """Measure the grid; ``nets`` keeps a subset of the nets."""
+    if quick:
+        nets_all = ["chaos-small", "lm-bench"]
+        worker_counts = {"chaos-small": [1, 4], "lm-bench": [2]}
+        kernel_modes = {"chaos-small": [False], "lm-bench": [False, True]}
+        lm_modes = ("chaos",)
+    else:
+        nets_all = ["chaos-small", "chaos-medium", "chaos-large", "lm-bench"]
+        worker_counts = {net: [1, 2, 4, 8] for net in nets_all}
+        worker_counts["lm-bench"] = list(LM_WORKERS)
+        kernel_modes = {net: [False, True] for net in nets_all}
+        lm_modes = LM_MODES
+    if nets:
+        nets_all = [n for n in nets_all if n in set(nets)]
+    nets = nets_all
+    # measured supersteps per cell, scaled to per-step cost (the K-step
+    # superstep amortization already smooths dispatch noise)
+    net_measured = {"chaos-small": 4, "chaos-medium": 2, "chaos-large": 1,
+                    "lm-bench": 4}
+
+    require_devices(max(max(worker_counts[n]) for n in nets))
+
+    if any(True in kernel_modes[n] for n in nets):
+        # populate the per-shard autotune keys (batch/logical_shards = 1)
+        # the sharded kernel path looks up at EVERY worker count (the
+        # worker route always runs kernels at per-shard batch, N=1 included)
+        import repro.configs as C
+        from repro.core.types import WorkerConfig
+        from repro.kernels import autotune as AT
+        from repro.kernels.ops import _interpret
+        for net in nets:
+            if True not in kernel_modes[net]:
+                continue
+            cfg = C.get(net)
+            if cfg.family == "cnn":
+                shard_batch = BATCH // WorkerConfig().logical_shards
+                print(f"# tuning per-shard kernels for {net} "
+                      f"(batch {shard_batch})", file=sys.stderr, flush=True)
+                AT.tune_cnn_net(cfg, shard_batch, iters=1,
+                                interpret=_interpret())
+            else:
+                shard_batch = LM_BATCH // LM_SHARDS
+                print(f"# tuning per-shard flash attention for {net} "
+                      f"(batch {shard_batch}, seq {LM_SEQ})",
+                      file=sys.stderr, flush=True)
+                AT.tune_lm_attention(cfg, shard_batch, LM_SEQ, iters=1,
+                                     interpret=_interpret())
+
+    runs = []
+    for net in nets:
+        for use_kernel in kernel_modes[net]:
+            for mode in modes:
+                if net == "lm-bench" and mode not in lm_modes:
+                    continue
+                for n in worker_counts[net]:
+                    m = 1 if quick else net_measured[net]
+                    if use_kernel and net != "lm-bench":
+                        # interpret-mode CNN kernels are 10-100x the XLA
+                        # step; the flash LM step is cheap — keep its full
+                        # measured window (short windows are noise-bound)
+                        m = min(m, 2)
+                    r = measure(net, mode, n, use_kernel, m)
+                    runs.append(r)
+                    print(f"# {net} {mode} kernel={int(use_kernel)} "
+                          f"N={n}: {r['steps_per_s']:.2f} steps/s",
+                          file=sys.stderr, flush=True)
+    return {"runs": runs}
 
 
 def main():
@@ -187,84 +253,8 @@ def main():
                          "with benchmarks/merge_scaling.py)")
     args = ap.parse_args()
     modes = tuple(m for m in args.modes.split(",") if m)
-
-    if args.quick:
-        nets = ["chaos-small", "lm-bench"]
-        worker_counts = {"chaos-small": [1, 4], "lm-bench": [2]}
-        kernel_modes = {"chaos-small": [False], "lm-bench": [False, True]}
-        lm_modes = ("chaos",)
-    else:
-        nets = ["chaos-small", "chaos-medium", "chaos-large", "lm-bench"]
-        worker_counts = {net: [1, 2, 4, 8] for net in nets}
-        worker_counts["lm-bench"] = list(LM_WORKERS)
-        kernel_modes = {net: [False, True] for net in nets}
-        lm_modes = LM_MODES
-    if args.nets:
-        keep = {n for n in args.nets.split(",") if n}
-        nets = [n for n in nets if n in keep]
-    # measured supersteps per cell, scaled to per-step cost (the K-step
-    # superstep amortization already smooths dispatch noise)
-    net_measured = {"chaos-small": 4, "chaos-medium": 2, "chaos-large": 1,
-                    "lm-bench": 4}
-
-    n_dev = len(jax.devices())
-    need = max(max(worker_counts[n]) for n in nets)
-    if need > n_dev:
-        print(f"error: need {need} devices, have {n_dev}; "
-              f"set XLA_FLAGS=--xla_force_host_platform_device_count="
-              f"{need}", file=sys.stderr)
-        sys.exit(2)
-
-    if any(True in kernel_modes[n] for n in nets):
-        ok, why = kernel_path_ok()
-        if not ok:
-            print(f"# kernel path unavailable ({why}); dropping kernel "
-                  f"cells — XLA rows still measured", file=sys.stderr,
-                  flush=True)
-            kernel_modes = {n: [False] for n in nets}
-
-    if any(True in kernel_modes[n] for n in nets):
-        # populate the per-shard autotune keys (batch/logical_shards = 1)
-        # the sharded kernel path looks up at EVERY worker count (the
-        # worker route always runs kernels at per-shard batch, N=1 included)
-        import repro.configs as C
-        from repro.core.types import WorkerConfig
-        from repro.kernels import autotune as AT
-        for net in nets:
-            if True not in kernel_modes[net]:
-                continue
-            cfg = C.get(net)
-            if cfg.family == "cnn":
-                shard_batch = BATCH // WorkerConfig().logical_shards
-                print(f"# tuning per-shard kernels for {net} "
-                      f"(batch {shard_batch})", file=sys.stderr, flush=True)
-                AT.tune_cnn_net(cfg, shard_batch, iters=1)
-            else:
-                shard_batch = LM_BATCH // LM_SHARDS
-                print(f"# tuning per-shard flash attention for {net} "
-                      f"(batch {shard_batch}, seq {LM_SEQ})",
-                      file=sys.stderr, flush=True)
-                AT.tune_lm_attention(cfg, shard_batch, LM_SEQ, iters=1)
-
-    runs = []
-    for net in nets:
-        for use_kernel in kernel_modes[net]:
-            for mode in modes:
-                if net == "lm-bench" and mode not in lm_modes:
-                    continue
-                for n in worker_counts[net]:
-                    m = 1 if args.quick else net_measured[net]
-                    if use_kernel and net != "lm-bench":
-                        # interpret-mode CNN kernels are 10-100x the XLA
-                        # step; the flash LM step is cheap — keep its full
-                        # measured window (short windows are noise-bound)
-                        m = min(m, 2)
-                    r = measure(net, mode, n, use_kernel, m)
-                    runs.append(r)
-                    print(f"# {net} {mode} kernel={int(use_kernel)} "
-                          f"N={n}: {r['steps_per_s']:.2f} steps/s",
-                          file=sys.stderr, flush=True)
-    json.dump({"runs": runs}, sys.stdout)
+    nets = [n for n in args.nets.split(",") if n] if args.nets else None
+    json.dump(run(args.quick, modes, nets), sys.stdout)
     print(flush=True)
 
 

@@ -18,8 +18,8 @@ Each engine is warmed by replaying the trace once untimed, so every
 (A, T) prefill bucket and the decode program are compiled before timing.
 
 Prints one JSON document {"runs": [...], "roofline": {...}} to stdout;
-progress lines go to stderr.  Spawned by ``benchmarks/run.py --only
-serve``.
+progress lines go to stderr.  ``benchmarks/run.py --only serve`` calls
+``run`` in its own process.
 
     PYTHONPATH=src python -m benchmarks.serve [--quick]
 """
@@ -93,6 +93,7 @@ def _bench_mode(arch, mode, trace, slots, max_seq, seed,
 
 def _roofline_row(eng, arch):
     """Analytic bound for ONE decode dispatch of the warmed engine."""
+    import jax
     import jax.numpy as jnp
     from repro.core import roofline
 
@@ -103,39 +104,38 @@ def _roofline_row(eng, arch):
     compiled = eng._decode.lower(eng.params, eng.kv.tree, toks, cur,
                                  rids, poss).compile()
     n_active_params = eng.cfg.active_param_count()
-    rl = roofline.analyze(compiled, n_devices=1,
+    # on the CPU the bound models the v5e this engine is built for
+    kind = (roofline.V5E if jax.default_backend() == "cpu"
+            else jax.devices()[0].device_kind)
+    rl = roofline.analyze(compiled, n_devices=1, device_kind=kind,
                           model_flops_total=2.0 * n_active_params
                           * eng.kv.slots)
     return {"arch": arch, "decode_bound_s": rl.bound_s,
             "dominant": rl.dominant,
             "flops_per_dispatch": rl.flops,
             "bytes_per_dispatch": rl.bytes_accessed,
-            "note": "bound uses TPU v5e roofs; sanity contract is "
-                    "measured_p50 >= bound on every backend"}
+            "device_kind": kind,
+            "note": "bound uses the roofs of device_kind (a v5e when run "
+                    "on the CPU); sanity contract is measured_p50 >= "
+                    "bound on every backend"}
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--quick", action="store_true")
-    ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
-
+def run(quick: bool = False, seed: int = 0) -> dict:
     from repro.serve.engine import poisson_trace
     import repro.configs as C
 
-    slots, requests, gen = (2, 4, 4) if args.quick else (4, 10, 8)
-    prompt_lens = (4, 12) if args.quick else (6, 24)
+    slots, requests, gen = (2, 4, 4) if quick else (4, 10, 8)
+    prompt_lens = (4, 12) if quick else (6, 24)
     max_seq = 64
     runs, roofline_info = [], {}
     for arch in ARCHS:
         cfg = C.smoke(arch)
-        trace = poisson_trace(args.seed, requests, rate=1.0,
+        trace = poisson_trace(seed, requests, rate=1.0,
                               vocab=cfg.vocab_size,
                               prompt_lens=prompt_lens, max_new=gen)
         per_mode = {}
         for mode in ("batched", "loop"):
-            r, eng = _bench_mode(arch, mode, trace, slots, max_seq,
-                                 args.seed)
+            r, eng = _bench_mode(arch, mode, trace, slots, max_seq, seed)
             per_mode[mode] = r
             runs.append(r)
             _log(f"[serve-bench] {arch}/{mode}: "
@@ -161,14 +161,22 @@ def main():
         # sampling fused into the decode dispatch (cost of sampling =
         # this row vs the greedy batched row)
         rs, _ = _bench_mode(arch, "batched", trace, slots, max_seq,
-                            args.seed, temperature=0.8, top_p=0.9)
+                            seed, temperature=0.8, top_p=0.9)
         rs["sampling_overhead_vs_greedy"] = (
             b["tokens_per_s"] / rs["tokens_per_s"])
         runs.append(rs)
         _log(f"[serve-bench] {arch}: sampled decode "
              f"{rs['tokens_per_s']:.1f} tok/s "
              f"(x{rs['sampling_overhead_vs_greedy']:.2f} vs greedy)")
-    print(json.dumps({"runs": runs, "roofline": roofline_info}, indent=1))
+    return {"runs": runs, "roofline": roofline_info}
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--quick", action="store_true")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+    print(json.dumps(run(args.quick, args.seed), indent=1))
 
 
 if __name__ == "__main__":

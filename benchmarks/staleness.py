@@ -22,9 +22,9 @@ check.
 
 τ=0 resolves to the bsp strategy object itself (train/sync.py), so its
 cells ARE the synchronous baseline.  Must run with enough visible devices
-for the largest worker count — the parent (``benchmarks/run.py --only
-staleness``) spawns this module with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``:
+for the largest worker count; ``benchmarks/run.py --only staleness``
+calls ``run`` in its own process, and on the CPU the caller's environment
+supplies forced host devices:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m benchmarks.staleness [--quick]
@@ -167,20 +167,10 @@ def run_cell(net: str, tau: int, n_workers: int, train_steps: int,
     return cell
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--quick", action="store_true",
-                    help="CI smoke: chaos-small + chaos-medium tau {0,2} at "
-                         "4 workers, plus lm-bench tau {0,1} at 2 workers "
-                         "(with the layerwise tau=0 bit-identity cell), "
-                         "short training")
-    ap.add_argument("--nets", default=None,
-                    help="comma-separated net subset (e.g. --nets lm-bench "
-                         "to add/refresh only the dense-LM column, merged "
-                         "with benchmarks/merge_staleness.py)")
-    args = ap.parse_args()
-
-    if args.quick:
+def run(quick: bool = False, nets=None) -> dict:
+    """Measure the grid; ``nets`` keeps a subset of the nets."""
+    keep = set(nets) if nets else None
+    if quick:
         nets = ["chaos-small", "chaos-medium", "lm-bench"]
         net_taus = {"chaos-small": [0, 2], "chaos-medium": [0, 2],
                     "lm-bench": [0, 1]}
@@ -208,17 +198,10 @@ def main():
         # every worker count next to their batched twins
         layerwise_cells = {(net, tau, n) for net in nets for tau in (0, 1)
                            for n in net_workers[net]}
-    if args.nets:
-        keep = {n for n in args.nets.split(",") if n}
+    if keep:
         nets = [n for n in nets if n in keep]
-
-    n_dev = len(jax.devices())
-    need = max(max(net_workers[n]) for n in nets)
-    if need > n_dev:
-        print(f"error: need {need} devices, have {n_dev}; "
-              f"set XLA_FLAGS=--xla_force_host_platform_device_count="
-              f"{need}", file=sys.stderr)
-        sys.exit(2)
+    from benchmarks.scaling import require_devices
+    require_devices(max(max(net_workers[n]) for n in nets))
 
     runs = []
     for net in nets:
@@ -235,7 +218,23 @@ def main():
                           f"{r['steps_per_s']:.2f} steps/s "
                           f"err={r['final_error']:.4f}",
                           file=sys.stderr, flush=True)
-    json.dump({"runs": runs}, sys.stdout)
+    return {"runs": runs}
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--quick", action="store_true",
+                    help="CI smoke: chaos-small + chaos-medium tau {0,2} at "
+                         "4 workers, plus lm-bench tau {0,1} at 2 workers "
+                         "(with the layerwise tau=0 bit-identity cell), "
+                         "short training")
+    ap.add_argument("--nets", default=None,
+                    help="comma-separated net subset (e.g. --nets lm-bench "
+                         "to add/refresh only the dense-LM column, merged "
+                         "with benchmarks/merge_staleness.py)")
+    args = ap.parse_args()
+    nets = [n for n in args.nets.split(",") if n] if args.nets else None
+    json.dump(run(args.quick, nets), sys.stdout)
     print(flush=True)
 
 

@@ -7,14 +7,15 @@ Prints, side by side:
     (``benchmarks/run.py --only scaling``): the worker-mesh superstep
     path run at 1/2/4/8 workers for the three Table-2 nets x three sync
     modes, with the model's prediction for the same worker count,
-  - a live 4-worker CHAOS run through the production driver
-    (``repro.launch.train --workers 4``) on forced host devices.
+  - a live CHAOS run through the production driver on up to 4 workers
+    (``repro.launch.train.train(workers=N)``); on the CPU, forced host
+    devices give it 4:
 
-    PYTHONPATH=src python examples/chaos_speedup.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=src python examples/chaos_speedup.py
 """
 import json
 import os
-import subprocess
 import sys
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -142,19 +143,18 @@ def staleness_curves(path=None):
 
 
 def measured_workers():
-    """Live demo: 4 CHAOS workers through the production driver's
-    worker-mesh route (shard_map superstep; forced host devices)."""
-    print("\n== live: 4 CHAOS workers via repro.launch.train ==")
-    env = dict(os.environ, PYTHONPATH=SRC,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    out = subprocess.run(
-        [sys.executable, "-m", "repro.launch.train", "--arch", "chaos-small",
-         "--steps", "12", "--superstep", "4", "--workers", "4",
-         "--sync", "chaos"],
-        env=env, capture_output=True, text=True, timeout=900)
-    print(out.stdout)
-    if out.returncode != 0:
-        print(f"driver FAILED (rc={out.returncode}):\n{out.stderr[-2000:]}")
+    """Live demo: CHAOS workers through the production driver's worker-mesh
+    route (shard_map superstep), in this process — a chip belongs to one
+    process, so no child is started."""
+    import jax
+
+    from repro.launch.train import train
+
+    n = min(4, len(jax.devices()))
+    print(f"\n== live: {n} CHAOS worker(s) via repro.launch.train ==")
+    _, losses = train("chaos-small", 12, "chaos", superstep=4, workers=n)
+    print(f"loss {losses[0]:.4f} -> {losses[-1]:.4f} over {len(losses)} "
+          f"steps")
 
 
 if __name__ == "__main__":

@@ -10,13 +10,14 @@ Three layers, each optional flags deeper than the last:
    ``steps / superstep`` superstep spans, and every (bucket, worker) pair
    carries exactly ``steps`` ``exchange/<bucket>`` spans — one per
    optimizer step, for every bucket the layerwise schedule exchanges.
-3. **Cross-check** (``--bench BENCH_overlap.json``): the per-step summed
-   ``exchange_wait`` duration (mean over workers) agrees with the matching
-   committed artifact cell's ``exchange_us`` within ``--tolerance``.
+3. **Injected latency** (``--check-waits``): the run's own record of
+   the latency it injected agrees with the trace — every ``exchange``
+   window (issue to gate end) covers its injected ``delay_ms``, and the
+   summed ``exchange_wait`` durations agree with the sleeps the gates
+   computed (``slept_ms``) within ``--tolerance``.
 
     python scripts/trace_check.py trace.json --steps 8 --superstep 2 \
-        --workers 4 --bench BENCH_overlap.json --net chaos-small \
-        --schedule interleave --delay 400 --tolerance 0.25
+        --workers 4 --check-waits --tolerance 0.25
 """
 from __future__ import annotations
 
@@ -38,11 +39,9 @@ def main() -> int:
                     help="optimizer steps the traced run executed")
     ap.add_argument("--superstep", type=int, default=1)
     ap.add_argument("--workers", type=int, default=None)
-    ap.add_argument("--bench", default=None,
-                    help="BENCH_overlap.json to cross-check exchange_us")
-    ap.add_argument("--net", default="chaos-small")
-    ap.add_argument("--schedule", default="interleave")
-    ap.add_argument("--delay", type=float, default=400.0)
+    ap.add_argument("--check-waits", action="store_true",
+                    help="check the exchange spans against the latency the "
+                         "run injected (needs --collective-delay > 0)")
     ap.add_argument("--tolerance", type=float, default=0.25)
     args = ap.parse_args()
 
@@ -108,31 +107,27 @@ def main() -> int:
               f"{args.steps} exchange spans "
               f"({len(buckets)} buckets x {len(workers)} workers)")
 
-    # 3. exchange_us cross-check
-    if args.bench:
-        if args.steps is None:
-            fail("--bench needs --steps")
-        with open(args.bench) as f:
-            bench = json.load(f)
-        cell = next((r for r in bench.get("runs", [])
-                     if r["net"] == args.net
-                     and r["workers"] == (args.workers or r["workers"])
-                     and r["schedule"] == args.schedule
-                     and r["delay_ns_per_byte"] == args.delay), None)
-        if cell is None:
-            fail(f"no {args.net}/N{args.workers}/{args.schedule}"
-                 f"/delay{args.delay} cell in {args.bench}")
-        per_worker = [sum(ds) / args.steps for ds in waits.values()]
-        if not per_worker:
-            fail("no exchange_wait spans to compare")
-        measured = sum(per_worker) / len(per_worker)
-        ref = cell["exchange_us"]
-        err = abs(measured - ref) / ref
-        print(f"[trace-check] per-step exchange wait {measured:.0f}us vs "
-              f"committed exchange_us {ref:.0f}us "
-              f"(rel err {err:.1%}, tolerance {args.tolerance:.0%})")
+    # 3. injected latency vs the trace
+    if args.check_waits:
+        windows = [ev for ev in spans if ev["name"].startswith("exchange/")]
+        gates = [ev for ev in spans
+                 if ev["name"].startswith("exchange_wait/")]
+        short = [ev for ev in windows
+                 if ev["dur"] < ev["args"]["delay_ms"] * 1e3
+                 * (1 - args.tolerance)]
+        if short:
+            fail(f"{len(short)} exchange window(s) shorter than their "
+                 f"injected delay, e.g. {short[0]}")
+        waited = sum(ev["dur"] for ev in gates)
+        slept = sum(ev["args"]["slept_ms"] * 1e3 for ev in gates)
+        if slept <= 0:
+            fail("no gate slept: was --collective-delay > 0?")
+        err = abs(waited - slept) / slept
+        print(f"[trace-check] gate waits {waited:.0f}us vs computed sleeps "
+              f"{slept:.0f}us (rel err {err:.1%}, tolerance "
+              f"{args.tolerance:.0%})")
         if err > args.tolerance:
-            fail(f"traced exchange wait disagrees with {args.bench} "
+            fail(f"traced gate waits disagree with the run's own sleeps "
                  f"beyond {args.tolerance:.0%}")
     print("[trace-check] OK")
     return 0

@@ -378,7 +378,6 @@ def worker_train_fn(loss_fn, lr_fn, sync: SyncConfig, mesh,
     batches carry a leading worker axis likewise.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     inner = make_worker_step(loss_fn, lr_fn, sync, axis_name)
 
@@ -388,7 +387,7 @@ def worker_train_fn(loss_fn, lr_fn, sync: SyncConfig, mesh,
             batch_l = jax.tree.map(lambda x: x[0], batch_l)
             new_state, metrics = inner(state_l, batch_l)
             return (jax.tree.map(lambda x: x[None], new_state), metrics)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis_name), P(axis_name)),
             out_specs=(P(axis_name), P()),

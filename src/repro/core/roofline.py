@@ -1,9 +1,9 @@
 """Roofline analysis from compiled dry-run artifacts (deliverable g).
 
-TPU v5e targets (per chip):
-    peak bf16 compute : 197 TFLOP/s
-    HBM bandwidth     : 819 GB/s
-    ICI link bandwidth: ~50 GB/s/link
+Peaks come from ``PEAKS``, keyed by ``jax.Device.device_kind``; a kind
+that is not there is an error, never a default.  A caller that models a
+chip it is not running on (the CPU tests and dry runs model a v5e) names
+the kind explicitly.
 
 Terms (per device — the post-SPMD HLO module is the per-device program):
     compute term    = HLO_FLOPs / peak_FLOPs
@@ -22,9 +22,33 @@ import dataclasses
 import re
 from typing import Dict, List
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float     # peak bf16 FLOP/s per chip
+    hbm_bw: float    # HBM bytes/s per chip
+    ici_bw: float    # interconnect bytes/s per link
+
+
+#: ``device_kind`` JAX reports for a TPU v5e chip.
+V5E = "TPU v5 lite"
+
+#: Published per-chip peaks.  TPU v5e: Google Cloud documentation, "TPU
+#: v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+#: interconnect per chip (4 links of ~50 GB/s).
+PEAKS = {
+    V5E: Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to core/roofline.py::PEAKS with their source") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
@@ -145,8 +169,10 @@ class Roofline:
         }
 
 
-def analyze(compiled, *, n_devices: int, model_flops_total: float = 0.0):
-    """Build Roofline terms from a compiled executable.
+def analyze(compiled, *, n_devices: int, device_kind: str,
+            model_flops_total: float = 0.0):
+    """Build Roofline terms from a compiled executable, against the peaks
+    of ``device_kind``.
 
     The partitioned HLO module is the per-device program, so cost_analysis
     FLOPs/bytes are per-device quantities already.
@@ -166,11 +192,12 @@ def analyze(compiled, *, n_devices: int, model_flops_total: float = 0.0):
                + getattr(mem, "output_size_in_bytes", 0)
                - getattr(mem, "alias_size_in_bytes", 0))
     model_flops_dev = model_flops_total / n_devices if n_devices else 0.0
+    pk = peaks(device_kind)
     return Roofline(
         flops=flops, bytes_accessed=bytes_acc, collective=coll,
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=bytes_acc / HBM_BW,
-        collective_s=coll.effective_bytes / ICI_BW,
+        compute_s=flops / pk.flops,
+        memory_s=bytes_acc / pk.hbm_bw,
+        collective_s=coll.effective_bytes / pk.ici_bw,
         model_flops=model_flops_dev,
         per_device_hbm_bytes=hbm,
     )

@@ -15,10 +15,10 @@ Two-phase design, because timing is impossible under ``jit`` tracing:
   ``kernels/ops.py`` on the training hot path, possibly inside a trace)
   return the cached winner when present, else a VMEM-budget heuristic.
 
-Candidates are divisor block sizes pruned by a VMEM-footprint estimate, and
-the hard-coded ``batch_block=8`` whole-map baseline is ALWAYS in the
-candidate set, so the tuned pick is never slower than the baseline on the
-measurements it was chosen from.
+Candidates are TPU-legal divisor block sizes pruned by a VMEM-footprint
+estimate over padded (8, 128) tiles, and the heuristic default (what an
+untuned run uses) is ALWAYS the first candidate, so the tuned pick is never
+slower than the untuned one on the measurements it was chosen from.
 
 Cache format (one JSON object)::
 
@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import functools
 import json
+import math
 import os
 import time
 
@@ -57,13 +58,10 @@ _MEM: dict[str, dict] = {}
 # re-open the JSON file per conv call; reloaded when the path changes
 _DISK: dict = {"path": None, "data": {}}
 
-#: VMEM is ~16 MB/core; leave headroom for double buffering + the compiler.
+#: Mosaic's default scoped-VMEM limit is 16 MiB per core; leave headroom
+#: for the compiler's own spills.  Estimates below count padded tiles.
 VMEM_BUDGET_BYTES = int(os.environ.get("REPRO_VMEM_BUDGET", 12 * 2 ** 20))
 
-BASELINE = {"batch_block": 8, "row_block": None, "cout_block": None}
-BWD_BASELINE = {"batch_block": 8, "row_block": None}
-FC_BASELINE = {"batch_block": 8, "dout_block": None}
-FC_BWD_BASELINE = {"batch_block": 8}
 FLASH_BASELINE = {"block_q": 512, "block_k": 512}
 
 
@@ -167,47 +165,88 @@ def _divisors(n: int, cap: int | None = None) -> list[int]:
     return [d for d in range(1, cap + 1) if n % d == 0]
 
 
+def _lane_blocks(n: int, cap: int | None = None) -> list[int]:
+    """Block sizes a TPU accepts for a minor (lane) dim of extent ``n``:
+    divisors up to ``cap`` that are multiples of 128 lanes, and the whole
+    dim."""
+    return sorted({d for d in _divisors(n, cap) if d % K.LANES == 0} | {n})
+
+
+def _sublane_blocks(n: int, cap: int | None = None) -> list[int]:
+    """Block sizes a TPU accepts for a second-minor (sublane) dim of extent
+    ``n``: divisors up to ``cap`` that are multiples of 8, else the whole
+    dim."""
+    return ([d for d in _divisors(n, cap) if d % K.SUBLANES == 0]
+            or [n])
+
+
+def tiled_bytes(shape, itemsize: int = 4) -> int:
+    """VMEM bytes of an array whose two minor dims are padded to whole
+    (8 * 4 / itemsize, 128) tiles — a Cin=1 slab costs 128x its logical
+    size."""
+    *lead, sub, lane = (1,) * (2 - len(shape)) + tuple(shape)
+    sub_tile = K.SUBLANES * 4 // itemsize
+    return (math.prod(lead) * (-(-sub // sub_tile) * sub_tile)
+            * (-(-lane // K.LANES) * K.LANES) * itemsize)
+
+
 def conv_fwd_vmem_bytes(cfg, x_shape, w_shape, itemsize: int = 4) -> int:
-    """Bytes resident per grid step: x slab + weight block + out block +
-    the fp32 accumulator."""
+    """Bytes per grid step: the double-buffered x slab, weight, bias and
+    out blocks, plus the loaded slab, a tap patch and the fp32
+    accumulator the kernel body keeps live."""
     B, H, W, Cin = x_shape
     Kk, _, _, Cout = w_shape
     Ho, Wo = H - Kk + 1, W - Kk + 1
     bb = K._divisor_block(B, cfg["batch_block"])
     rb = K._divisor_block(Ho, cfg["row_block"])
     cb = K._divisor_block(Cout, cfg["cout_block"])
-    return (bb * (rb + Kk - 1) * W * Cin * itemsize
-            + Kk * Kk * Cin * cb * itemsize
-            + bb * rb * Wo * cb * itemsize
-            + bb * rb * Wo * cb * 4)
+    Wa = K.aligned(Wo)
+    x = tiled_bytes((bb, rb + Kk - 1, Wa + Kk - 1, Cin), itemsize)
+    blocks = (x + tiled_bytes((Kk, Kk, Cin, cb), itemsize)
+              + tiled_bytes((1, cb), itemsize)
+              + tiled_bytes((bb, rb, Wa, cb), itemsize))
+    rows = bb * rb * Wa
+    return (2 * blocks + x + tiled_bytes((rows, Cin), itemsize)
+            + tiled_bytes((rows, cb)))
 
 
 def conv_bwd_vmem_bytes(cfg, x_shape, w_shape, itemsize: int = 4,
                         fused_tanh: bool = True) -> int:
+    """Bytes per grid step of the fused backward: double-buffered x, dy
+    (+ y), w, dx, dw and db blocks, the dw/db scratch, and the loaded
+    slabs, dz, tap patches and fp32 dx accumulator of the kernel body."""
     B, H, W, Cin = x_shape
     Kk, _, _, Cout = w_shape
     Wo = W - Kk + 1
     bb = K._divisor_block(B, cfg["batch_block"])
     rb = K._divisor_block(H, cfg["row_block"])
-    slab = bb * (rb + Kk - 1) * (Wo + 2 * (Kk - 1)) * Cout * itemsize
-    return (bb * (rb + Kk - 1) * W * Cin * itemsize      # x slab
-            + slab * (2 if fused_tanh else 1)            # dy (+ y) slabs
-            + Kk * Kk * Cin * Cout * (itemsize + 4)      # w + dw scratch
-            + bb * rb * W * Cin * itemsize)              # dx block
+    Wa, Woa = K.aligned(W), K.aligned(Wo)
+    n_dz = 2 if fused_tanh else 1
+    x = tiled_bytes((bb, rb + Kk - 1, Woa + Kk - 1, Cin), itemsize)
+    dz = tiled_bytes((bb, rb + Kk - 1, Wa + Kk - 1, Cout), itemsize)
+    dw = tiled_bytes((Kk, Kk, Cin, Cout))
+    db = tiled_bytes((1, Cout))
+    blocks = (x + n_dz * dz + tiled_bytes((Kk, Kk, Cin, Cout), itemsize)
+              + tiled_bytes((bb, rb, Wa, Cin), itemsize) + dw + db)
+    dx_rows, dw_rows = bb * rb * Wa, bb * rb * Woa
+    values = (x + (n_dz + 1) * dz + tiled_bytes((dx_rows, Cin))
+              + tiled_bytes((dw_rows, Cout)) + tiled_bytes((dw_rows, Cin))
+              + tiled_bytes((Cin, dw_rows)))
+    return 2 * blocks + dw + db + values
 
 
 def conv_fwd_candidates(x_shape, w_shape, itemsize: int = 4) -> list[dict]:
+    """The heuristic default first, then every TPU-legal (bb, rb, cb) that
+    fits the VMEM budget."""
     B, H, W, Cin = x_shape
     Kk, _, _, Cout = w_shape
     Ho = H - Kk + 1
-    cands = [dict(BASELINE)]
+    cands = [default_conv_fwd(x_shape, w_shape, itemsize)]
     for bb in _divisors(B, 16):
         for rb in _divisors(Ho):
             if rb < Kk and rb != Ho:      # halo would dominate the slab
                 continue
-            for cb in _divisors(Cout, 128):
-                if cb % 8 and cb != Cout:  # keep lane-friendly channel blocks
-                    continue
+            for cb in _lane_blocks(Cout):
                 cfg = {"batch_block": bb, "row_block": rb, "cout_block": cb}
                 if conv_fwd_vmem_bytes(cfg, x_shape, w_shape,
                                        itemsize) <= VMEM_BUDGET_BYTES:
@@ -218,7 +257,7 @@ def conv_fwd_candidates(x_shape, w_shape, itemsize: int = 4) -> list[dict]:
 def conv_bwd_candidates(x_shape, w_shape, itemsize: int = 4) -> list[dict]:
     B, H, W, Cin = x_shape
     Kk = w_shape[0]
-    cands = [dict(BWD_BASELINE)]
+    cands = [default_conv_bwd(x_shape, w_shape, itemsize)]
     for bb in _divisors(B, 16):
         for rb in _divisors(H):
             if rb < Kk and rb != H:
@@ -231,36 +270,43 @@ def conv_bwd_candidates(x_shape, w_shape, itemsize: int = 4) -> list[dict]:
 
 
 def fc_fwd_vmem_bytes(cfg, x_shape, w_shape, itemsize: int = 4) -> int:
-    """Bytes per grid step: x row block + w column block + bias block +
-    the output tile and its fp32 accumulator."""
+    """Bytes per grid step: double-buffered x row block, w column block,
+    bias block and output tile, plus the fp32 accumulator."""
     B, Din = x_shape
     _, Dout = w_shape
     bb = K._divisor_block(B, cfg["batch_block"])
     db = K._divisor_block(Dout, cfg["dout_block"])
-    return (bb * Din * itemsize + Din * db * itemsize + db * itemsize
-            + bb * db * (itemsize + 4))
+    blocks = (tiled_bytes((bb, Din), itemsize)
+              + tiled_bytes((Din, db), itemsize)
+              + tiled_bytes((1, db), itemsize)
+              + tiled_bytes((bb, db), itemsize))
+    return 2 * blocks + tiled_bytes((bb, db))
 
 
 def fc_bwd_vmem_bytes(cfg, x_shape, w_shape, itemsize: int = 4,
                       fused_tanh: bool = True) -> int:
+    """Bytes per grid step of the fused backward: double-buffered x, dy
+    (+ y), w, dx, dw and db blocks, the dw/db scratch, and the dz and
+    transposed operands of the kernel body."""
     B, Din = x_shape
     _, Dout = w_shape
     bb = K._divisor_block(B, cfg["batch_block"])
-    return (bb * Din * itemsize                      # x block
-            + bb * Dout * itemsize * (2 if fused_tanh else 1)  # dy (+ y)
-            + Din * Dout * (itemsize + 4)            # w + dw scratch
-            + Dout * 4                               # db scratch
-            + bb * Din * itemsize)                   # dx block
+    n_dy = 2 if fused_tanh else 1
+    dw, db = tiled_bytes((Din, Dout)), tiled_bytes((1, Dout))
+    blocks = (2 * tiled_bytes((bb, Din), itemsize)           # x, dx
+              + n_dy * tiled_bytes((bb, Dout), itemsize)      # dy (+ y)
+              + tiled_bytes((Din, Dout), itemsize) + dw + db)
+    values = (tiled_bytes((bb, Dout)) + tiled_bytes((Dout, Din))
+              + tiled_bytes((Din, bb)))
+    return 2 * blocks + dw + db + values
 
 
 def fc_fwd_candidates(x_shape, w_shape, itemsize: int = 4) -> list[dict]:
     B, _ = x_shape
     _, Dout = w_shape
-    cands = [dict(FC_BASELINE)]
-    for bb in _divisors(B, 64):
-        for db in _divisors(Dout, 512):
-            if db % 8 and db != Dout:  # keep lane-friendly column blocks
-                continue
+    cands = [default_fc_fwd(x_shape, w_shape, itemsize)]
+    for bb in _sublane_blocks(B, 64):
+        for db in _lane_blocks(Dout, 512):
             cfg = {"batch_block": bb, "dout_block": db}
             if fc_fwd_vmem_bytes(cfg, x_shape, w_shape,
                                  itemsize) <= VMEM_BUDGET_BYTES:
@@ -270,8 +316,8 @@ def fc_fwd_candidates(x_shape, w_shape, itemsize: int = 4) -> list[dict]:
 
 def fc_bwd_candidates(x_shape, w_shape, itemsize: int = 4) -> list[dict]:
     B, _ = x_shape
-    cands = [dict(FC_BWD_BASELINE)]
-    for bb in _divisors(B, 64):
+    cands = [default_fc_bwd(x_shape, w_shape, itemsize)]
+    for bb in _sublane_blocks(B, 64):
         cfg = {"batch_block": bb}
         if fc_bwd_vmem_bytes(cfg, x_shape, w_shape,
                              itemsize) <= VMEM_BUDGET_BYTES:
@@ -327,78 +373,61 @@ def _dedup(cands: list[dict]) -> list[dict]:
 # ---------------------------------------------------------------------------
 # Heuristic defaults (used when nothing has been tuned yet)
 # ---------------------------------------------------------------------------
+def _first_fit(configs, fits) -> dict:
+    """The first config that ``fits``, else the last (smallest) one."""
+    for cfg in configs:
+        if fits(cfg):
+            return cfg
+    return cfg
+
+
 def default_conv_fwd(x_shape, w_shape, itemsize: int = 4) -> dict:
-    """Largest whole-map baseline that fits VMEM, shrinking rows first,
-    then batch, then output channels."""
+    """Largest block that fits VMEM: start from the whole output map, all
+    output channels and 8 images, and shrink the batch block first, then
+    rows, then (lane-legal) output channels."""
     B, H, _, _ = x_shape
     Kk, _, _, Cout = w_shape
     Ho = H - Kk + 1
-    cfg = dict(BASELINE)
-    for rb in reversed(_divisors(Ho)):
-        cfg["row_block"] = rb
-        if conv_fwd_vmem_bytes(cfg, x_shape, w_shape,
-                               itemsize) <= VMEM_BUDGET_BYTES:
-            return cfg
-    cfg["row_block"] = 1
-    for bb in reversed(_divisors(min(B, 8))):
-        cfg["batch_block"] = bb
-        if conv_fwd_vmem_bytes(cfg, x_shape, w_shape,
-                               itemsize) <= VMEM_BUDGET_BYTES:
-            return cfg
-    for cb in reversed(_divisors(Cout, 128)):
-        cfg["cout_block"] = cb
-        if conv_fwd_vmem_bytes(cfg, x_shape, w_shape,
-                               itemsize) <= VMEM_BUDGET_BYTES:
-            return cfg
-    return cfg
+    return _first_fit(
+        ({"batch_block": bb, "row_block": rb, "cout_block": cb}
+         for cb in reversed(_lane_blocks(Cout))
+         for rb in reversed(_divisors(Ho))
+         for bb in reversed(_divisors(B, 8))),
+        lambda c: conv_fwd_vmem_bytes(c, x_shape, w_shape,
+                                      itemsize) <= VMEM_BUDGET_BYTES)
 
 
 def default_conv_bwd(x_shape, w_shape, itemsize: int = 4) -> dict:
+    """As ``default_conv_fwd``: whole input map and 8 images, shrinking the
+    batch block first, then rows."""
     B, H, _, _ = x_shape
-    cfg = {"batch_block": 8, "row_block": None}
-    for rb in reversed(_divisors(H)):
-        cfg["row_block"] = rb
-        if conv_bwd_vmem_bytes(cfg, x_shape, w_shape,
-                               itemsize) <= VMEM_BUDGET_BYTES:
-            return cfg
-    cfg["row_block"] = 1
-    for bb in reversed(_divisors(min(B, 8))):
-        cfg["batch_block"] = bb
-        if conv_bwd_vmem_bytes(cfg, x_shape, w_shape,
-                               itemsize) <= VMEM_BUDGET_BYTES:
-            return cfg
-    return cfg
+    return _first_fit(
+        ({"batch_block": bb, "row_block": rb}
+         for rb in reversed(_divisors(H))
+         for bb in reversed(_divisors(B, 8))),
+        lambda c: conv_bwd_vmem_bytes(c, x_shape, w_shape,
+                                      itemsize) <= VMEM_BUDGET_BYTES)
 
 
 def default_fc_fwd(x_shape, w_shape, itemsize: int = 4) -> dict:
-    """Largest whole-row baseline that fits VMEM, shrinking the output
-    column block first, then the batch block."""
+    """Largest whole-row block that fits VMEM: shrink the (lane-legal)
+    output column block first, then the (sublane-legal) batch block."""
     B, _ = x_shape
     _, Dout = w_shape
-    cfg = dict(FC_BASELINE)
-    for db in reversed(_divisors(Dout)):
-        cfg["dout_block"] = db
-        if fc_fwd_vmem_bytes(cfg, x_shape, w_shape,
-                             itemsize) <= VMEM_BUDGET_BYTES:
-            return cfg
-    cfg["dout_block"] = 1
-    for bb in reversed(_divisors(min(B, 8))):
-        cfg["batch_block"] = bb
-        if fc_fwd_vmem_bytes(cfg, x_shape, w_shape,
-                             itemsize) <= VMEM_BUDGET_BYTES:
-            return cfg
-    return cfg
+    return _first_fit(
+        ({"batch_block": bb, "dout_block": db}
+         for bb in reversed(_sublane_blocks(B, 8))
+         for db in reversed(_lane_blocks(Dout))),
+        lambda c: fc_fwd_vmem_bytes(c, x_shape, w_shape,
+                                    itemsize) <= VMEM_BUDGET_BYTES)
 
 
 def default_fc_bwd(x_shape, w_shape, itemsize: int = 4) -> dict:
     B, _ = x_shape
-    cfg = dict(FC_BWD_BASELINE)
-    for bb in reversed(_divisors(min(B, 8))):
-        cfg["batch_block"] = bb
-        if fc_bwd_vmem_bytes(cfg, x_shape, w_shape,
-                             itemsize) <= VMEM_BUDGET_BYTES:
-            return cfg
-    return cfg
+    return _first_fit(
+        ({"batch_block": bb} for bb in reversed(_sublane_blocks(B, 8))),
+        lambda c: fc_bwd_vmem_bytes(c, x_shape, w_shape,
+                                    itemsize) <= VMEM_BUDGET_BYTES)
 
 
 def get_conv_fwd_config(x_shape, w_shape, dtype, *, interpret: bool,
@@ -453,7 +482,7 @@ def _time_us(fn, *args, iters: int = 3, warmup: int = 1) -> float:
 
 @_traced
 def tune_conv_fwd(x, w, bias=None, *, activation: str | None = None,
-                  interpret: bool = True, iters: int = 3,
+                  interpret: bool, iters: int = 3,
                   max_candidates: int | None = None):
     """Measure all pruned candidates for the forward kernel; cache + return
     ``(best_config, report)``.  The baseline is always measured, so
@@ -474,13 +503,13 @@ def tune_conv_fwd(x, w, bias=None, *, activation: str | None = None,
     best = json.loads(best_key)
     record(key, best, measured[best_key], measured, iters=iters)
     return best, {"key": key, "best_us": measured[best_key],
-                  "baseline_us": measured[json.dumps(dict(BASELINE),
+                  "baseline_us": measured[json.dumps(cands[0],
                                                      sort_keys=True)],
                   "candidates": measured}
 
 
 @_traced
-def tune_conv_bwd(x, dy, w, y=None, *, interpret: bool = True,
+def tune_conv_bwd(x, dy, w, y=None, *, interpret: bool,
                   iters: int = 3, max_candidates: int | None = None):
     """Measure candidates for the fused backward kernel (dtanh-fused when
     ``y`` is given); cache + return ``(best_config, report)``."""
@@ -500,14 +529,14 @@ def tune_conv_bwd(x, dy, w, y=None, *, interpret: bool = True,
     best = json.loads(best_key)
     record(key, best, measured[best_key], measured, iters=iters)
     return best, {"key": key, "best_us": measured[best_key],
-                  "baseline_us": measured[json.dumps(dict(BWD_BASELINE),
+                  "baseline_us": measured[json.dumps(cands[0],
                                                      sort_keys=True)],
                   "candidates": measured}
 
 
 @_traced
 def tune_fc_fwd(x, w, bias=None, *, activation: str | None = None,
-                interpret: bool = True, iters: int = 3,
+                interpret: bool, iters: int = 3,
                 max_candidates: int | None = None):
     """Measure all pruned candidates for the fused FC forward; cache +
     return ``(best_config, report)``.  Same contract as the conv tuners:
@@ -528,13 +557,13 @@ def tune_fc_fwd(x, w, bias=None, *, activation: str | None = None,
     best = json.loads(best_key)
     record(key, best, measured[best_key], measured, iters=iters)
     return best, {"key": key, "best_us": measured[best_key],
-                  "baseline_us": measured[json.dumps(dict(FC_BASELINE),
+                  "baseline_us": measured[json.dumps(cands[0],
                                                      sort_keys=True)],
                   "candidates": measured}
 
 
 @_traced
-def tune_fc_bwd(x, dy, w, y=None, *, interpret: bool = True, iters: int = 3,
+def tune_fc_bwd(x, dy, w, y=None, *, interpret: bool, iters: int = 3,
                 max_candidates: int | None = None):
     """Measure candidates for the fused FC backward (dtanh-fused when ``y``
     is given); cache + return ``(best_config, report)``."""
@@ -554,14 +583,14 @@ def tune_fc_bwd(x, dy, w, y=None, *, interpret: bool = True, iters: int = 3,
     best = json.loads(best_key)
     record(key, best, measured[best_key], measured, iters=iters)
     return best, {"key": key, "best_us": measured[best_key],
-                  "baseline_us": measured[json.dumps(dict(FC_BWD_BASELINE),
+                  "baseline_us": measured[json.dumps(cands[0],
                                                      sort_keys=True)],
                   "candidates": measured}
 
 
 @_traced
 def tune_flash_attention(q, k, v, *, causal: bool = True,
-                         interpret: bool = True, iters: int = 3,
+                         interpret: bool, iters: int = 3,
                          max_candidates: int | None = None):
     """Measure (block_q, block_k) candidates for the Pallas flash forward
     (q, k, v in kernel layout (B, H, T, D)); cache + return
@@ -589,16 +618,13 @@ def tune_flash_attention(q, k, v, *, causal: bool = True,
                   "candidates": measured}
 
 
-def tune_lm_attention(cfg, batch: int, seq: int, *, iters: int = 1,
-                      interpret: bool | None = None):
+def tune_lm_attention(cfg, batch: int, seq: int, *, interpret: bool,
+                      iters: int = 1):
     """Tune the flash forward at an LM config's training attention shape —
     exactly the cache key ``flash_attention_train`` looks up (q is
     (batch, n_heads, seq, d_head) after the BTHD -> BHTD transpose).  The
     worker mesh runs per-shard batches, so callers pass the per-shard
     batch.  Returns the list of cache keys written."""
-    if interpret is None:
-        from repro.kernels import ops as kops
-        interpret = kops._interpret()
     dtype = jnp.dtype(cfg.param_dtype)
     kk = jax.random.key(0)
     q = jax.random.normal(kk, (batch, cfg.n_heads, seq, cfg.d_head), dtype)
@@ -611,8 +637,7 @@ def tune_lm_attention(cfg, batch: int, seq: int, *, iters: int = 1,
     return [rep["key"]]
 
 
-def tune_cnn_net(cfg, batch: int, *, iters: int = 1,
-                 interpret: bool | None = None):
+def tune_cnn_net(cfg, batch: int, *, interpret: bool, iters: int = 1):
     """Tune every fused conv/FC kernel of a Table-2 CNN at the given batch
     size, populating exactly the cache keys the training path looks up.
 
@@ -624,9 +649,6 @@ def tune_cnn_net(cfg, batch: int, *, iters: int = 1,
     fallback.  Returns the list of cache keys written."""
     from repro.models.cnn import _trace_shapes  # local: avoid import cycle
 
-    if interpret is None:
-        from repro.kernels import ops as kops
-        interpret = kops._interpret()
     keys = []
     h = cfg.cnn_input[0]  # input spatial size of the NEXT layer
     kk = jax.random.key(0)

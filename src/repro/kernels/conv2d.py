@@ -10,11 +10,12 @@ array).
 
 Tiling (DESIGN.md §Kernels): the forward grid is 3-D
 (batch-block × output-row-block × Cout-block).  Row blocks read a halo of
-``K-1`` extra input rows via unblocked indexing, so feature maps larger than
-a single VMEM block (e.g. 64x64) stream through in row slabs instead of
-requiring the whole image resident.  Block sizes come from
-``kernels/autotune.py`` (or the caller) and must divide the corresponding
-dimension.
+``K-1`` extra input rows via element-offset (``pl.Element``) block dims, so
+feature maps larger than a single VMEM block (e.g. 64x64) stream through in
+row slabs instead of requiring the whole image resident.  Block sizes come
+from ``kernels/autotune.py`` (or the caller) and must divide the
+corresponding dimension; compiled for a TPU they must also be whole or
+(8, 128)-tile multiples in the two minor dims.
 
 Fusion: the forward kernel applies a bias + tanh epilogue in-register, and
 ``conv2d_bwd_fused`` computes dx, dw AND db from ONE shared pass over the
@@ -60,6 +61,26 @@ def launch_trace():
         _ACTIVE_TRACE = prev
 
 
+#: A TPU vector register is an (8, 128) f32 tile over an array's two minor
+#: dims.  A block's minor dims must be whole or tile multiples, and a
+#: reshape that folds leading dims into the second-minor dim keeps its
+#: layout only when that dim is a multiple of SUBLANES.
+SUBLANES, LANES = 8, 128
+
+
+#: Scoped-VMEM limit of the conv kernels.  Mosaic's default is 16 MiB; the
+#: block heuristic (kernels/autotune.py) fills at most 12 MiB of its own
+#: estimate, but f32 dots at "highest" precision keep ~2.4x that live
+#: (conv2's forward: 22.8 MiB for a 9.6 MiB estimate).  A v5e core has
+#: 128 MiB of VMEM.
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 2 ** 20)
+
+
+def aligned(n: int) -> int:
+    """``n`` rounded up to a whole number of sublane tiles."""
+    return -(-n // SUBLANES) * SUBLANES
+
+
 def _divisor_block(n: int, want: int | None) -> int:
     """Largest block size <= ``want`` that divides ``n``."""
     d = n if want is None else max(1, min(want, n))
@@ -91,12 +112,15 @@ def _conv_fwd_kernel(x_ref, w_ref, b_ref, o_ref, *, K: int, rb: int, Wo: int,
 
 def conv2d_fwd(x, w, bias=None, *, activation: str | None = None,
                batch_block: int = 8, row_block: int | None = None,
-               cout_block: int | None = None, interpret: bool = True):
+               cout_block: int | None = None, interpret: bool):
     """Valid conv, stride 1, NHWC x HWIO -> NHWC, optional fused bias+tanh.
 
     Grid is (B/bb, Ho/rb, Cout/cb); the x slab for each row block carries a
-    K-1 halo (unblocked indexing), so VMEM holds bb*(rb+K-1)*W*Cin elements
-    instead of the whole feature map.
+    K-1 halo (element-offset indexing), so VMEM holds bb*(rb+K-1)*W*Cin
+    elements instead of the whole feature map.  The kernel computes
+    ``aligned(Wo)`` output columns from an x zero-padded on the right, so
+    every (bb, rb, Wo, C) -> (bb*rb*Wo, C) reshape keeps the (8, 128) tile
+    layout; the extra columns are cropped on return.
     """
     B, H, W, Cin = x.shape
     K, _, _, Cout = w.shape
@@ -104,27 +128,31 @@ def conv2d_fwd(x, w, bias=None, *, activation: str | None = None,
     bb = _divisor_block(B, batch_block)
     rb = _divisor_block(Ho, row_block)
     cb = _divisor_block(Cout, cout_block)
+    Wa = aligned(Wo)
+    xp = jnp.pad(x, ((0, 0), (0, 0), (0, Wa - Wo), (0, 0)))
     b2 = (jnp.zeros((Cout,), x.dtype) if bias is None else bias).reshape(
         1, Cout)
-    kern = functools.partial(_conv_fwd_kernel, K=K, rb=rb, Wo=Wo,
+    kern = functools.partial(_conv_fwd_kernel, K=K, rb=rb, Wo=Wa,
                              activation=activation)
     record_launch("conv2d_fwd")
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=(B // bb, Ho // rb, Cout // cb),
         in_specs=[
-            # element offsets (unblocked): row slabs overlap by the K-1 halo
-            pl.BlockSpec((bb, rb + K - 1, W, Cin),
-                         lambda b, r, c: (b * bb, r * rb, 0, 0),
-                         indexing_mode=pl.unblocked),
+            # element offsets: row slabs overlap by the K-1 halo
+            pl.BlockSpec((pl.Element(bb), pl.Element(rb + K - 1),
+                          pl.Element(Wa + K - 1), pl.Element(Cin)),
+                         lambda b, r, c: (b * bb, r * rb, 0, 0)),
             pl.BlockSpec((K, K, Cin, cb), lambda b, r, c: (0, 0, 0, c)),
             pl.BlockSpec((1, cb), lambda b, r, c: (0, c)),
         ],
-        out_specs=pl.BlockSpec((bb, rb, Wo, cb),
+        out_specs=pl.BlockSpec((bb, rb, Wa, cb),
                                lambda b, r, c: (b, r, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, Cout), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Ho, Wa, Cout), x.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(x, w, b2)
+    )(xp, w, b2)
+    return out[:, :, :Wo]
 
 
 # ---------------------------------------------------------------------------
@@ -132,8 +160,8 @@ def conv2d_fwd(x, w, bias=None, *, activation: str | None = None,
 # ---------------------------------------------------------------------------
 def _bwd_body(x, dzp, w, dx_ref, dw_ref, db_ref, dw_acc, db_acc, *,
               K: int, rb: int, W: int, Wo: int):
-    """Shared backward pass.  ``x``: (bb, rb+K-1, W, Cin) input slab,
-    ``dzp``: (bb, rb+K-1, Wo+2K-2, Cout) zero-padded upstream grad slab
+    """Shared backward pass.  ``x``: (bb, rb+K-1, Wo+K-1, Cin) input slab,
+    ``dzp``: (bb, rb+K-1, W+K-1, Cout) zero-padded upstream grad slab
     (already multiplied by dtanh when fusing), ``w``: (K, K, Cin, Cout).
 
     dx rows [r*rb, r*rb+rb) = correlation of dzp with the flipped taps;
@@ -195,14 +223,18 @@ def _conv_bwd_tanh_kernel(xp_ref, dyp_ref, yp_ref, w_ref, dx_ref, dw_ref,
 
 
 def conv2d_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
-                     row_block: int | None = None, interpret: bool = True):
+                     row_block: int | None = None, interpret: bool):
     """One pallas_call -> (dx, dw, db) for the valid conv.
 
     ``y`` (the forward tanh output) fuses the dtanh factor in-kernel; with
     ``y=None`` the upstream gradient is used as-is (plain conv backward).
     Grid is (B/bb, H/rb) over *input* rows; dy (and y) arrive zero-padded by
     K-1 so halo reads, out-of-range output rows, and the width correlation
-    all fall out of the padding — no in-kernel masking needed.
+    all fall out of the padding — no in-kernel masking needed.  As in the
+    forward, the kernel works on sublane-aligned widths (``aligned(W)`` dx
+    columns, ``aligned(Wo)`` dz columns for dw); the extra dz columns are
+    zero padding, so they add nothing to dw/db, and the extra dx columns are
+    cropped on return.
     """
     B, H, W, Cin = x.shape
     K, _, _, Cout = w.shape
@@ -210,21 +242,23 @@ def conv2d_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
     bb = _divisor_block(B, batch_block)
     rb = _divisor_block(H, row_block)
     pad = K - 1
-    dyp = jnp.pad(dy, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-    xp = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    slab = pl.BlockSpec((bb, rb + pad, Wo + 2 * pad, Cout),
-                        lambda b, r: (b * bb, r * rb, 0, 0),
-                        indexing_mode=pl.unblocked)
+    Wa, Woa = aligned(W), aligned(Wo)
+    dz_pad = ((0, 0), (pad, pad), (pad, Wa - Wo), (0, 0))
+    dyp = jnp.pad(dy, dz_pad)
+    xp = jnp.pad(x, ((0, 0), (0, pad), (0, Woa - Wo), (0, 0)))
+    slab = pl.BlockSpec((pl.Element(bb), pl.Element(rb + pad),
+                         pl.Element(Wa + pad), pl.Element(Cout)),
+                        lambda b, r: (b * bb, r * rb, 0, 0))
     in_specs = [
-        pl.BlockSpec((bb, rb + pad, W, Cin),
-                     lambda b, r: (b * bb, r * rb, 0, 0),
-                     indexing_mode=pl.unblocked),
+        pl.BlockSpec((pl.Element(bb), pl.Element(rb + pad),
+                      pl.Element(Woa + pad), pl.Element(Cin)),
+                     lambda b, r: (b * bb, r * rb, 0, 0)),
         slab,
     ]
     inputs = [xp, dyp]
     if y is not None:
         in_specs.append(slab)
-        inputs.append(jnp.pad(y, ((0, 0), (pad, pad), (pad, pad), (0, 0))))
+        inputs.append(jnp.pad(y, dz_pad))
         kern = _conv_bwd_tanh_kernel
     else:
         kern = _conv_bwd_kernel
@@ -233,16 +267,16 @@ def conv2d_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
     inputs.append(w)
     record_launch("conv2d_bwd_fused")
     dx, dw, db = pl.pallas_call(
-        functools.partial(kern, K=K, rb=rb, W=W, Wo=Wo),
+        functools.partial(kern, K=K, rb=rb, W=Wa, Wo=Woa),
         grid=(B // bb, H // rb),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bb, rb, W, Cin), lambda b, r: (b, r, 0, 0)),
+            pl.BlockSpec((bb, rb, Wa, Cin), lambda b, r: (b, r, 0, 0)),
             pl.BlockSpec((K, K, Cin, Cout), lambda b, r: (0, 0, 0, 0)),
             pl.BlockSpec((1, Cout), lambda b, r: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, W, Cin), x.dtype),
+            jax.ShapeDtypeStruct((B, H, Wa, Cin), x.dtype),
             jax.ShapeDtypeStruct((K, K, Cin, Cout), jnp.float32),
             jax.ShapeDtypeStruct((1, Cout), jnp.float32),
         ],
@@ -250,9 +284,10 @@ def conv2d_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
             pltpu.VMEM((K, K, Cin, Cout), jnp.float32),
             pltpu.VMEM((1, Cout), jnp.float32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
-    return dx, dw, db.reshape(Cout)
+    return dx[:, :, :W], dw, db.reshape(Cout)
 
 
 # ---------------------------------------------------------------------------
@@ -279,7 +314,7 @@ def _conv_dx_kernel(dy_ref, w_ref, dx_ref, *, K: int, H: int, W: int):
 
 
 def conv2d_dx(dy, w, x_shape, *, batch_block: int = 8,
-              interpret: bool = True):
+              interpret: bool):
     B, H, W, Cin = x_shape
     K = w.shape[0]
     Ho, Wo = dy.shape[1], dy.shape[2]
@@ -328,7 +363,7 @@ def _conv_dw_kernel(x_ref, dy_ref, dw_ref, acc_ref, *, K: int):
 
 
 def conv2d_dw(x, dy, w_shape, *, batch_block: int = 8,
-              interpret: bool = True):
+              interpret: bool):
     B, H, W, Cin = x.shape
     K, _, _, Cout = w_shape
     Ho, Wo = dy.shape[1], dy.shape[2]

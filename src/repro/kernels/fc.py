@@ -51,7 +51,7 @@ def _fc_fwd_kernel(x_ref, w_ref, b_ref, o_ref, *, activation: str | None):
 
 def fc_fwd(x, w, bias=None, *, activation: str | None = None,
            batch_block: int = 8, dout_block: int | None = None,
-           interpret: bool = True):
+           interpret: bool):
     """act(x @ w + b); x: (B, Din), w: (Din, Dout), b: (Dout,) -> (B, Dout).
 
     Grid is (B/bb, Dout/db); each step holds an x row block, a w column
@@ -120,7 +120,7 @@ def _fc_bwd_tanh_kernel(x_ref, dy_ref, y_ref, w_ref, dx_ref, dw_ref, db_ref,
 
 
 def fc_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
-                 interpret: bool = True):
+                 interpret: bool):
     """One pallas_call -> (dx, dw, db) for the fused FC layer.
 
     ``y`` (the forward tanh output) fuses the dtanh factor in-kernel; with
@@ -185,7 +185,7 @@ def _softmax_xent_kernel(l_ref, lab_ref, loss_ref, dl_ref):
 
 
 def softmax_xent_fwd(logits, labels, *, batch_block: int = 8,
-                     interpret: bool = True):
+                     interpret: bool):
     """Per-sample CE loss and its logits gradient from one launch.
 
     logits: (B, C), labels: (B,) int -> (loss (B,), dlogits (B, C) where

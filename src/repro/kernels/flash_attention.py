@@ -8,7 +8,7 @@ dots), whereas this kernel keeps them in VMEM.
 Structure (the canonical TPU pallas flash pattern):
   grid = (B, Hkv*G, n_q_blocks, n_kv_blocks)   -- sequential on TPU
   scratch (VMEM, persists across the innermost kv iterations):
-      m (bq,), l (bq,), acc (bq, D)
+      m (bq, 1), l (bq, 1), acc (bq, D)
   @pl.when(kv_idx == 0)         -> init scratch
   each step: s = q @ k^T, online-softmax update of (m, l, acc)
   @pl.when(kv_idx == nk - 1)    -> out = acc / l
@@ -66,12 +66,12 @@ def _fa_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev, l_prev = m_sc[...], l_sc[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p.sum(axis=1)
-        acc_sc[...] = (acc_sc[...] * corr[:, None]
+        l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
+        acc_sc[...] = (acc_sc[...] * corr
                        + jax.lax.dot_general(
                            p, v, (((1,), (0,)), ((), ())),
                            preferred_element_type=jnp.float32))
@@ -91,7 +91,7 @@ def _fa_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
     def _done():
         l = l_sc[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_sc[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_sc[...] / l).astype(o_ref.dtype)
         # log-sum-exp of the (scaled) scores per query row — the training
         # backward's softmax denominator (models/layers.py::_flash_bwd
         # recomputes p = exp(s - lse) per block from it, so the kernel
@@ -101,7 +101,7 @@ def _fa_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, q_offset=0,
                         softmax_scale=None, block_q: int = 512,
-                        block_k: int = 512, interpret: bool = True,
+                        block_k: int = 512, interpret: bool,
                         return_lse: bool = False):
     """q: (B, Hq, Tq, D); k, v: (B, Hkv, Tk, D/Dv) — GQA by head grouping.
     Returns (B, Hq, Tq, Dv); with ``return_lse`` also the per-row
@@ -155,16 +155,17 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, q_offset=0,
             out_specs=[
                 pl.BlockSpec((1, 1, block_q, Dv),
                              lambda b, h, i, j: (b, h, i, 0)),
-                pl.BlockSpec((1, 1, block_q),
-                             lambda b, h, i, j: (b, h, i)),
+                # a (block_q, 1) column: the TPU tiles the two minor dims
+                pl.BlockSpec((1, 1, block_q, 1),
+                             lambda b, h, i, j: (b, h, i, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((B, Hkv, Tq + pad_q, Dv), q.dtype),
-                jax.ShapeDtypeStruct((B, Hkv, Tq + pad_q), jnp.float32),
+                jax.ShapeDtypeStruct((B, Hkv, Tq + pad_q, 1), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q,), jnp.float32),
-                pltpu.VMEM((block_q,), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, Dv), jnp.float32),
             ],
             interpret=interpret,
@@ -175,7 +176,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, q_offset=0,
     out = out.reshape(B, Hq, Tq + pad_q, Dv)[:, :, :Tq]
     if not return_lse:
         return out
-    lse = jnp.stack([l for _, l in outs], axis=2)[:, :, :, :Tq]
+    lse = jnp.stack([l[..., 0] for _, l in outs], axis=2)[:, :, :, :Tq]
     return out, lse
 
 

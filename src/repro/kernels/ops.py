@@ -8,13 +8,11 @@ one fused forward (conv + bias + tanh) and one fused backward (dx + dw + db
 from a single pass, dtanh folded in), down from three with the split
 fwd/dx/dw kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only — the
-kernels execute their bodies in Python for correctness validation; on a
-real TPU set REPRO_PALLAS_INTERPRET=0 or rely on backend detection).
+The kernels run interpreted (their bodies executed as jnp ops) only on the
+CPU backend, where the tests run; on any other backend they are compiled.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -29,10 +27,7 @@ from repro.kernels import pool as P
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _fwd_cfg(x, w, variant="plain"):

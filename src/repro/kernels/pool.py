@@ -33,7 +33,7 @@ def _maxpool_fwd_kernel(x_ref, o_ref, *, k: int, Ho: int, Wo: int):
 
 
 def maxpool2d_fwd(x, k: int, *, batch_block: int = 8,
-                  interpret: bool = True):
+                  interpret: bool):
     B, H, W, C = x.shape
     Ho, Wo = H // k, W // k
     bb = _divisor_block(B, batch_block)
@@ -65,7 +65,7 @@ def _maxpool_bwd_kernel(x_ref, y_ref, dy_ref, dx_ref, *, k: int, Ho: int,
 
 
 def maxpool2d_bwd(x, y, dy, k: int, *, batch_block: int = 8,
-                  interpret: bool = True):
+                  interpret: bool):
     """dx for maxpool2d_fwd; one pallas_call, gradient split across ties."""
     B, H, W, C = x.shape
     Ho, Wo = H // k, W // k

@@ -68,7 +68,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_sc, *, Q: int):
     o_ref[0, 0] = y.astype(o_ref.dtype)
 
 
-def wkv6_chunked(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def wkv6_chunked(r, k, v, w, u, *, chunk: int = 64, interpret: bool):
     """r, k, v, w: (B, T, H, D); w = decay in (0,1); u: (H, D).
     Returns y: (B, T, H, D).  T must be a multiple of `chunk`."""
     B, T, H, D = r.shape

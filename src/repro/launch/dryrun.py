@@ -165,7 +165,8 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         ML.UNROLL_ATTN = False
 
     mf = RL.model_flops(cfg, shape)
-    rl = RL.analyze(compiled, n_devices=n_dev, model_flops_total=mf)
+    rl = RL.analyze(compiled, n_devices=n_dev, device_kind=RL.V5E,
+                    model_flops_total=mf)
     mem = compiled.memory_analysis()
     # XLA-CPU promotes bf16 buffers to f32 for compute (wrapped_convert
     # computations with identical shapes): those f32 copies do not exist on
@@ -246,9 +247,10 @@ def roofline_cell(arch: str, shape_name: str, *, sync_mode: str = "bsp",
     coll_tot = ext("collective_bytes_per_dev")
     mf = RL.model_flops(cfg, shape)
     n_dev = i2["n_devices"]
-    compute_s = flops / RL.PEAK_FLOPS
-    memory_s = bytes_acc / RL.HBM_BW
-    coll_s = coll_eff / RL.ICI_BW
+    pk = RL.peaks(RL.V5E)     # the dry run models a v5e pod
+    compute_s = flops / pk.flops
+    memory_s = bytes_acc / pk.hbm_bw
+    coll_s = coll_eff / pk.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dominant = max(terms, key=terms.get)
     info = {

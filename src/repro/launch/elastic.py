@@ -41,6 +41,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.core.chaos import SyncConfig
 from repro.core.types import ArchConfig, WorkerConfig
+from repro.launch.mesh import auto_mesh, cpu_device_hint
 from repro.train import sharding as SH
 from repro.train.step import (init_train_state, init_worker_state,
                               make_optimizer, make_train_step,
@@ -64,11 +65,9 @@ def make_mesh_from_available(mesh_shape: Optional[Sequence[int]] = None,
     if need > len(devs):
         raise ValueError(
             f"mesh_shape {tuple(mesh_shape)} needs {need} device(s) but "
-            f"only {len(devs)} are visible; shrink the mesh or set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={need} in "
-            f"the environment BEFORE jax initialises to force host devices")
-    return jax.make_mesh(tuple(mesh_shape), tuple(axes),
-                         devices=devs[:need])
+            f"only {len(devs)} are visible; shrink the mesh"
+            + cpu_device_hint(need))
+    return auto_mesh(mesh_shape, axes, devices=devs[:need])
 
 
 def resume_elastic(cfg: ArchConfig, sync: SyncConfig, ckpt_dir: str,

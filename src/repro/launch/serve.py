@@ -21,6 +21,7 @@ import time
 import numpy as np
 
 import repro.configs as C
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import MetricsBus, Tracer
 from repro.serve.engine import (Request, RequestFeed, ServeEngine,
                                 poisson_trace)
@@ -120,6 +121,7 @@ def main():
                          "lifecycle here (DESIGN.md §11)")
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
     tracer = Tracer("serve") if args.trace_out else None
     bus = MetricsBus() if args.trace_out else None
     if args.slots:

@@ -57,6 +57,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.core.chaos import SyncConfig
 from repro.core.types import WorkerConfig
 from repro.data.pipeline import ImagePipeline, TokenPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.elastic import ResizeController
 from repro.launch.faults import FaultPlan
 from repro.launch.mesh import make_host_mesh
@@ -519,9 +520,9 @@ def main():
                     help="route the CNN hot path through the Pallas kernels")
     ap.add_argument("--workers", type=int, default=None,
                     help="CHAOS worker-mesh route: N worker instances over "
-                         "a 1-D device mesh (needs N visible devices; force "
-                         "host devices with XLA_FLAGS=--xla_force_host_"
-                         "platform_device_count=N)")
+                         "a 1-D device mesh (needs N visible devices; on the "
+                         "CPU force host devices with XLA_FLAGS=--xla_force_"
+                         "host_platform_device_count=N)")
     ap.add_argument("--logical-shards", type=int, default=8,
                     help="fixed micro-shard count of the global batch on "
                          "the worker route; any --workers dividing it "
@@ -581,6 +582,7 @@ def main():
                          "at chunk granularity; 0 keeps the single-stack "
                          "scan layout, must divide n_layers")
     args = ap.parse_args()
+    use_compile_cache()
     _, losses = train(args.arch, args.steps, args.sync, args.batch, args.seq,
                       args.ckpt_dir, args.ckpt_every, args.die_at_step,
                       args.lr, args.compress, smoke=not args.full_config,

@@ -573,8 +573,6 @@ def make_worker_superstep(cfg: ArchConfig, sync: SyncConfig,
     strategy's ``shard_view`` (replicated or worker-stacked).  Metrics are
     replicated (K,) vectors.  jit'd with the TrainState donated.
     """
-    from jax.experimental.shard_map import shard_map
-
     step = make_worker_train_step(cfg, sync, worker, optimizer)
     strat = get_strategy(sync)
     stacked = strat.stacked_state
@@ -607,10 +605,10 @@ def make_worker_superstep(cfg: ArchConfig, sync: SyncConfig,
                  for k, v in layout.items()}
     state_spec = {"params": base, "opt": base, "step": base,
                   "sync": sync_spec}
-    fn = shard_map(superstep, mesh=mesh,
-                   in_specs=(state_spec, P(None, worker.axis)),
-                   out_specs=(state_spec, P()),
-                   check_rep=False)
+    fn = jax.shard_map(superstep, mesh=mesh,
+                       in_specs=(state_spec, P(None, worker.axis)),
+                       out_specs=(state_spec, P()),
+                       check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 

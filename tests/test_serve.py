@@ -86,6 +86,30 @@ def test_prefill_matches_decode_loop(arch):
                                   np.asarray(logits_pre[:, T - 1]))
 
 
+def _assert_equal_but_bf16_dot_rounding(got, want):
+    """Bit-equal, except that a bfloat16 element may differ by one ulp.
+
+    XLA:CPU picks its dot kernel by the operands' shape: the same jitted
+    ``x @ wv`` on identical bf16 operands, with 16 rows (batched prefill)
+    and with 1 row (single-token decode), accumulates in a different order
+    and can round the f32 sum to the neighbouring bf16 value (qwen3-14b's
+    layer-0 value cache: -0.0012741 vs -0.0012817, exact -0.0012774).  That
+    is XLA's rounding, not the engine's, so one bf16 ulp is allowed; f32
+    state stays bit-exact."""
+    if got.dtype != jnp.bfloat16:
+        np.testing.assert_array_equal(got, want)
+        return
+
+    def ordered(x):  # bf16 bit patterns -> integers ordered like values
+        b = x.view(np.uint16).astype(np.int32)
+        return np.where(b & 0x8000, -(b & 0x7FFF), b)
+
+    ulps = np.abs(ordered(got) - ordered(want))
+    assert ulps.max(initial=0) <= 1, (
+        f"{int((ulps > 1).sum())} element(s) differ by more than one bf16 "
+        f"ulp (max {int(ulps.max())})")
+
+
 @pytest.mark.parametrize("arch", SERVED)
 def test_ragged_prefill_matches_per_row_loop(arch):
     """Right-padded rows of different lengths: each row's cache and
@@ -116,7 +140,7 @@ def test_ragged_prefill_matches_per_row_loop(arch):
                 # KV rows: positions past the row's length hold bucket junk
                 # that decode can never attend; compare the live prefix
                 got, want = got[:, :ln], want[:, :ln]
-            np.testing.assert_array_equal(got, want)
+            _assert_equal_but_bf16_dot_rounding(got, want)
         np.testing.assert_array_equal(np.asarray(logits_row[0, 0]),
                                       np.asarray(logits_b[i, ln - 1]))
 

@@ -1,26 +1,9 @@
 """Forward parity of the Pallas WKV6 kernel against the pure-jnp chunked
-oracle (``models/rwkv6.wkv_chunked``), with a skip guard for hosts whose
-jax build lacks a working interpret-mode Pallas."""
+oracle (``models/rwkv6.wkv_chunked``), in interpret mode."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-
-def _pallas_available():
-    try:
-        from repro.kernels import ops as kops
-        jax.block_until_ready(kops.conv2d_valid(
-            jnp.zeros((1, 6, 6, 1), jnp.float32),
-            jnp.zeros((3, 3, 1, 2), jnp.float32)))
-        return True
-    except Exception:  # noqa: BLE001 — any failure means "skip"
-        return False
-
-
-pytestmark = pytest.mark.skipif(
-    not _pallas_available(),
-    reason="interpret-mode Pallas unavailable on this host")
 
 
 def _inputs(key, B, T, H, D):
@@ -45,7 +28,7 @@ def test_wkv6_fwd_parity(B, T, H, D, chunk):
     from repro.kernels.wkv6 import wkv6_chunked
     from repro.models.rwkv6 import wkv_chunked
     r, k, v, w, u = _inputs(B * 1000 + T, B, T, H, D)
-    got = wkv6_chunked(r, k, v, w, u, chunk=chunk)
+    got = wkv6_chunked(r, k, v, w, u, chunk=chunk, interpret=True)
     want, _ = wkv_chunked(r, k, v, w, u)
     assert got.shape == want.shape == (B, T, H, D)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -59,7 +42,7 @@ def test_wkv6_fwd_parity_under_jit():
     from repro.models.rwkv6 import wkv_chunked
     B, T, H, D = 1, 128, 2, 16
     r, k, v, w, u = _inputs(7, B, T, H, D)
-    got = jax.jit(lambda *a: wkv6_chunked(*a, chunk=64))(r, k, v, w, u)
+    got = jax.jit(lambda *a: wkv6_chunked(*a, chunk=64, interpret=True))(r, k, v, w, u)
     want, _ = wkv_chunked(r, k, v, w, u)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-4, rtol=2e-3)
