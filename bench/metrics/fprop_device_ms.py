@@ -1,0 +1,10 @@
+"""Device time per training step of the model's forward pass: the leaf
+operations the compiled step names as a Table-2 layer (``conv0`` ...
+``fc7``) or the ``loss``, in the forward direction (``models/cnn.py``'s
+named scopes, ``bench/scopes.py``), averaged over chips."""
+
+import scopes
+
+
+def read(ctx):
+    return scopes.per_step_ms(ctx, scopes.model_pass("fwd"))

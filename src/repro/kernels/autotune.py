@@ -45,8 +45,8 @@ from repro.obs.trace import span as _obs_span
 
 def _traced(fn):
     """Wrap a tune entry point in an ``autotune`` obs span (DESIGN.md §11)
-    so kernel-tuning time lands on the trace timeline; no-op without an
-    installed tracer."""
+    so kernel-tuning time lands on the trace timeline (the profiler's,
+    and an installed tracer's)."""
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
         with _obs_span("autotune", target=fn.__name__):

@@ -151,6 +151,7 @@ def conv2d_fwd(x, w, bias=None, *, activation: str | None = None,
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wa, Cout), x.dtype),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="conv2d_fwd" + (f"_{activation}" if activation else ""),
     )(xp, w, b2)
     return out[:, :, :Wo]
 
@@ -259,9 +260,9 @@ def conv2d_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
     if y is not None:
         in_specs.append(slab)
         inputs.append(jnp.pad(y, dz_pad))
-        kern = _conv_bwd_tanh_kernel
+        kern, name = _conv_bwd_tanh_kernel, "conv2d_bwd_tanh"
     else:
-        kern = _conv_bwd_kernel
+        kern, name = _conv_bwd_kernel, "conv2d_bwd"
     in_specs.append(pl.BlockSpec((K, K, Cin, Cout),
                                  lambda b, r: (0, 0, 0, 0)))
     inputs.append(w)
@@ -286,6 +287,7 @@ def conv2d_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
         ],
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name=name,
     )(*inputs)
     return dx[:, :, :W], dw, db.reshape(Cout)
 
@@ -332,6 +334,7 @@ def conv2d_dx(dy, w, x_shape, *, batch_block: int = 8,
         out_specs=pl.BlockSpec((bb, H, W, Cin), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, Cin), dy.dtype),
         interpret=interpret,
+        name="conv2d_dx",
     )(dy, w)
 
 
@@ -381,4 +384,5 @@ def conv2d_dw(x, dy, w_shape, *, batch_block: int = 8,
         out_shape=jax.ShapeDtypeStruct((K, K, Cin, Cout), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, K, Cin, Cout), jnp.float32)],
         interpret=interpret,
+        name="conv2d_dw",
     )(x, dy)

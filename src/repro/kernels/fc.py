@@ -75,6 +75,7 @@ def fc_fwd(x, w, bias=None, *, activation: str | None = None,
         out_specs=pl.BlockSpec((bb, db), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, Dout), x.dtype),
         interpret=interpret,
+        name="fc_fwd" + (f"_{activation}" if activation else ""),
     )(x, w, b2)
 
 
@@ -138,9 +139,9 @@ def fc_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
     if y is not None:
         in_specs.append(pl.BlockSpec((bb, Dout), lambda b: (b, 0)))
         inputs.append(y)
-        kern = _fc_bwd_tanh_kernel
+        kern, name = _fc_bwd_tanh_kernel, "fc_bwd_tanh"
     else:
-        kern = _fc_bwd_kernel
+        kern, name = _fc_bwd_kernel, "fc_bwd"
     in_specs.append(pl.BlockSpec((Din, Dout), lambda b: (0, 0)))
     inputs.append(w)
     record_launch("fc_bwd_fused")
@@ -163,6 +164,7 @@ def fc_bwd_fused(x, dy, w, y=None, *, batch_block: int = 8,
             pltpu.VMEM((1, Dout), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(*inputs)
     return dx, dw, db.reshape(Dout)
 
@@ -211,5 +213,6 @@ def softmax_xent_fwd(logits, labels, *, batch_block: int = 8,
             jax.ShapeDtypeStruct((B, C), logits.dtype),
         ],
         interpret=interpret,
+        name="softmax_xent",
     )(logits, lab2)
     return loss.reshape(B), dl

@@ -45,6 +45,7 @@ def maxpool2d_fwd(x, k: int, *, batch_block: int = 8,
         out_specs=pl.BlockSpec((bb, Ho, Wo, C), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, C), x.dtype),
         interpret=interpret,
+        name="maxpool_fwd",
     )(x)
 
 
@@ -82,4 +83,5 @@ def maxpool2d_bwd(x, y, dy, k: int, *, batch_block: int = 8,
         out_specs=pl.BlockSpec((bb, H, W, C), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, C), x.dtype),
         interpret=interpret,
+        name="maxpool_bwd",
     )(x, y, dy)

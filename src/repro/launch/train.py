@@ -171,19 +171,30 @@ def put_worker_sharded(pipe, start: int, k: int, mesh, worker: WorkerConfig):
     # build the global stacked batch ONCE and slice per worker (slicing is
     # what worker_superstep_at does; rebuilding it N times would put O(N)
     # redundant host work on the prefetch hot path)
-    stacked = pipe.superstep_at(start, k)
-    b = next(iter(stacked.values())).shape[1]
-    shards = [worker_slice(stacked, b, n, w) for w in range(n)]
+    with obs_trace.span("feed/build", thread="feed", step_start=start):
+        stacked = pipe.superstep_at(start, k)
+        b = next(iter(stacked.values())).shape[1]
+        shards = [worker_slice(stacked, b, n, w) for w in range(n)]
     sharding = NamedSharding(mesh, P(None, worker.axis))
     devices = list(mesh.devices.flat)
     out = {}
-    for key in shards[0]:
-        arrs = [jax.device_put(s[key], d) for s, d in zip(shards, devices)]
-        shp = shards[0][key].shape
-        gshape = (shp[0], shp[1] * n) + shp[2:]
-        out[key] = jax.make_array_from_single_device_arrays(
-            gshape, sharding, arrs)
+    with obs_trace.span("feed/put", thread="feed", step_start=start):
+        for key in shards[0]:
+            arrs = [jax.device_put(s[key], d)
+                    for s, d in zip(shards, devices)]
+            shp = shards[0][key].shape
+            gshape = (shp[0], shp[1] * n) + shp[2:]
+            out[key] = jax.make_array_from_single_device_arrays(
+                gshape, sharding, arrs)
     return out
+
+
+def _put(pipe, start: int, k: int):
+    """The default feed transfer: the stacked batch to the default device."""
+    with obs_trace.span("feed/build", thread="feed", step_start=start):
+        stacked = pipe.superstep_at(start, k)
+    with obs_trace.span("feed/put", thread="feed", step_start=start):
+        return jax.device_put(stacked)
 
 
 class PrefetchFeed:
@@ -194,15 +205,16 @@ class PrefetchFeed:
     main thread's current superstep is still computing; queue depth 2 is
     classic double buffering (one in flight, one ready).  ``put`` overrides
     the host->device transfer (the worker route shards each superstep
-    batch over the worker mesh, ``put_worker_sharded``).
+    batch over the worker mesh, ``put_worker_sharded``).  Spans (DESIGN.md
+    §11): ``feed/build`` and ``feed/put`` on the producer thread, inside
+    ``put``; ``feed/wait`` where the consumer blocks on the queue.
     """
 
     def __init__(self, pipe, chunks, depth: int = 2, put=None):
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._error: BaseException | None = None
         self._stopped = False
-        self._put = put or (lambda p, s, k: jax.device_put(
-            p.superstep_at(s, k)))
+        self._put = put or _put
         self._thread = threading.Thread(
             target=self._produce, args=(pipe, list(chunks)), daemon=True)
         self._thread.start()
@@ -233,7 +245,8 @@ class PrefetchFeed:
 
     def __iter__(self):
         while True:
-            item = self._q.get()
+            with obs_trace.span("feed/wait"):
+                item = self._q.get()
             if item is None:
                 if self._error is not None:
                     raise RuntimeError("prefetch feed failed") from self._error
@@ -267,9 +280,9 @@ def train(arch: str, steps: int, sync_mode: str = "bsp", batch: int = 8,
     # -- observability (DESIGN.md §11) ------------------------------------
     # The bus is ALWAYS present (it replaced the ad-hoc loss_map / metrics
     # dict — per-step cost is one dict store); the tracer only when asked.
-    # set_tracer BEFORE building any step function: the worker-mesh bucket
-    # paths consult the global at build time, so with no tracer the
-    # compiled graphs are byte-identical to a no-obs build.
+    # The loop's spans are profiler annotations either way; an installed
+    # tracer also records them for trace.json.  Nothing reaches a compiled
+    # step: the graphs are the same with and without a tracer.
     bus = metrics_bus if metrics_bus is not None else MetricsBus()
     if bus.sink is None and metrics_interval > 0 and metrics_out:
         bus.sink = JsonlSink(metrics_out + ".jsonl")
@@ -380,16 +393,14 @@ def _train(arch, steps, sync_mode, batch, seq, ckpt_dir, ckpt_every,
         resize_request = None
         for s0, k, dev_batch in feed:
             t0 = time.time()
-            if tracer is not None:
-                with tracer.span("superstep", step_start=s0, k=k):
+            with obs_trace.span("superstep", step_start=s0, k=k):
+                with obs_trace.span("dispatch"):
                     state, metrics = super_fn(state, dev_batch)
-                    # ONE host sync per K steps: the (K,) loss vector —
-                    # inside the span so it covers device time, not just
-                    # the async dispatch
+                # ONE host sync per K steps: the (K,) loss vector — inside
+                # the superstep span so it covers device time, not just
+                # the async dispatch
+                with obs_trace.span("loss_readback"):
                     loss_vec = np.asarray(metrics["loss"])
-            else:
-                state, metrics = super_fn(state, dev_batch)
-                loss_vec = np.asarray(metrics["loss"])
             end = s0 + k
             for t in range(s0, end):
                 bus.series("train/loss", t, float(loss_vec[t - s0]))
@@ -547,9 +558,9 @@ def main():
                          "metrics bus, DESIGN.md §11)")
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome-trace/Perfetto trace.json (+ "
-                         ".jsonl) with superstep/checkpoint/resize spans "
-                         "and, on the layerwise worker mesh, per-bucket "
-                         "exchange issue/gate spans (DESIGN.md §11)")
+                         ".jsonl) with superstep (dispatch, loss_readback), "
+                         "feed, checkpoint and resize spans on the "
+                         "profiler's clock (DESIGN.md §11)")
     ap.add_argument("--metrics-interval", type=int, default=0,
                     help="emit a metrics-bus snapshot every N steps — to "
                          "<metrics-out>.jsonl when --metrics-out is set, "

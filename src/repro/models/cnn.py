@@ -12,9 +12,17 @@ forward and one fused dx+dw+db launch backward per conv layer, Pallas
 max-pool both ways, one fused matmul+bias(+tanh) launch per FC layer
 each way, and a fused softmax-cross-entropy kernel whose backward reuses
 the saved dlogits (zero extra launches).
+
+Every layer runs under ``jax.named_scope("{kind}{i}")`` (the names
+``bucket_spec`` uses; pool layers too) and the loss under ``"loss"``, on
+both paths: the compiled step's HLO names each layer's forward
+(``jvp(conv2)/...``) and backward (``transpose(jvp(conv2))/...``, or
+``conv2/bwd/...`` in the saved-activation tape) — ``obs/trace.py``'s
+naming contract.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -93,84 +101,74 @@ def _use_kernel(cfg: ArchConfig, use_kernel):
     return cfg.use_kernel if use_kernel is None else use_kernel
 
 
+def _scoped(fn, scope: str):
+    """``fn`` run under a named scope (``obs/trace.py``)."""
+    def run(*args):
+        with jax.named_scope(scope):
+            return fn(*args)
+    return run
+
+
 def forward(params, images, cfg: ArchConfig, use_kernel: bool | None = None):
     """images: (B, H, W, 1) float32 in [0,1].  Returns (B, n_classes) logits."""
     x = images
     uk = _use_kernel(cfg, use_kernel)
+    shapes = _trace_shapes(cfg)
+    for i, (kind, k, *_) in enumerate(shapes):
+        with jax.named_scope(f"{kind}{i}"):
+            x = _forward_layer(params, x, i, kind, k, i == len(shapes) - 1,
+                               uk)
+    return x
+
+
+def _forward_layer(params, x, i, kind, k, last, uk):
+    """Layer ``i`` of ``forward``: the XLA or the Pallas-kernel path."""
     if uk:
         from repro.kernels import ops as kops
-    shapes = _trace_shapes(cfg)
-    for i, (kind, k, _, cin, cout) in enumerate(shapes):
-        if kind == "conv":
-            p = params[f"conv{i}"]
-            if uk:
-                x = kops.conv2d_bias_tanh(x, p["w"], p["b"])
-            else:
-                x = jnp.tanh(jax.lax.conv_general_dilated(
-                    x, p["w"], (1, 1), "VALID",
-                    dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["b"])
-        elif kind == "pool":
-            if k > 1:
-                if uk:
-                    x = kops.maxpool2d(x, k)
-                else:
-                    x = jax.lax.reduce_window(
-                        x, -jnp.inf, jax.lax.max, (1, k, k, 1), (1, k, k, 1),
-                        "VALID")
-        else:
-            p = params[f"fc{i}"]
-            if x.ndim > 2:
-                x = x.reshape(x.shape[0], -1)
-            last = i == len(shapes) - 1
-            if uk:
-                x = (kops.fc_bias(x, p["w"], p["b"]) if last
-                     else kops.fc_bias_tanh(x, p["w"], p["b"]))
-            else:
-                x = x @ p["w"] + p["b"]
-                if not last:
-                    x = jnp.tanh(x)
-    return x
+    if kind == "conv":
+        p = params[f"conv{i}"]
+        if uk:
+            return kops.conv2d_bias_tanh(x, p["w"], p["b"])
+        return jnp.tanh(jax.lax.conv_general_dilated(
+            x, p["w"], (1, 1), "VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["b"])
+    if kind == "pool":
+        if k == 1:
+            return x
+        if uk:
+            return kops.maxpool2d(x, k)
+        return jax.lax.reduce_window(
+            x, -jnp.inf, jax.lax.max, (1, k, k, 1), (1, k, k, 1), "VALID")
+    p = params[f"fc{i}"]
+    if x.ndim > 2:
+        x = x.reshape(x.shape[0], -1)
+    if uk:
+        return (kops.fc_bias(x, p["w"], p["b"]) if last
+                else kops.fc_bias_tanh(x, p["w"], p["b"]))
+    x = x @ p["w"] + p["b"]
+    return x if last else jnp.tanh(x)
 
 
 def _layer_fns(cfg: ArchConfig, uk: bool):
     """One closure per Table-2 layer, in forward order: ``(name, fn)`` where
     ``fn(p, x)`` (params-less layers: ``fn(x)``, name None) runs that layer
-    through the XLA or Pallas-kernel path.  Shared by the layerwise walk so
-    both paths stay byte-compatible with ``forward``."""
-    if uk:
-        from repro.kernels import ops as kops
+    through the XLA or Pallas-kernel path under the layer's named scope.
+    Shared by the layerwise walk; each runs ``forward``'s own layer body,
+    so both paths stay byte-compatible."""
     shapes = _trace_shapes(cfg)
     out = []
-    for i, (kind, k, _, cin, cout) in enumerate(shapes):
-        if kind == "conv":
-            if uk:
-                fn = lambda p, x: kops.conv2d_bias_tanh(x, p["w"], p["b"])
-            else:
-                fn = lambda p, x: jnp.tanh(jax.lax.conv_general_dilated(
-                    x, p["w"], (1, 1), "VALID",
-                    dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["b"])
-            out.append((f"conv{i}", fn))
-        elif kind == "pool":
+    for i, (kind, k, *_) in enumerate(shapes):
+        scope = f"{kind}{i}"
+        layer = functools.partial(_forward_layer, i=i, kind=kind, k=k,
+                                  last=i == len(shapes) - 1, uk=uk)
+        if kind == "pool":
             if k > 1:
-                if uk:
-                    fn = lambda x, k=k: kops.maxpool2d(x, k)
-                else:
-                    fn = lambda x, k=k: jax.lax.reduce_window(
-                        x, -jnp.inf, jax.lax.max, (1, k, k, 1),
-                        (1, k, k, 1), "VALID")
-                out.append((None, fn))
+                out.append((None, _scoped(
+                    lambda x, layer=layer: layer(None, x), scope)))
         else:
-            last = i == len(shapes) - 1
-
-            def fn(p, x, last=last):
-                if x.ndim > 2:
-                    x = x.reshape(x.shape[0], -1)
-                if uk:
-                    return (kops.fc_bias(x, p["w"], p["b"]) if last
-                            else kops.fc_bias_tanh(x, p["w"], p["b"]))
-                x = x @ p["w"] + p["b"]
-                return x if last else jnp.tanh(x)
-            out.append((f"fc{i}", fn))
+            out.append((scope, _scoped(
+                lambda p, x, layer=layer, scope=scope: layer({scope: p}, x),
+                scope)))
     return out
 
 
@@ -183,7 +181,8 @@ def _layer_bwd_fns(cfg: ArchConfig, uk: bool):
     (``kernels/ops.py`` saved-activation entry points) and the XLA path
     applies the exact tanh VJP rule ``g * (1 - y*y)`` plus
     ``jax.linear_transpose`` of the linear conv/matmul — the same
-    primitives ``jax.vjp`` would emit, minus the primal recompute."""
+    primitives ``jax.vjp`` would emit, minus the primal recompute.  Each
+    runs under ``{layer}/bwd``: the same layer's scope, backward."""
     if uk:
         from repro.kernels import ops as kops
     shapes = _trace_shapes(cfg)
@@ -208,7 +207,7 @@ def _layer_bwd_fns(cfg: ArchConfig, uk: bool):
                     return ({"w": dw.astype(p["w"].dtype),
                              "b": g.sum((0, 1, 2)).astype(p["b"].dtype)},
                             dx.astype(x.dtype))
-            out.append(bwd)
+            out.append(_scoped(bwd, f"conv{i}/bwd"))
         elif kind == "pool":
             if k > 1:
                 if uk:
@@ -222,7 +221,7 @@ def _layer_bwd_fns(cfg: ArchConfig, uk: bool):
                         _, vjp = jax.vjp(pool, x)
                         (dx,) = vjp(g)
                         return dx
-                out.append(bwd)
+                out.append(_scoped(bwd, f"pool{i}/bwd"))
         else:
             last = i == len(shapes) - 1
 
@@ -241,7 +240,7 @@ def _layer_bwd_fns(cfg: ArchConfig, uk: bool):
                     db = g.sum(0).astype(p["b"].dtype)
                     dxf = (g @ p["w"].T).astype(x.dtype)
                 return {"w": dw, "b": db}, dxf.reshape(x.shape)
-            out.append(bwd)
+            out.append(_scoped(bwd, f"fc{i}/bwd"))
     return out
 
 
@@ -275,19 +274,8 @@ def loss_and_bucket_grads(params, batch, cfg: ArchConfig, tape,
             x, vjp = jax.vjp(fn, params[name], x)
         layer_tape.append((name, vjp))
 
-    def loss_part(logits):
-        logits = logits.astype(jnp.float32)
-        if uk:
-            from repro.kernels import ops as kops
-            return jnp.mean(kops.softmax_xent(logits, labels))
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        ll = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-        return jnp.mean(lse - ll)
-
-    loss, vjp_loss = jax.vjp(loss_part, x)
-    logits32 = x.astype(jnp.float32)
-    err = jnp.mean((jnp.argmax(logits32, -1) != labels).astype(jnp.float32))
-    metrics = {"ce": loss, "error_rate": err,
+    loss, vjp_loss = jax.vjp(lambda lg: _xent(lg, labels, uk), x)
+    metrics = {"ce": loss, "error_rate": _error_rate(x, labels),
                "aux": jnp.zeros((), jnp.float32)}
 
     (dy,) = vjp_loss(jnp.ones((), loss.dtype))
@@ -347,25 +335,11 @@ def loss_and_shard_bucket_grads(params, shards, cfg: ArchConfig, on_bucket,
             xs = jax.lax.map(lambda x, p=params[name], fn=fn: fn(p, x), xs)
         acts.append(xs)
 
-    if uk:
-        from repro.kernels import ops as kops
-
     def loss_and_dy(args):
         logits, lab = args
-
-        def loss_part(lg):
-            lg = lg.astype(jnp.float32)
-            if uk:
-                return jnp.mean(kops.softmax_xent(lg, lab))
-            lse = jax.nn.logsumexp(lg, axis=-1)
-            ll = jnp.take_along_axis(lg, lab[:, None], axis=-1)[:, 0]
-            return jnp.mean(lse - ll)
-
-        loss, vjp_loss = jax.vjp(loss_part, logits)
+        loss, vjp_loss = jax.vjp(lambda lg: _xent(lg, lab, uk), logits)
         (dy,) = vjp_loss(jnp.ones((), loss.dtype))
-        lg32 = logits.astype(jnp.float32)
-        err = jnp.mean((jnp.argmax(lg32, -1) != lab).astype(jnp.float32))
-        return loss, err, dy
+        return loss, _error_rate(logits, lab), dy
 
     losses, errs, dy = jax.lax.map(loss_and_dy, (xs, labels))
     metrics = {"ce": losses, "error_rate": errs,
@@ -391,18 +365,29 @@ def loss_and_shard_bucket_grads(params, shards, cfg: ArchConfig, on_bucket,
     return losses, metrics, grads
 
 
+def _xent(logits, labels, uk: bool):
+    """Mean softmax cross-entropy over the batch, in float32, under the
+    ``loss`` scope."""
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        if uk:
+            from repro.kernels import ops as kops
+            return jnp.mean(kops.softmax_xent(logits, labels))
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+        return jnp.mean(lse - ll)
+
+
+def _error_rate(logits, labels):
+    with jax.named_scope("loss"):
+        wrong = jnp.argmax(logits.astype(jnp.float32), -1) != labels
+        return jnp.mean(wrong.astype(jnp.float32))
+
+
 def loss_fn(params, batch, cfg: ArchConfig, use_kernel: bool | None = None):
     uk = _use_kernel(cfg, use_kernel)
     logits = forward(params, batch["images"], cfg, use_kernel=uk)
-    labels = batch["labels"]
-    logits = logits.astype(jnp.float32)
-    if uk:
-        from repro.kernels import ops as kops
-        loss = jnp.mean(kops.softmax_xent(logits, labels))
-    else:
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        ll = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-        loss = jnp.mean(lse - ll)
-    err = jnp.mean((jnp.argmax(logits, -1) != labels).astype(jnp.float32))
-    return loss, {"ce": loss, "error_rate": err,
+    loss = _xent(logits, batch["labels"], uk)
+    return loss, {"ce": loss, "error_rate": _error_rate(logits,
+                                                        batch["labels"]),
                   "aux": jnp.zeros((), jnp.float32)}

@@ -1,7 +1,9 @@
-"""Observability subsystem (DESIGN.md §11): span tracing with Perfetto
-export (`obs.trace`) and the counters/gauges/histograms metrics bus
-(`obs.metrics`).  Zero-cost when unused: no tracer installed ⇒ nothing is
-inserted into any compiled graph or hot loop."""
+"""Observability subsystem (DESIGN.md §11): host spans on the profiler's
+clock with Perfetto export and the naming contract of the compiled step's
+scopes (`obs.trace`), and the counters/gauges/histograms metrics bus
+(`obs.metrics`).  Nothing is inserted into any compiled graph; a span
+with no profiler running and no tracer installed is one sub-microsecond
+annotation."""
 from repro.obs.metrics import JsonlSink, MetricsBus
 from repro.obs.trace import Tracer, get_tracer, set_tracer, span
 

@@ -1,76 +1,166 @@
-"""Span tracer (DESIGN.md §11): host-side nestable spans + in-dispatch
-per-bucket exchange stamps, exported as Chrome-trace/Perfetto ``trace.json``
-and a flat JSONL.
+"""Span tracer and the naming contract of the compiled step (DESIGN.md §11).
 
-Two event sources share one clock (`core/chaos.py`'s deadline epoch, so
-trace timestamps and injected-latency deadlines line up exactly):
-
-- **host spans** — `Tracer.span(...)` context manager around driver-side
-  phases (``superstep``, ``prefill``, ``decode``, ``checkpoint``,
-  ``resize``, ``autotune``).  Cost: one ``time.monotonic()`` pair + a dict
-  append; nesting is Perfetto's native stacking of overlapping complete
-  events on one track.
-
-- **device stamps** — ``bucket_issue``/``bucket_gate`` reuse the PR-7
-  ``pure_callback`` deadline machinery (``core/chaos.py``): the issue
-  callback fires the moment a bucket's gradient exists mid-backward and
-  returns the f32 deadline token (``now + delay_ms``, ms since the chaos
-  epoch — the SAME token ``delay_gate`` consumes), recording the issue
-  time; the gate callback sleeps the deadline remainder (0 when no latency
-  is injected) and records ``[gate_start, gate_end]`` plus the residual
-  actually slept.  With ``delay_ms > 0`` the pair IS the injection — the
-  traced path never double-charges.  ``finalize()`` pairs the i-th issue
-  with the i-th gate per (bucket, worker) — one issue and one gate per
-  step, steps are sequential inside the scan — yielding per-bucket
-  ``exchange/<bucket>`` spans (issue → gate end, the in-flight window) and
-  ``exchange_wait/<bucket>`` spans (the gate's critical-path sleep, whose
-  per-step sum is the measured exchange cost BENCH_overlap.json calls
-  ``exchange_us``).
+**Host spans** — ``Tracer.span`` and the module-level ``span`` around
+training-loop phases (``superstep`` with its children ``dispatch`` and
+``loss_readback``; ``feed/wait``, ``feed/build``, ``feed/put``;
+``checkpoint``, ``resize``, ``prefill``, ``decode``, ``autotune``), and
+``Tracer.open``/``Tracer.complete`` for a lifecycle that ends in a later
+call (a serve request, submit to evict).  Every span enters a
+``jax.profiler.TraceAnnotation``, installed tracer or not, so under
+``jax.profiler.start_trace`` it sits on the profiler's host plane beside
+the device operations (an annotation costs well under a microsecond when
+no profiler runs).  An installed ``Tracer`` also keeps its own copy for a
+Chrome-trace/Perfetto ``trace.json``, stamped on the clock the profiler's
+host events use (``time.time_ns``), so the two overlay.
 
 Track layout (Perfetto): pid per subsystem (``train`` / ``serve`` /
-``bench``), tid 0 = the host thread (``driver`` / ``engine``), tid 1+ one
-per worker (``worker0..N``) or slot (``slot0..S``).  Span args carry
-bytes, bucket name, τ, and injected delay.
+``bench``), tid 0 = the host thread (``driver`` / ``engine``), further
+tids per thread name (``feed``, ``slot0..S``).
 
-When no tracer is installed (``get_tracer() is None``) nothing is inserted
-anywhere — the compiled graph, and therefore every bit-exactness pin, is
-byte-identical to a no-obs build.
+**Device scopes** — the compiled step names its work with
+``jax.named_scope``; the HLO keeps the scope in each instruction's
+``op_name``:
+
+======================  ==================================================
+``{kind}{i}``           a Table-2 layer (``conv0``, ``pool1``, ... ``fc7``,
+                        the names ``bucket_spec`` uses); forward under
+                        autodiff reads ``jvp(conv2)/...``
+backward of a layer     ``transpose(jvp(conv2))/...`` (autodiff, custom
+                        VJPs included) or ``conv2/bwd/...`` (the explicit
+                        saved-activation tape)
+``loss``                the softmax cross-entropy and the error rate
+``update``              what a sync strategy does once the gradients
+                        exist: exchange, stale mixing, optimizer, boundary
+``exchange/{bucket}``   one bucket's gradient exchange (inside ``update``,
+                        or mid-backward on the interleaved schedule)
+======================  ==================================================
+
+``scope_of`` reads one ``op_name``; ``hlo_scopes`` a compiled module's
+text.  Nothing here runs inside a compiled step: no host callback is ever
+inserted, installed tracer or not.
 """
 from __future__ import annotations
 
 import json
+import re
 import threading
 import time
 from contextlib import contextmanager
-from functools import partial
 from typing import Optional
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.core.chaos import _EPOCH, _first_scalar
+_LAYER = re.compile(r"(?:conv|pool|fc)\d+")
+_WRAPPED = re.compile(r"(\w+)\((.*)\)")
+
+
+def _unwrap(part: str) -> tuple[str, bool]:
+    """``transpose(jvp(conv2))`` -> ``("conv2", True)``: the scope inside
+    JAX's transformation wrappers, and whether one of them is a
+    transpose (the backward pass)."""
+    transposed = False
+    m = _WRAPPED.fullmatch(part)
+    while m:
+        transposed |= m.group(1) == "transpose"
+        part = m.group(2)
+        m = _WRAPPED.fullmatch(part)
+    return part, transposed
+
+
+def scope_of(op_name: str) -> Optional[tuple[str, str]]:
+    """``(scope, "fwd" | "bwd")`` of an HLO ``op_name``, or None when it
+    carries no scope of the contract above.  The innermost scope wins
+    (``update/exchange/conv2/...`` is ``exchange/conv2``); of a fused
+    ``a;b`` name, the first part that has one."""
+    for name in op_name.split(";"):
+        parts = [_unwrap(p) for p in name.split("/")]
+        found, transposed, i = None, False, 0
+        while i < len(parts):
+            part, t = parts[i]
+            transposed |= t
+            nxt = parts[i + 1][0] if i + 1 < len(parts) else None
+            if part == "exchange" and nxt is not None:
+                found = (f"exchange/{nxt}", transposed)
+                i += 1
+            elif _LAYER.fullmatch(part) or part in ("loss", "update"):
+                found = (part, transposed or nxt == "bwd")
+            i += 1
+        if found is not None:
+            return found[0], "bwd" if found[1] else "fwd"
+    return None
+
+
+_INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def hlo_scopes(hlo_text: str) -> dict[str, Optional[tuple[str, str]]]:
+    """{instruction name: ``scope_of`` its op_name} for every instruction
+    of a compiled HLO module's text.  A fusion takes the scope of its
+    root; where the root has none (a ``lax.map`` writing a weight gradient
+    into its stack), of its convolution or dot member, or else of its
+    first scoped member."""
+    own, calls, opcode = {}, {}, {}
+    members: dict[str, list[str]] = {}
+    root: dict[str, str] = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and " = " not in line:
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+            members[comp] = []
+            continue
+        m = _INSTR.match(line)
+        if not m or comp is None:
+            continue
+        is_root, name, rest = m.groups()
+        op = _OPCODE.search(" " + rest.split(", metadata=")[0])
+        opcode[name] = op.group(1) if op else ""
+        on = _OP_NAME.search(rest)
+        own[name] = scope_of(on.group(1)) if on else None
+        members[comp].append(name)
+        if is_root:
+            root[comp] = name
+        c = _CALLS.search(rest) if opcode[name] == "fusion" else None
+        if c:
+            calls[name] = c.group(1)
+
+    memo: dict = {}
+
+    def resolved(n):
+        if n not in memo:
+            memo[n] = own[n]
+            body = calls.get(n)
+            if body is not None:
+                inner = members.get(body, [])
+                heavy = [x for x in inner
+                         if opcode[x] in ("convolution", "dot")]
+                first = [root[body]] if body in root else []
+                memo[n] = next((s for s in map(resolved, first + heavy
+                                               + inner) if s), own[n])
+        return memo[n]
+
+    return {n: resolved(n) for n in own}
 
 
 def _now_us() -> float:
-    """Microseconds since the chaos deadline epoch (shared clock)."""
-    return (time.monotonic() - _EPOCH) * 1e6
+    """Microseconds on the clock of the profiler's host events (since the
+    Unix epoch), so ``trace.json`` overlays the ``.xplane.pb``."""
+    return time.time_ns() * 1e-3
 
 
 class Tracer:
     """Collects events in memory; ``write()`` exports trace.json + .jsonl.
 
-    Thread-safe: host spans come from the driver thread, device stamps from
-    XLA host-callback threads (one per forced-host device), serve spans
-    from the engine loop.
-    """
+    Thread-safe: spans come from the driver thread, the feed's producer
+    thread and the serve engine loop."""
 
     def __init__(self, process: str = "train"):
         self.default_process = process
         self._lock = threading.Lock()
         self._events: list = []          # chrome "X"/"i"/"C" dicts
-        self._device: list = []          # raw issue/gate stamp records
-        self._tag_args: dict = {}        # bucket tag -> static args
         self._pids: dict = {}            # process name -> pid
         self._tids: dict = {}            # (pid, thread name) -> tid
 
@@ -85,166 +175,69 @@ class Tracer:
                 self._tids[key] = (max(used) + 1) if used else 0
             return pid, self._tids[key]
 
+    def _record(self, ev: dict, process, thread, args):
+        ev["pid"], ev["tid"] = self._track(process, thread)
+        if args:
+            ev["args"] = args
+        with self._lock:
+            self._events.append(ev)
+
     # -- host spans ---------------------------------------------------------
     @contextmanager
     def span(self, name: str, *, process: Optional[str] = None,
              thread: str = "driver", cat: str = "host", **args):
-        t0 = _now_us()
-        try:
-            yield self
-        finally:
-            t1 = _now_us()
-            pid, tid = self._track(process, thread)
-            ev = {"name": name, "ph": "X", "ts": t0, "dur": t1 - t0,
-                  "pid": pid, "tid": tid, "cat": cat}
-            if args:
-                ev["args"] = args
-            with self._lock:
-                self._events.append(ev)
+        with TraceAnnotation(name, **args):
+            t0 = _now_us()
+            try:
+                yield self
+            finally:
+                t1 = _now_us()
+                self._record({"name": name, "ph": "X", "ts": t0,
+                              "dur": t1 - t0, "cat": cat},
+                             process, thread, args)
 
-    def complete(self, name: str, t0_us: float, t1_us: float, *,
-                 process: Optional[str] = None, thread: str = "driver",
-                 cat: str = "host", **args):
-        """Record a span from explicit ``_now_us()``-clock endpoints (for
-        lifecycles that open in one call and close in another, e.g. a serve
-        request's admit→evict window)."""
-        pid, tid = self._track(process, thread)
-        ev = {"name": name, "ph": "X", "ts": t0_us, "dur": t1_us - t0_us,
-              "pid": pid, "tid": tid, "cat": cat}
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self._events.append(ev)
+    def open(self, name: str, **args) -> dict:
+        """Begin a span that ``complete`` ends in a later call (a serve
+        request's submit→evict window); its annotation starts now."""
+        return {"name": name, "ts": _now_us(), "args": args,
+                "annotation": TraceAnnotation(name, **args)}
+
+    def complete(self, opened: dict, *, process: Optional[str] = None,
+                 thread: str = "driver", cat: str = "host", **args):
+        """End a span begun by ``open``; ``args`` join the opening ones."""
+        opened["annotation"].__exit__(None, None, None)
+        self._record({"name": opened["name"], "ph": "X",
+                      "ts": opened["ts"], "dur": _now_us() - opened["ts"],
+                      "cat": cat}, process, thread,
+                     {**opened["args"], **args})
 
     def instant(self, name: str, *, process: Optional[str] = None,
                 thread: str = "driver", cat: str = "host", **args):
-        pid, tid = self._track(process, thread)
-        ev = {"name": name, "ph": "i", "s": "t", "ts": _now_us(),
-              "pid": pid, "tid": tid, "cat": cat}
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self._events.append(ev)
+        self._record({"name": name, "ph": "i", "s": "t", "ts": _now_us(),
+                      "cat": cat}, process, thread, args)
 
     def counter(self, name: str, value: float, *,
                 process: Optional[str] = None, thread: str = "driver"):
         """Chrome counter event — renders as a value track in Perfetto
         (e.g. per-superstep wall time, so a straggler is visible as a spike
         before any eviction fires)."""
-        pid, tid = self._track(process, thread)
-        with self._lock:
-            self._events.append({"name": name, "ph": "C", "ts": _now_us(),
-                                 "pid": pid, "tid": tid,
-                                 "args": {"value": float(value)}})
+        self._record({"name": name, "ph": "C", "ts": _now_us()},
+                     process, thread, {"value": float(value)})
 
-    def now_us(self) -> float:
-        return _now_us()
-
-    # -- in-dispatch device stamps (pure_callback, chaos deadline clock) ----
-    def _issue_cb(self, tag, widx, _anchor, delay_ms):
-        t = _now_us()
-        with self._lock:
-            self._device.append({"tag": tag, "phase": "issue",
-                                 "worker": int(widx), "t_us": t,
-                                 "delay_ms": float(delay_ms)})
-        # deadline token in ms since the chaos epoch — delay_gate-compatible
-        return np.float32(t * 1e-3 + float(delay_ms))
-
-    def _gate_cb(self, tag, deadline, widx, _anchor):
-        t0 = _now_us()
-        rem = (float(deadline) - t0 * 1e-3) * 1e-3
-        if rem > 0:
-            time.sleep(rem)
-        t1 = _now_us()
-        with self._lock:
-            self._device.append({"tag": tag, "phase": "gate",
-                                 "worker": int(widx), "t_us": t0,
-                                 "t_end_us": t1,
-                                 "slept_ms": max(rem, 0.0) * 1e3})
-        return np.float32(0.0)
-
-    def bucket_issue(self, anchor_tree, tag: str, delay_ms=0.0, worker=None,
-                     args: Optional[dict] = None):
-        """Issue stamp: fires when ``anchor_tree``'s first leaf is ready
-        (the exchange's issue point, mid-backward).  Returns the f32
-        deadline token, exactly like ``core.chaos.delay_start`` — with
-        ``delay_ms > 0`` the stamped deadline doubles as the injected
-        collective latency.  ``args`` (static per tag: bytes, τ, ...) land
-        on the exported spans."""
-        if args:
-            with self._lock:
-                self._tag_args.setdefault(tag, dict(args))
-        w = jnp.asarray(0 if worker is None else worker, jnp.int32)
-        return jax.pure_callback(
-            partial(self._issue_cb, tag),
-            jax.ShapeDtypeStruct((), np.float32),
-            w, _first_scalar(anchor_tree),
-            jnp.asarray(delay_ms, jnp.float32))
-
-    def bucket_gate(self, tree, token, anchor_tree, tag: str, worker=None):
-        """Gate stamp: once ``anchor_tree`` is ready, sleep ``token``'s
-        deadline remainder (0 when nothing was injected), record the gate
-        window, and pass ``tree`` through value-unchanged (the gate's 0.0
-        is added to the first leaf so XLA cannot eliminate or reorder it —
-        ``core.chaos.delay_gate``'s tie)."""
-        w = jnp.asarray(0 if worker is None else worker, jnp.int32)
-        z = jax.pure_callback(
-            partial(self._gate_cb, tag),
-            jax.ShapeDtypeStruct((), np.float32),
-            token, w, _first_scalar(anchor_tree))
-        leaves, treedef = jax.tree.flatten(tree)
-        leaves = [leaves[0] + z.astype(leaves[0].dtype)] + leaves[1:]
-        return jax.tree.unflatten(treedef, leaves)
-
-    # -- assembly / export --------------------------------------------------
-    def finalize(self) -> list:
-        """Pair issue/gate stamps into ``exchange``/``exchange_wait`` spans
-        on per-worker tracks; returns (and caches into the event list via
-        ``to_chrome``) the chrome dicts."""
-        by_key: dict = {}
-        with self._lock:
-            device = list(self._device)
-        for rec in device:
-            by_key.setdefault((rec["tag"], rec["worker"]),
-                              {"issue": [], "gate": []})[rec["phase"]] \
-                .append(rec)
-        out = []
-        for (tag, worker), recs in sorted(by_key.items()):
-            issues = sorted(recs["issue"], key=lambda r: r["t_us"])
-            gates = sorted(recs["gate"], key=lambda r: r["t_us"])
-            pid, tid = self._track(None, f"worker{worker}")
-            static = self._tag_args.get(tag, {})
-            for i, g in zip(issues, gates):
-                args = {"bucket": tag, "worker": worker,
-                        "slept_ms": g["slept_ms"],
-                        "delay_ms": i["delay_ms"], **static}
-                out.append({"name": f"exchange/{tag}", "ph": "X",
-                            "ts": i["t_us"],
-                            "dur": g["t_end_us"] - i["t_us"],
-                            "pid": pid, "tid": tid, "cat": "exchange",
-                            "args": args})
-                out.append({"name": f"exchange_wait/{tag}", "ph": "X",
-                            "ts": g["t_us"],
-                            "dur": g["t_end_us"] - g["t_us"],
-                            "pid": pid, "tid": tid, "cat": "exchange",
-                            "args": args})
-        return out
-
+    # -- export -------------------------------------------------------------
     def to_chrome(self) -> dict:
-        device = self.finalize()     # registers worker tracks before the
-        events = []                  # metadata snapshot below
+        events = []
         with self._lock:
             pids = dict(self._pids)
             tids = dict(self._tids)
-            host = list(self._events)
+            spans = list(self._events)
         for name, pid in pids.items():
             events.append({"ph": "M", "name": "process_name", "pid": pid,
                            "tid": 0, "args": {"name": name}})
         for (pid, tname), tid in tids.items():
             events.append({"ph": "M", "name": "thread_name", "pid": pid,
                            "tid": tid, "args": {"name": tname}})
-        events += host + device
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
+        return {"traceEvents": events + spans, "displayTimeUnit": "ms"}
 
     def write(self, path: str):
         """Write Chrome-trace JSON to ``path`` and a flat JSONL (one event
@@ -260,14 +253,13 @@ class Tracer:
               f"{path} (+ {jsonl})", flush=True)
 
 
-# -- module-global active tracer (build-time switch) ------------------------
+# -- module-global active tracer --------------------------------------------
 _ACTIVE: Optional[Tracer] = None
 
 
 def set_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
-    """Install (or clear, with None) the process-wide tracer.  Step builders
-    consult this AT BUILD TIME: functions compiled while it is None contain
-    no callbacks at all.  Returns the previous tracer."""
+    """Install (or clear, with None) the process-wide tracer that the
+    module-level ``span`` records into.  Returns the previous tracer."""
     global _ACTIVE
     prev, _ACTIVE = _ACTIVE, tracer
     return prev
@@ -279,10 +271,14 @@ def get_tracer() -> Optional[Tracer]:
 
 @contextmanager
 def span(name: str, **kw):
-    """No-op when no tracer is installed; otherwise ``Tracer.span``."""
+    """A profiler annotation always; ``Tracer.span`` on the installed
+    tracer too, when there is one (yields it, else None)."""
     t = _ACTIVE
     if t is None:
-        yield None
+        args = {k: v for k, v in kw.items()
+                if k not in ("process", "thread", "cat")}
+        with TraceAnnotation(name, **args):
+            yield None
     else:
         with t.span(name, **kw):
             yield t

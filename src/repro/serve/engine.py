@@ -211,7 +211,7 @@ class ServeEngine:
             seed if sample_seed is None else sample_seed)
         self.tracer = tracer
         self.bus = bus
-        self._submit_us: dict = {}           # rid -> submit time (trace µs)
+        self._submit_span: dict = {}         # rid -> open request span
         self._submit_t: dict = {}            # rid -> submit time.monotonic()
         vocab = self.cfg.vocab_size
         sampler, skey = self._sampler, self._sample_key
@@ -312,7 +312,8 @@ class ServeEngine:
             st = {"req": r, "out": [int(first[i, 0])],
                   "admit_step": self.step_idx, "t_first": now}
             if tr is not None:
-                st["t0_us"] = self._submit_us.pop(r.rid, tr.now_us())
+                st["span"] = (self._submit_span.pop(r.rid, None)
+                              or tr.open(f"request/{r.rid}", rid=r.rid))
             if bus is not None:
                 t_sub = self._submit_t.pop(r.rid, now)
                 bus.observe("serve/ttft_s", now - t_sub)
@@ -322,7 +323,8 @@ class ServeEngine:
     def submit(self, req: Request) -> None:
         self.kv.validate_admit(len(req.tokens), req.max_new)
         if self.tracer is not None:
-            self._submit_us[req.rid] = self.tracer.now_us()
+            self._submit_span[req.rid] = self.tracer.open(
+                f"request/{req.rid}", rid=req.rid)
         if self.bus is not None:
             self._submit_t[req.rid] = time.monotonic()
         self.pending.append(req)
@@ -339,11 +341,8 @@ class ServeEngine:
                     tokens=np.array(st["out"], np.int32),
                     admit_step=st["admit_step"], finish_step=self.step_idx))
                 if tr is not None:
-                    t1 = tr.now_us()
-                    tr.complete(f"request/{st['req'].rid}",
-                                st.get("t0_us", t1), t1,
-                                thread=f"slot{slot}", cat="serve",
-                                rid=st["req"].rid,
+                    tr.complete(st["span"], thread=f"slot{slot}",
+                                cat="serve",
                                 prompt_len=len(st["req"].tokens),
                                 generated=len(st["out"]))
                 if bus is not None:

@@ -11,6 +11,9 @@ later) is fully delegated to ``train/sync.py``: this module builds the
 execution-path ``StepContext`` (how gradients are produced and reduced) and
 the strategy supplies the step body — there are no per-mode branches here
 (DESIGN.md §5).
+
+Named scopes (``obs/trace.py``): everything after the gradients exist runs
+under ``update``, and each bucket's exchange under ``exchange/{bucket}``.
 """
 from __future__ import annotations
 
@@ -22,12 +25,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.chaos import (SyncConfig, delay_gate, delay_start,
-                              delay_tie, gathered_shard_mean)
+                              gathered_shard_mean)
 from repro.core.schedule import make_lr_fn
 from repro.core.types import ArchConfig, WorkerConfig
 from repro.models import layers as ML
 from repro.models.api import get_ops
-from repro.obs import trace as obs_trace
 from repro.optim import adamw, sgd
 from repro.train.sync import StepContext, get_strategy
 
@@ -166,6 +168,30 @@ def _apply_bucket(optimizer, bucket, params, g_b, opt_state, step):
     return new_p_b, optimizer.merge_state(opt_state, bucket.keys, new_st)
 
 
+def _exchange(exchange_bucket, bucket, g_b):
+    """One bucket's exchange under its ``exchange/{bucket}`` scope."""
+    with jax.named_scope(f"exchange/{bucket.name}"):
+        return exchange_bucket(bucket, g_b)
+
+
+def _exchange_by_bucket(reduce, spec):
+    """``reduce`` of a params-shaped tree taken bucket by bucket, each
+    under its ``exchange/{bucket}`` scope (the non-layerwise strategies
+    exchange the whole gradient tree in one call); any other tree — one
+    bucket's slice, the step's metrics — is reduced whole.  ``reduce``
+    works leaf by leaf, so the split changes no value."""
+    keys = {k for b in spec for k in b.keys}
+
+    def combine(tree):
+        if not isinstance(tree, dict) or set(tree) != keys:
+            return reduce(tree)
+        out = {}
+        for b in spec:
+            out.update(_exchange(lambda _, t: reduce(t), b, b.view(tree)))
+        return out
+    return combine
+
+
 def _bucket_walk(spec, optimizer, exchange_bucket, params, opt_state, grads,
                  step):
     """Collect-then-walk flavour of the bucket tape (reverse-production
@@ -178,14 +204,15 @@ def _bucket_walk(spec, optimizer, exchange_bucket, params, opt_state, grads,
     opt = opt_state
     if optimizer.pre_apply is None:
         for bucket in reversed(spec):
-            g_ex = exchange_bucket(bucket, bucket.view(grads))
+            g_ex = _exchange(exchange_bucket, bucket, bucket.view(grads))
             new_p_b, opt = _apply_bucket(optimizer, bucket, new_params,
                                          g_ex, opt, step)
             new_params.update(new_p_b)
         return new_params, opt
     exchanged = {}
     for bucket in reversed(spec):
-        exchanged.update(exchange_bucket(bucket, bucket.view(grads)))
+        exchanged.update(_exchange(exchange_bucket, bucket,
+                                   bucket.view(grads)))
     exchanged = optimizer.pre_apply(exchanged)
     for bucket in reversed(spec):
         new_p_b, opt = _apply_bucket(optimizer, bucket, new_params,
@@ -218,13 +245,15 @@ def _make_bucket_step(cfg: ArchConfig, sync: SyncConfig, strat, ops,
     acc_grad_fn = _make_grad_fn(cfg, ops) if n_micro > 1 else None
 
     def step(state, batch):
-        exchange_bucket, finish = strat.bucket_exchange(ctx, state["sync"],
-                                                        state["step"])
+        with jax.named_scope("update"):
+            exchange_bucket, finish = strat.bucket_exchange(
+                ctx, state["sync"], state["step"])
         if n_micro > 1:
             loss, metrics, grads = acc_grad_fn(state["params"], batch)
-            new_params, new_opt = _bucket_walk(
-                spec, optimizer, exchange_bucket, state["params"],
-                state["opt"], grads, state["step"])
+            with jax.named_scope("update"):
+                new_params, new_opt = _bucket_walk(
+                    spec, optimizer, exchange_bucket, state["params"],
+                    state["opt"], grads, state["step"])
         elif optimizer.pre_apply is None:
             # true tape: each bucket's exchange + update fires inside the
             # backward walk, the moment that bucket's gradient is produced
@@ -232,10 +261,11 @@ def _make_bucket_step(cfg: ArchConfig, sync: SyncConfig, strat, ops,
 
             def on_bucket(bucket, p_b, g_b):
                 del p_b  # the walk's running params are in new_params
-                g_ex = exchange_bucket(bucket, g_b)
-                new_p_b, opt_box[0] = _apply_bucket(
-                    optimizer, bucket, state["params"], g_ex, opt_box[0],
-                    state["step"])
+                with jax.named_scope("update"):
+                    g_ex = _exchange(exchange_bucket, bucket, g_b)
+                    new_p_b, opt_box[0] = _apply_bucket(
+                        optimizer, bucket, state["params"], g_ex,
+                        opt_box[0], state["step"])
                 return new_p_b
 
             loss, metrics, new_params, grads = ops.loss_and_grads(
@@ -247,12 +277,14 @@ def _make_bucket_step(cfg: ArchConfig, sync: SyncConfig, strat, ops,
             # walk the per-bucket updates in the same reverse order
             loss, metrics, grads = ops.loss_and_grads(state["params"],
                                                       batch)
-            new_params, new_opt = _bucket_walk(
-                spec, optimizer, exchange_bucket, state["params"],
-                state["opt"], grads, state["step"])
-        new_sync = finish(grads)
-        new_params, new_sync = strat.boundary(ctx, new_params, new_sync,
-                                              state["step"])
+            with jax.named_scope("update"):
+                new_params, new_opt = _bucket_walk(
+                    spec, optimizer, exchange_bucket, state["params"],
+                    state["opt"], grads, state["step"])
+        with jax.named_scope("update"):
+            new_sync = finish(grads)
+            new_params, new_sync = strat.boundary(ctx, new_params, new_sync,
+                                                  state["step"])
         new_state = {"params": new_params, "opt": new_opt,
                      "sync": new_sync, "step": state["step"] + 1}
         return new_state, {**metrics, "loss": loss}
@@ -333,12 +365,14 @@ def make_worker_train_step(cfg: ArchConfig, sync: SyncConfig,
     # for the uncompressed f32 path; with per-shard bf16 compression the
     # stacks arrive bf16 and must not sum in bf16)
     delay = sync.collective_delay_ns_per_byte
+    spec = ops.bucket_spec()
     ctx = StepContext(
         optimizer=optimizer, grad_fn=shard_grads,
         # blocking delay injection (the synchronous-exchange model) lives
         # here, at the gather; delay == 0 leaves the graph untouched
-        combine=lambda t: gathered_shard_mean(t, axis, N, S,
-                                              delay_ns_per_byte=delay),
+        combine=_exchange_by_bucket(
+            lambda t: gathered_shard_mean(t, axis, N, S,
+                                          delay_ns_per_byte=delay), spec),
         local_mean=lambda t: jax.tree.map(
             lambda x: jnp.sum(x.astype(jnp.float32), 0) / s_local, t),
         # sum * (1/S), NOT sum / S: gathered_shard_mean multiplies by the
@@ -350,7 +384,6 @@ def make_worker_train_step(cfg: ArchConfig, sync: SyncConfig,
         explicit_workers=True, axis=axis, n_workers=N)
 
     if sync.layerwise:
-        spec = ops.bucket_spec()
         # interleaved schedule (DESIGN.md §8): fire each bucket's exchange
         # collective the moment that layer's stacked gradient is produced
         # during backprop, via the model's shard tape.  Needs a per-leaf
@@ -372,76 +405,56 @@ def make_worker_train_step(cfg: ArchConfig, sync: SyncConfig,
             # per-shard gradient bytes (bf16 on the compressed wire)
             itemsize = 2 if sync.compress else 4
             abstract = ops.abstract_params()
-            bucket_bytes = {
+            bucket_ms = {
                 b.name: S * sum(l.size * itemsize for l in
                                 jax.tree.leaves(b.view(abstract)))
-                for b in spec}
-            bucket_ms = {name: nbytes * delay * 1e-6
-                         for name, nbytes in bucket_bytes.items()}
+                * delay * 1e-6 for b in spec}
             inject = delay > 0 and N > 1 and strat.bucket_exchange_gathers
-            # per-bucket exchange stamps (obs, DESIGN.md §11): when a tracer
-            # is installed AT BUILD TIME, the issue/gate pair is routed
-            # through it — the tracer's callbacks stamp event times AND
-            # carry the same deadline token, so tracing + injection share
-            # one callback pair (never double-charged).  No tracer ⇒ this
-            # whole branch compiles exactly as before.
-            tracer = obs_trace.get_tracer()
-            stamp = (tracer is not None and N > 1
-                     and strat.bucket_exchange_gathers)
 
             def bucket_step(state, batch):
-                exchange_bucket, finish = strat.bucket_exchange(
-                    ctx_i, state["sync"], state["step"])
+                with jax.named_scope("update"):
+                    exchange_bucket, finish = strat.bucket_exchange(
+                        ctx_i, state["sync"], state["step"])
                 shards = jax.tree.map(
                     lambda x: x.reshape((s_local, x.shape[0] // s_local)
                                         + x.shape[1:]), batch)
-                widx = jax.lax.axis_index(axis) if stamp else None
                 exchanged = {}
 
                 def on_bucket(bucket, g_b):
-                    g_ex = exchange_bucket(bucket, g_b)
-                    # deadline stamped when this bucket's gradient exists =
-                    # the collective's issue point, mid-backward
-                    if stamp:
-                        tok = tracer.bucket_issue(
-                            g_b, bucket.name,
-                            delay_ms=bucket_ms[bucket.name] if inject
-                            else 0.0,
-                            worker=widx,
-                            args={"bytes": bucket_bytes[bucket.name],
-                                  "tau": sync.staleness,
-                                  "schedule": "interleave"})
-                    elif inject:
-                        tok = delay_start(g_b, bucket_ms[bucket.name])
-                    else:
-                        tok = None
+                    with jax.named_scope(f"exchange/{bucket.name}"):
+                        g_ex = exchange_bucket(bucket, g_b)
+                        # deadline stamped when this bucket's gradient
+                        # exists = the collective's issue point,
+                        # mid-backward
+                        tok = (delay_start(g_b, bucket_ms[bucket.name])
+                               if inject else None)
                     exchanged[bucket.name] = (g_ex, tok)
                     return tok
 
                 losses, metrics, grads = ops.shard_bucket_grads(
                     state["params"], shards, on_bucket)
-                # gates anchor on the LAST-produced gradient: each bucket
-                # sleeps only what remains of its deadline after the rest
-                # of the backward walk ran — latency hidden behind compute
-                anchor = grads[spec[0].name]
-                new_params = dict(state["params"])
-                new_opt = state["opt"]
-                for bucket in reversed(spec):
-                    g_ex, tok = exchanged[bucket.name]
-                    if tok is not None and stamp:
-                        g_ex = tracer.bucket_gate(g_ex, tok, anchor,
-                                                  bucket.name, worker=widx)
-                    elif tok is not None:
-                        g_ex = delay_gate(g_ex, tok, anchor)
-                    new_p_b, new_opt = _apply_bucket(
-                        optimizer, bucket, new_params, g_ex, new_opt,
-                        state["step"])
-                    new_params.update(new_p_b)
-                new_sync = finish(grads)
-                new_params, new_sync = strat.boundary(
-                    ctx_i, new_params, new_sync, state["step"])
-                return strat.finish_step(ctx_i, state, new_params, new_opt,
-                                         new_sync, losses, metrics)
+                with jax.named_scope("update"):
+                    # gates anchor on the LAST-produced gradient: each
+                    # bucket sleeps only what remains of its deadline after
+                    # the rest of the backward walk ran — latency hidden
+                    # behind compute
+                    anchor = grads[spec[0].name]
+                    new_params = dict(state["params"])
+                    new_opt = state["opt"]
+                    for bucket in reversed(spec):
+                        g_ex, tok = exchanged[bucket.name]
+                        if tok is not None:
+                            g_ex = delay_gate(g_ex, tok, anchor)
+                        new_p_b, new_opt = _apply_bucket(
+                            optimizer, bucket, new_params, g_ex, new_opt,
+                            state["step"])
+                        new_params.update(new_p_b)
+                    new_sync = finish(grads)
+                    new_params, new_sync = strat.boundary(
+                        ctx_i, new_params, new_sync, state["step"])
+                    return strat.finish_step(ctx_i, state, new_params,
+                                             new_opt, new_sync, losses,
+                                             metrics)
 
             return bucket_step
 
@@ -453,45 +466,20 @@ def make_worker_train_step(cfg: ArchConfig, sync: SyncConfig,
         # dividing logical_shards); with delay injection each bucket's
         # gather charge lands synchronously inside the walk (the baseline
         # benchmarks/overlap.py measures the interleaved tape against)
-        tracer = obs_trace.get_tracer()
-        stamp = (tracer is not None and N > 1
-                 and strat.bucket_exchange_gathers)
-        if stamp:
-            itemsize = 2 if sync.compress else 4
-            abstract = ops.abstract_params()
-            bucket_bytes = {
-                b.name: S * sum(l.size * itemsize for l in
-                                jax.tree.leaves(b.view(abstract)))
-                for b in spec}
-
         def bucket_step(state, batch):
-            exchange_bucket, finish = strat.bucket_exchange(
-                ctx, state["sync"], state["step"])
-            if stamp:
-                # wrap each bucket's exchange in an issue/gate stamp pair:
-                # the span covers the gather (and, with --collective-delay,
-                # the blocking charge gathered_shard_mean injects inside it)
-                widx = jax.lax.axis_index(axis)
-                inner_exchange = exchange_bucket
-
-                def exchange_bucket(bucket, g_b):
-                    tok = tracer.bucket_issue(
-                        g_b, bucket.name, worker=widx,
-                        args={"bytes": bucket_bytes[bucket.name],
-                              "tau": sync.staleness,
-                              "schedule": "collect"})
-                    g_ex = inner_exchange(bucket, delay_tie(g_b, tok))
-                    return tracer.bucket_gate(g_ex, tok, g_ex, bucket.name,
-                                              worker=widx)
+            with jax.named_scope("update"):
+                exchange_bucket, finish = strat.bucket_exchange(
+                    ctx, state["sync"], state["step"])
             losses, metrics, grads = ctx.grad_fn(state["params"], batch)
-            new_params, new_opt = _bucket_walk(
-                spec, optimizer, exchange_bucket, state["params"],
-                state["opt"], grads, state["step"])
-            new_sync = finish(grads)
-            new_params, new_sync = strat.boundary(ctx, new_params, new_sync,
-                                                  state["step"])
-            return strat.finish_step(ctx, state, new_params, new_opt, new_sync,
-                                 losses, metrics)
+            with jax.named_scope("update"):
+                new_params, new_opt = _bucket_walk(
+                    spec, optimizer, exchange_bucket, state["params"],
+                    state["opt"], grads, state["step"])
+                new_sync = finish(grads)
+                new_params, new_sync = strat.boundary(
+                    ctx, new_params, new_sync, state["step"])
+                return strat.finish_step(ctx, state, new_params, new_opt,
+                                         new_sync, losses, metrics)
 
         return bucket_step
 

@@ -343,14 +343,16 @@ class BspStrategy:
     # -- the step body ---------------------------------------------------
     def step(self, ctx: StepContext, state, batch):
         losses, metrics, grads = ctx.grad_fn(state["params"], batch)
-        grads, new_sync = self._maybe_compress(ctx, grads, state["sync"])
-        g = self._reduce(ctx, grads)
-        new_params, new_opt = ctx.optimizer.apply(
-            state["params"], g, state["opt"], state["step"])
-        new_params, new_sync = self.boundary(ctx, new_params, new_sync,
-                                             state["step"])
-        return self.finish_step(ctx, state, new_params, new_opt, new_sync,
-                            losses, metrics)
+        with jax.named_scope("update"):
+            grads, new_sync = self._maybe_compress(ctx, grads,
+                                                   state["sync"])
+            g = self._reduce(ctx, grads)
+            new_params, new_opt = ctx.optimizer.apply(
+                state["params"], g, state["opt"], state["step"])
+            new_params, new_sync = self.boundary(ctx, new_params, new_sync,
+                                                 state["step"])
+            return self.finish_step(ctx, state, new_params, new_opt,
+                                    new_sync, losses, metrics)
 
     # -- per-bucket exchange (the layerwise path, DESIGN.md §6) ----------
     def bucket_exchange(self, ctx: StepContext, sync_state, step):
@@ -571,15 +573,18 @@ class ChaosStrategy(BspStrategy):
         reduction gates only the step OUTPUT (overlappable)."""
         tau = self.sync.staleness
         hist = state["sync"]["hist"]
-        stale = ring_read(hist, state["step"], tau)
-        new_params, new_opt = ctx.optimizer.apply(
-            state["params"], stale, state["opt"], state["step"])
+        with jax.named_scope("update"):
+            stale = ring_read(hist, state["step"], tau)
+            new_params, new_opt = ctx.optimizer.apply(
+                state["params"], stale, state["opt"], state["step"])
         losses, metrics, grads = ctx.grad_fn(new_params, batch)
-        grads, new_sync = self._maybe_compress(ctx, grads, state["sync"])
-        new_sync["hist"] = ring_write(hist, state["step"], tau,
-                                      ctx.combine(grads))
-        return self.finish_step(ctx, state, new_params, new_opt, new_sync,
-                            losses, metrics)
+        with jax.named_scope("update"):
+            grads, new_sync = self._maybe_compress(ctx, grads,
+                                                   state["sync"])
+            new_sync["hist"] = ring_write(hist, state["step"], tau,
+                                          ctx.combine(grads))
+            return self.finish_step(ctx, state, new_params, new_opt,
+                                    new_sync, losses, metrics)
 
     def _hogwild_step(self, ctx: StepContext, state, batch):
         """Worker mesh: own term instant + remote terms τ steps stale.
@@ -589,21 +594,24 @@ class ChaosStrategy(BspStrategy):
         tau = self.sync.staleness
         hist = state["sync"]["hist"]
         losses, metrics, grads = ctx.grad_fn(state["params"], batch)
-        grads, new_sync = self._maybe_compress(ctx, grads, state["sync"])
-        own = ctx.local_frac(grads)
-        stale_remote = ring_read(hist, state["step"], tau)
-        g = jax.tree.map(lambda o, s: o + s.astype(jnp.float32),
-                         own, stale_remote)
-        new_params, new_opt = ctx.optimizer.apply(
-            state["params"], g, state["opt"], state["step"])
-        # this step's remote term: the all_gather'd global mean minus the
-        # own term — it gates only the ring write (the step output), never
-        # this step's update
-        remote_now = jax.tree.map(lambda a, o: a - o, ctx.combine(grads),
-                                  own)
-        new_sync["hist"] = ring_write(hist, state["step"], tau, remote_now)
-        return self.finish_step(ctx, state, new_params, new_opt, new_sync,
-                            losses, metrics)
+        with jax.named_scope("update"):
+            grads, new_sync = self._maybe_compress(ctx, grads,
+                                                   state["sync"])
+            own = ctx.local_frac(grads)
+            stale_remote = ring_read(hist, state["step"], tau)
+            g = jax.tree.map(lambda o, s: o + s.astype(jnp.float32),
+                             own, stale_remote)
+            new_params, new_opt = ctx.optimizer.apply(
+                state["params"], g, state["opt"], state["step"])
+            # this step's remote term: the all_gather'd global mean minus
+            # the own term — it gates only the ring write (the step
+            # output), never this step's update
+            remote_now = jax.tree.map(lambda a, o: a - o,
+                                      ctx.combine(grads), own)
+            new_sync["hist"] = ring_write(hist, state["step"], tau,
+                                          remote_now)
+            return self.finish_step(ctx, state, new_params, new_opt,
+                                    new_sync, losses, metrics)
 
     def bucket_exchange(self, ctx: StepContext, sync_state, step):
         """Layerwise chaos (paper §3 order): the forward pass runs at the

@@ -1,6 +1,7 @@
-"""Observability subsystem (DESIGN.md §11): tracer export format, stamp
-pairing, metrics bus semantics, the PR-6 metrics-out schema fold, and the
-two overhead pins — obs off is bit-exact, obs on costs <= 2%."""
+"""Observability subsystem (DESIGN.md §11): tracer export format, spans
+on the profiler's host plane and clock, metrics bus semantics, the PR-6
+metrics-out schema fold, and the two overhead pins — obs off is
+bit-exact, obs on costs <= 2%."""
 import json
 import os
 import subprocess
@@ -29,8 +30,9 @@ def test_tracer_chrome_export(tmp_path):
             pass
     tr.instant("fault", kind="kill")
     tr.counter("watchdog/superstep_s", 0.25)
-    tr.complete("request/7", 100.0, 250.0, process="serve", thread="slot0",
-                rid=7)
+    req = tr.open("request/7", rid=7)
+    time.sleep(0.002)
+    tr.complete(req, process="serve", thread="slot0", generated=3)
     path = tmp_path / "trace.json"
     tr.write(str(path))
 
@@ -55,46 +57,12 @@ def test_tracer_chrome_export(tmp_path):
     assert ckpt["ts"] + ckpt["dur"] <= sup["ts"] + sup["dur"] + 1e-3
     assert by_name["fault"]["ph"] == "i"
     assert by_name["watchdog/superstep_s"]["ph"] == "C"
-    assert by_name["request/7"]["dur"] == pytest.approx(150.0)
+    assert by_name["request/7"]["dur"] >= 2e3                # us
+    assert by_name["request/7"]["args"] == {"rid": 7, "generated": 3}
     # the sibling JSONL has one event per line
     lines = (tmp_path / "trace.jsonl").read_text().splitlines()
     assert len(lines) == len(evs)
     assert all(json.loads(ln) for ln in lines)
-
-
-def test_tracer_stamp_pairing():
-    """bucket_issue/bucket_gate inside a jitted function pair into
-    exchange/exchange_wait spans, and an injected delay is actually slept
-    by the gate (the PR-7 deadline contract)."""
-    tr = Tracer("train")
-
-    @jax.jit
-    def f(x):
-        g = x * 2.0
-        tok = tr.bucket_issue(g, "conv0", delay_ms=30.0,
-                              args={"bytes": 128, "tau": 0})
-        g = tr.bucket_gate(g, tok, g, "conv0")
-        return g
-
-    x = jnp.ones((4,))
-    t0 = time.monotonic()
-    y1 = jax.block_until_ready(f(x))
-    y2 = jax.block_until_ready(f(x))
-    dt = time.monotonic() - t0
-    np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
-    np.testing.assert_allclose(np.asarray(y1), 2.0)  # value-preserving
-    assert dt >= 0.05                                # 2 x 30ms slept
-
-    spans = tr.finalize()
-    ex = [e for e in spans if e["name"] == "exchange/conv0"]
-    wait = [e for e in spans if e["name"] == "exchange_wait/conv0"]
-    assert len(ex) == len(wait) == 2
-    for e in ex + wait:
-        assert e["args"]["bucket"] == "conv0"
-        assert e["args"]["bytes"] == 128
-    for w in wait:
-        assert w["args"]["slept_ms"] == pytest.approx(30.0, rel=0.5)
-        assert w["dur"] >= 25e3                      # us
 
 
 def test_tracer_global_install():
@@ -112,6 +80,62 @@ def test_tracer_global_install():
     finally:
         set_tracer(prev)
     assert get_tracer() is None
+
+
+def _xplane_spans(trace_dir, names):
+    """{name: [(start, end) ns since the Unix epoch]} of the host events
+    with those names in the profile written under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    paths = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(paths) == 1
+    data = ProfileData.from_file(paths[0])
+    env = data.find_plane_with_name("Task Environment")
+    t0 = dict(env.stats)["profile_start_time"]
+    out = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in names:
+                        out.setdefault(ev.name, []).append(
+                            (t0 + ev.start_ns, t0 + ev.end_ns))
+    return out
+
+
+def test_spans_on_the_profiler_host_plane(tmp_path):
+    """Under ``jax.profiler``: an obs span and the feed's spans from a
+    short PrefetchFeed run sit on the host plane, without a tracer too,
+    and a tracer's trace.json start agrees with the profile's within 1 ms
+    (one clock)."""
+    from repro.data.pipeline import ImagePipeline
+    from repro.launch.train import PrefetchFeed, superstep_schedule
+
+    imgs = np.zeros((16, 29, 29, 1), np.float32)
+    labels = np.zeros((16,), np.int32)
+    pipe = ImagePipeline(imgs, labels, batch=4, seed=0, sample_mode="queue")
+    tr = Tracer("train")
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        for _ in PrefetchFeed(pipe, superstep_schedule(0, 6, 2)):
+            pass
+        with obs_trace.span("checkpoint", step=3):   # no tracer installed
+            time.sleep(0.002)
+        with tr.span("superstep", step_start=0, k=2):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    got = _xplane_spans(str(tmp_path / "prof"),
+                        {"feed/wait", "feed/build", "feed/put",
+                         "checkpoint", "superstep"})
+    assert len(got["feed/build"]) == len(got["feed/put"]) == 3
+    assert len(got["feed/wait"]) >= 3
+    assert len(got["checkpoint"]) == 1
+    (start, end), = got["superstep"]
+    ev, = [e for e in tr.to_chrome()["traceEvents"]
+           if e["name"] == "superstep"]
+    assert abs(ev["ts"] * 1e3 - start) < 1e6                # ns
+    assert abs((ev["ts"] + ev["dur"]) * 1e3 - end) < 1e6
 
 
 # ---------------------------------------------------------------------------
@@ -214,14 +238,13 @@ def test_obs_overhead_and_bit_exactness():
 
 
 # ---------------------------------------------------------------------------
-# 4-worker traced driver run: structure + exchange_us cross-check
+# 4-worker traced driver run: trace.json format + superstep structure
 # ---------------------------------------------------------------------------
 def test_traced_interleave_driver(tmp_path):
-    """The acceptance path: --trace-out on the 4-worker interleave driver
-    with injected collective latency yields per-bucket exchange spans for
-    every bucket x step x worker, each covering its injected latency, and
-    their summed gate-wait agrees with the sleeps the same run computed
-    within 25%."""
+    """--trace-out on the 4-worker interleave driver with injected
+    collective latency writes a trace.json that Perfetto loads, with one
+    superstep span per dispatch, each holding its dispatch and
+    loss_readback spans."""
     trace_path = str(tmp_path / "trace.json")
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
@@ -238,8 +261,7 @@ def test_traced_interleave_driver(tmp_path):
     root = os.path.join(os.path.dirname(__file__), "..")
     check = subprocess.run(
         [sys.executable, os.path.join(root, "scripts", "trace_check.py"),
-         trace_path, "--steps", "8", "--superstep", "2", "--workers", "4",
-         "--check-waits", "--tolerance", "0.25"],
+         trace_path, "--steps", "8", "--superstep", "2"],
         capture_output=True, text=True, timeout=120)
     assert check.returncode == 0, (check.stdout + check.stderr)[-4000:]
     assert "OK" in check.stdout

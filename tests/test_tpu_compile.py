@@ -6,12 +6,15 @@ multiples, more VMEM than a kernel may use, layouts Mosaic cannot lower.
 These tests compile each kernel of ``chaos-large``'s training step (and the
 ``lm-bench`` flash forward) for a v5e that is described, not attached, with
 the block configs the heuristic picks, and check that the compiled program
-holds the kernel (``tpu_custom_call``).  Nothing runs.
+holds the kernel (``tpu_custom_call``) under its ``pallas_call`` name.
+Nothing runs.
 
 The topology is described inside a fixture: only the process that runs
 these tests loads the TPU compiler.
 """
 import dataclasses
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +27,11 @@ from jax.sharding import PartitionSpec as P
 import repro.configs as C
 from repro.kernels import autotune as AT
 from repro.kernels import ops as kops
+from repro.models import cnn
+from repro.obs.trace import hlo_scopes
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "bench"))
+import devtrace  # noqa: E402  (the benchmark's HLO classes)
 
 B = 32                                   # one chaos-large micro-shard
 CONVS = {"conv0": ((B, 29, 29, 1), (4, 4, 1, 20)),
@@ -68,9 +76,11 @@ def compiled_kernels(monkeypatch, tmp_path):
     AT.clear_memory_cache()
 
 
-def _assert_kernel(fn, *shapes, count=1):
+def _assert_kernel(fn, *shapes, count=1, names=()):
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     assert text.count("tpu_custom_call") >= count, text[:2000]
+    for name in names:
+        assert f"/{name}/pallas_call" in text, name
 
 
 def _shape(sharding, shape, dtype=jnp.float32):
@@ -86,7 +96,7 @@ def test_conv_fwd_compiles(layer, precision, one_chip, compiled_kernels):
     S = lambda s: _shape(one_chip, s)
     with jax.default_matmul_precision(precision):
         _assert_kernel(compiled_kernels.conv2d_bias_tanh,
-                       S(x), S(w), S(w[3:]))
+                       S(x), S(w), S(w[3:]), names=["conv2d_fwd_tanh"])
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
@@ -99,7 +109,8 @@ def test_conv_bwd_fused_compiles(layer, precision, one_chip,
     S = lambda s: _shape(one_chip, s)
     with jax.default_matmul_precision(precision):
         _assert_kernel(compiled_kernels.conv2d_bias_tanh_bwd,
-                       S(x), S(w), S(w[3:]), S(y), S(y))
+                       S(x), S(w), S(w[3:]), S(y), S(y),
+                       names=["conv2d_bwd_tanh"])
 
 
 @pytest.mark.parametrize("layer", sorted(POOLS))
@@ -107,9 +118,10 @@ def test_pool_fwd_bwd_compile(layer, one_chip, compiled_kernels):
     x = POOLS[layer]
     y = (B, x[1] // 2, x[2] // 2, x[3])
     S = lambda s: _shape(one_chip, s)
-    _assert_kernel(lambda x: compiled_kernels.maxpool2d(x, 2), S(x))
+    _assert_kernel(lambda x: compiled_kernels.maxpool2d(x, 2), S(x),
+                   names=["maxpool_fwd"])
     _assert_kernel(lambda x, y, dy: compiled_kernels.maxpool2d_vjp_saved(
-        x, y, dy, 2), S(x), S(y), S(y))
+        x, y, dy, 2), S(x), S(y), S(y), names=["maxpool_bwd"])
 
 
 @pytest.mark.parametrize("layer", sorted(FCS))
@@ -119,17 +131,18 @@ def test_fc_fwd_bwd_compile(layer, one_chip, compiled_kernels):
     S = lambda s: _shape(one_chip, s)
     x, w, b, y = S((B, din)), S((din, dout)), S((dout,)), S((B, dout))
     if hidden:
-        _assert_kernel(k.fc_bias_tanh, x, w, b)
-        _assert_kernel(k.fc_bias_tanh_bwd, x, w, b, y, y)
+        _assert_kernel(k.fc_bias_tanh, x, w, b, names=["fc_fwd_tanh"])
+        _assert_kernel(k.fc_bias_tanh_bwd, x, w, b, y, y,
+                       names=["fc_bwd_tanh"])
     else:
-        _assert_kernel(k.fc_bias, x, w, b)
-        _assert_kernel(k.fc_bias_bwd, x, w, b, y)
+        _assert_kernel(k.fc_bias, x, w, b, names=["fc_fwd"])
+        _assert_kernel(k.fc_bias_bwd, x, w, b, y, names=["fc_bwd"])
 
 
 def test_softmax_xent_compiles(one_chip, compiled_kernels):
     _assert_kernel(compiled_kernels.softmax_xent,
                    _shape(one_chip, (B, 10)),
-                   _shape(one_chip, (B,), jnp.int32))
+                   _shape(one_chip, (B,), jnp.int32), names=["softmax_xent"])
 
 
 def test_flash_train_fwd_compiles(one_chip, compiled_kernels):
@@ -179,7 +192,21 @@ def test_worker_superstep_compiles(topo, compiled_kernels):
              "labels": _shape(data, (8, 256), jnp.int32)}
     compiled = make_worker_superstep(cfg, sync, worker, mesh,
                                      opt).lower(state, batch).compile()
+    text = compiled.as_text()
     # 8 forward + 7 backward kernels per step, inside one scan body
-    assert compiled.as_text().count("tpu_custom_call") >= 15
+    assert text.count("tpu_custom_call") >= 15
+    for name in ("conv2d_fwd_tanh", "conv2d_bwd_tanh", "maxpool_fwd",
+                 "maxpool_bwd", "fc_fwd_tanh", "fc_fwd", "fc_bwd_tanh",
+                 "fc_bwd", "softmax_xent"):
+        assert f"/{name}/pallas_call" in text, name
+    # every Pallas conv kernel is named by its layer and direction
+    scopes = hlo_scopes(text)
+    weights = [(k, k, ci, co) for kind, k, _, ci, co in
+               cnn._trace_shapes(cfg) if kind == "conv"]
+    convs = [n for n, c in devtrace.hlo_classes(text, weights).items()
+             if c == "conv"]
+    assert convs and all(scopes[n] for n in convs)
+    assert {scopes[n] for n in convs} == {
+        (f"conv{i}", d) for i in (0, 2, 4) for d in ("fwd", "bwd")}
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2 ** 30
