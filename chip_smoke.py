@@ -51,7 +51,8 @@ SUPERSTEPS_4 = 2       # per four-chip run
 #: the loss and every layer's output and gradients.  The chip's tanh alone
 #: differs from the host's by ~4.5e-5 of a conv output's max.
 GRAD_RTOL = 1e-4
-KERNEL_LAUNCHES = {"conv2d_fwd", "conv2d_bwd_fused", "maxpool2d_fwd",
+KERNEL_LAUNCHES = {"conv2d_packed_fwd_tanh", "conv2d_packed_bwd_tanh",
+                   "conv2d_fwd", "conv2d_bwd_fused", "maxpool2d_fwd",
                    "maxpool2d_bwd", "fc_fwd", "fc_bwd_fused", "softmax_xent"}
 
 

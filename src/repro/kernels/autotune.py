@@ -235,6 +235,63 @@ def conv_bwd_vmem_bytes(cfg, x_shape, w_shape, itemsize: int = 4,
     return 2 * blocks + dw + db + values
 
 
+def _patch_cols(x_shape, w_shape) -> int:
+    """Columns per image of the tap-packed kernels' patch matrix (rows of
+    their ``(pixels, Cout)`` products): ``Ho * aligned(Wo)``."""
+    _, H, W, _ = x_shape
+    Kk = w_shape[0]
+    return (H - Kk + 1) * K.aligned(W - Kk + 1)
+
+
+def _packed_batch_blocks(x_shape, w_shape) -> list[int]:
+    """Batch blocks the tap-packed kernels accept on a TPU: the block's
+    patch columns fill whole lane tiles, or the block is the batch."""
+    B = x_shape[0]
+    cols = _patch_cols(x_shape, w_shape)
+    return [bb for bb in _divisors(B) if bb * cols % K.LANES == 0 or bb == B]
+
+
+def conv_packed_fwd_vmem_bytes(cfg, x_shape, w_shape,
+                               itemsize: int = 4) -> int:
+    """Bytes per grid step of the tap-packed forward: double-buffered
+    patch, weight, bias and out blocks, plus the loaded patches, their
+    (rows, taps) transpose and the fp32 result and its cropped copy.  The
+    patch block is K*K*Cin sublanes by rows lanes, not Cin lanes."""
+    B, H, W, Cin = x_shape
+    Kk, _, _, Cout = w_shape
+    Ho, Wo = H - Kk + 1, W - Kk + 1
+    bb = K._divisor_block(B, cfg["batch_block"])
+    rows, taps = bb * _patch_cols(x_shape, w_shape), Kk * Kk * Cin
+    p = tiled_bytes((taps, rows), itemsize)
+    blocks = (p + tiled_bytes((Kk, Kk, Cin, Cout), itemsize)
+              + tiled_bytes((1, Cout), itemsize)
+              + tiled_bytes((bb, Ho, Wo, Cout), itemsize))
+    return (2 * blocks + p + tiled_bytes((rows, taps), itemsize)
+            + 2 * tiled_bytes((rows, Cout)))
+
+
+def conv_packed_bwd_vmem_bytes(cfg, x_shape, w_shape, itemsize: int = 4,
+                               dx: bool = False) -> int:
+    """Bytes per grid step of the tap-packed backward (dtanh-fused):
+    double-buffered patch, dy, y, dw and db blocks (and w and the patch
+    gradient with ``dx``), the dw/db scratch, and the fp32 patches, dz and
+    its padded copy (and patch gradient) of the kernel body."""
+    B, H, W, Cin = x_shape
+    Kk, _, _, Cout = w_shape
+    Ho, Wo = H - Kk + 1, W - Kk + 1
+    bb = K._divisor_block(B, cfg["batch_block"])
+    rows, taps = bb * _patch_cols(x_shape, w_shape), Kk * Kk * Cin
+    p, pf = tiled_bytes((taps, rows), itemsize), tiled_bytes((taps, rows))
+    slab = tiled_bytes((bb, Ho, Wo, Cout), itemsize)
+    dw, db = tiled_bytes((Kk, Kk, Cin, Cout)), tiled_bytes((1, Cout))
+    blocks = p + 2 * slab + dw + db
+    values = pf + 2 * slab + 2 * tiled_bytes((rows, Cout))
+    if dx:
+        blocks += tiled_bytes((Kk, Kk, Cin, Cout), itemsize) + pf
+        values += pf
+    return (2 * blocks + tiled_bytes((taps, Cout)) + db + values)
+
+
 def conv_fwd_candidates(x_shape, w_shape, itemsize: int = 4) -> list[dict]:
     """The heuristic default first, then every TPU-legal (bb, rb, cb) that
     fits the VMEM budget."""
@@ -407,6 +464,27 @@ def default_conv_bwd(x_shape, w_shape, itemsize: int = 4) -> dict:
          for bb in reversed(_divisors(B, 8))),
         lambda c: conv_bwd_vmem_bytes(c, x_shape, w_shape,
                                       itemsize) <= VMEM_BUDGET_BYTES)
+
+
+def default_conv_packed_fwd(x_shape, w_shape, itemsize: int = 4) -> dict:
+    """Largest lane-legal batch block of the tap-packed forward that fits
+    VMEM (its estimate counts K*K*Cin taps, not Cin lanes)."""
+    return _first_fit(
+        ({"batch_block": bb}
+         for bb in reversed(_packed_batch_blocks(x_shape, w_shape))),
+        lambda c: conv_packed_fwd_vmem_bytes(c, x_shape, w_shape,
+                                             itemsize) <= VMEM_BUDGET_BYTES)
+
+
+def default_conv_packed_bwd(x_shape, w_shape, itemsize: int = 4,
+                            dx: bool = False) -> dict:
+    """As ``default_conv_packed_fwd``, for the backward with or without
+    the input gradient."""
+    return _first_fit(
+        ({"batch_block": bb}
+         for bb in reversed(_packed_batch_blocks(x_shape, w_shape))),
+        lambda c: conv_packed_bwd_vmem_bytes(
+            c, x_shape, w_shape, itemsize, dx=dx) <= VMEM_BUDGET_BYTES)
 
 
 def default_fc_fwd(x_shape, w_shape, itemsize: int = 4) -> dict:
@@ -646,7 +724,9 @@ def tune_cnn_net(cfg, batch: int, *, interpret: bool, iters: int = 1):
     per-shard batch (e.g. 1) whose autotune keys differ from the full-batch
     keys ``benchmarks/run.py --only kernels`` populates — scaling runs call
     this first so kernel-on cells measure tuned configs, not the heuristic
-    fallback.  Returns the list of cache keys written."""
+    fallback.  Conv layers that the tap-packed pair serves are skipped:
+    its heuristic block is all it has.  Returns the list of cache keys
+    written."""
     from repro.models.cnn import _trace_shapes  # local: avoid import cycle
 
     keys = []
@@ -654,7 +734,9 @@ def tune_cnn_net(cfg, batch: int, *, interpret: bool, iters: int = 1):
     kk = jax.random.key(0)
     shapes = _trace_shapes(cfg)
     for i, (kind, k, h_out, cin, cout) in enumerate(shapes):
-        if kind == "conv":
+        if kind == "conv" and K.packs_taps((k, k, cin, cout)):
+            h = h_out
+        elif kind == "conv":
             x = jax.random.normal(kk, (batch, h, h, cin), jnp.float32)
             w = jax.random.normal(kk, (k, k, cin, cout), jnp.float32) * 0.1
             b = jnp.zeros((cout,), jnp.float32)

@@ -27,6 +27,13 @@ total time (Table 5).
 dw/db accumulate across grid steps in fp32 VMEM scratch, relying on the
 TPU's sequential-grid revisiting semantics (tested explicitly for
 ``batch_block < B`` in tests/test_kernels.py).
+
+Tap packing: where a layer's K*K*Cin taps fit one 128-lane tile (the input
+layer, Cin = 1), the per-tap dots above would contract over Cin lanes of
+128.  ``conv2d_packed_fwd`` / ``conv2d_packed_bwd`` instead take an
+explicit, transposed patch matrix (``tap_patches``: taps along sublanes,
+output pixels along lanes, dense in HBM) and do ONE dot per grid step over
+all the taps; the backward computes the input gradient only when asked.
 """
 from __future__ import annotations
 
@@ -386,3 +393,180 @@ def conv2d_dw(x, dy, w_shape, *, batch_block: int = 8,
         interpret=interpret,
         name="conv2d_dw",
     )(x, dy)
+
+
+# ---------------------------------------------------------------------------
+# Tap-packed pair: every tap in one contraction (K*K*Cin <= LANES)
+# ---------------------------------------------------------------------------
+def packs_taps(w_shape) -> bool:
+    """Whether the tap-packed pair serves a conv of weight shape
+    ``(K, K, Cin, Cout)``: all K*K*Cin taps fit one lane tile."""
+    K, _, Cin, _ = w_shape
+    return K * K * Cin <= LANES
+
+
+def tap_patches(x, K: int):
+    """The valid conv's patch matrix, transposed: ``(K*K*Cin, B*Ho*Wa)``.
+
+    Row ``(kh*K + kw)*Cin + c`` (the row order of ``w.reshape(-1, Cout)``),
+    column ``(b*Ho + h)*Wa + j`` holds ``x[b, h+kh, j+kw, c]``; each output
+    row has ``Wa = aligned(Wo)`` columns, the extra ones read zero padding.
+    Built from K*K shifted slices, no convolution op.  Taps run along
+    sublanes and pixels along lanes, so the matrix is dense in HBM, where a
+    ``(pixels, taps)`` one would fill K*K*Cin of every 128 lanes.
+    """
+    B, H, W, Cin = x.shape
+    Ho, Wo = H - K + 1, W - K + 1
+    Wa = aligned(Wo)
+    xp = jnp.pad(x, ((0, 0), (0, 0), (0, Wa - Wo), (0, 0)))
+    taps = jnp.stack([xp[:, kh:kh + Ho, kw:kw + Wa, :]
+                      for kh in range(K) for kw in range(K)])
+    return taps.transpose(0, 4, 1, 2, 3).reshape(K * K * Cin, B * Ho * Wa)
+
+
+def _packed_fwd_kernel(p_ref, w_ref, b_ref, o_ref, *, Ho: int, Wa: int,
+                       activation: str | None):
+    w = w_ref[...]
+    w = w.reshape(-1, w.shape[3])                       # (taps, Cout)
+    # (taps, rows)^T @ (taps, Cout): one dot over all K*K*Cin taps
+    acc = jax.lax.dot_general(p_ref[...], w, (((0,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    acc += b_ref[...].astype(jnp.float32)
+    if activation == "tanh":
+        acc = jnp.tanh(acc)
+    bb, _, Wo, cout = o_ref.shape
+    o_ref[...] = acc.reshape(bb, Ho, Wa, cout)[:, :, :Wo].astype(o_ref.dtype)
+
+
+def conv2d_packed_fwd(x, w, bias=None, *, activation: str | None = None,
+                      batch_block: int = 8, interpret: bool):
+    """Valid conv, stride 1, NHWC x HWIO -> NHWC, optional fused bias+tanh,
+    as one dot per grid step over the tap-packed patch matrix.
+
+    Returns ``(y, patches)``: the patch matrix (``tap_patches``) is what a
+    backward needs of x.  The grid runs over batch blocks; a block's
+    ``bb*Ho*Wa`` patch columns must fill whole lane tiles or be the batch.
+    """
+    B, H, W, Cin = x.shape
+    K, _, _, Cout = w.shape
+    Ho, Wo = H - K + 1, W - K + 1
+    Wa = aligned(Wo)
+    bb = _divisor_block(B, batch_block)
+    patches = tap_patches(x, K)
+    b2 = (jnp.zeros((Cout,), x.dtype) if bias is None else bias).reshape(
+        1, Cout)
+    name = "conv2d_packed_fwd" + (f"_{activation}" if activation else "")
+    record_launch(name)
+    y = pl.pallas_call(
+        functools.partial(_packed_fwd_kernel, Ho=Ho, Wa=Wa,
+                          activation=activation),
+        grid=(B // bb,),
+        in_specs=[
+            pl.BlockSpec((patches.shape[0], bb * Ho * Wa), lambda b: (0, b)),
+            pl.BlockSpec((K, K, Cin, Cout), lambda b: (0, 0, 0, 0)),
+            pl.BlockSpec((1, Cout), lambda b: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((bb, Ho, Wo, Cout), lambda b: (b, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, Cout), x.dtype),
+        compiler_params=COMPILER_PARAMS,
+        interpret=interpret,
+        name=name,
+    )(patches, w, b2)
+    return y, patches
+
+
+def _packed_bwd_kernel(p_ref, dy_ref, *refs, Ho: int, Wa: int, tanh: bool,
+                       dx: bool):
+    refs = list(refs)
+    y_ref = refs.pop(0) if tanh else None
+    w_ref = refs.pop(0) if dx else None
+    dp_ref = refs.pop(0) if dx else None
+    dw_ref, db_ref, dw_acc, db_acc = refs
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+        db_acc[...] = jnp.zeros_like(db_acc)
+
+    dz = dy_ref[...].astype(jnp.float32)
+    if tanh:
+        y = y_ref[...].astype(jnp.float32)
+        dz = dz * (1.0 - y * y)
+    bb, _, Wo, cout = dz.shape
+    if Wa > Wo:   # the patch matrix's padding columns get a zero dz
+        dz = jnp.concatenate(
+            [dz, jnp.zeros((bb, Ho, Wa - Wo, cout), jnp.float32)], axis=2)
+    dz = dz.reshape(bb * Ho * Wa, cout)
+    dw_acc[...] += jnp.dot(p_ref[...].astype(jnp.float32), dz,
+                           preferred_element_type=jnp.float32)
+    db_acc[...] += jnp.sum(dz, axis=0, keepdims=True)
+    if dx:   # the patch matrix's gradient, (taps, rows) like the matrix
+        w = w_ref[...]
+        dp_ref[...] = jax.lax.dot_general(
+            w.reshape(-1, cout), dz, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
+    def _flush():
+        dw_ref[...] = dw_acc[...].reshape(dw_ref.shape).astype(dw_ref.dtype)
+        db_ref[...] = db_acc[...].astype(db_ref.dtype)
+
+
+def conv2d_packed_bwd(patches, dy, w, y=None, *, dx: bool,
+                      batch_block: int = 8, interpret: bool):
+    """One pallas_call -> (dx, dw, db) for the tap-packed conv, from the
+    forward's patch matrix.
+
+    ``y`` (the forward tanh output) fuses the dtanh factor, as in
+    ``conv2d_bwd_fused``.  dw = patches @ dz is one dot per grid step.
+    With ``dx=False`` the kernel skips the input gradient and returns None
+    for it; with ``dx=True`` it writes the patch matrix's gradient
+    ``w_packed @ dz^T``, which the transpose of ``tap_patches`` (a
+    shift-and-add) folds back into dx.
+    """
+    B, Ho, Wo, Cout = dy.shape
+    K, _, Cin, _ = w.shape
+    Wa = aligned(Wo)
+    bb = _divisor_block(B, batch_block)
+    taps, rows = patches.shape[0], bb * Ho * Wa
+    cols = pl.BlockSpec((taps, rows), lambda b: (0, b))
+    slab = pl.BlockSpec((bb, Ho, Wo, Cout), lambda b: (b, 0, 0, 0))
+    whole_w = pl.BlockSpec((K, K, Cin, Cout), lambda b: (0, 0, 0, 0))
+    in_specs, inputs = [cols, slab], [patches, dy]
+    if y is not None:
+        in_specs.append(slab)
+        inputs.append(y)
+    out_specs = [whole_w, pl.BlockSpec((1, Cout), lambda b: (0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((K, K, Cin, Cout), jnp.float32),
+                 jax.ShapeDtypeStruct((1, Cout), jnp.float32)]
+    if dx:
+        in_specs.append(whole_w)
+        inputs.append(w)
+        out_specs.insert(0, cols)
+        out_shape.insert(0, jax.ShapeDtypeStruct(patches.shape,
+                                                 jnp.float32))
+    name = ("conv2d_packed_bwd" + ("_tanh" if y is not None else "")
+            + ("_dx" if dx else ""))
+    record_launch(name)
+    outs = pl.pallas_call(
+        functools.partial(_packed_bwd_kernel, Ho=Ho, Wa=Wa,
+                          tanh=y is not None, dx=dx),
+        grid=(B // bb,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((taps, Cout), jnp.float32),
+                        pltpu.VMEM((1, Cout), jnp.float32)],
+        compiler_params=COMPILER_PARAMS,
+        interpret=interpret,
+        name=name,
+    )(*inputs)
+    if not dx:
+        dw, db = outs
+        return None, dw, db.reshape(Cout)
+    dp, dw, db = outs
+    x_shape = (B, Ho + K - 1, Wo + K - 1, Cin)
+    (dxv,) = jax.linear_transpose(
+        lambda x: tap_patches(x, K),
+        jax.ShapeDtypeStruct(x_shape, jnp.float32))(dp)
+    return dxv.astype(patches.dtype), dw, db.reshape(Cout)

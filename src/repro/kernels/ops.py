@@ -6,7 +6,9 @@ through Pallas — and through the autotuner's block configs (DESIGN.md
 Per conv layer per train step this issues exactly TWO pallas_call launches:
 one fused forward (conv + bias + tanh) and one fused backward (dx + dw + db
 from a single pass, dtanh folded in), down from three with the split
-fwd/dx/dw kernels.
+fwd/dx/dw kernels.  A conv whose K*K*Cin taps fit one lane tile runs the
+tap-packed pair instead (``conv2d.packs_taps``: chosen by shape alone),
+whose backward skips dx when the input takes no gradient (the images).
 
 The kernels run interpreted (their bodies executed as jnp ops) only on the
 CPU backend, where the tests run; on any other backend they are compiled.
@@ -41,17 +43,69 @@ def _bwd_cfg(x, w, variant="plain"):
 
 
 # ---------------------------------------------------------------------------
+# Tap-packed conv (K*K*Cin <= 128): one dot per grid step over every tap
+# ---------------------------------------------------------------------------
+def _packed_fwd(x, w, b, activation):
+    return K.conv2d_packed_fwd(
+        x, w, b, activation=activation, interpret=_interpret(),
+        **AT.default_conv_packed_fwd(x.shape, w.shape, x.dtype.itemsize))
+
+
+def _packed_bwd(patches, w, b, y, dy, dx: bool):
+    """(dx or None, dw, db) of the tap-packed conv, dw and db cast to the
+    parameters' dtypes."""
+    B, Ho, Wo, _ = dy.shape
+    Kk, _, Cin, _ = w.shape
+    x_shape = (B, Ho + Kk - 1, Wo + Kk - 1, Cin)
+    dxv, dw, db = K.conv2d_packed_bwd(
+        patches, dy, w, y, dx=dx, interpret=_interpret(),
+        **AT.default_conv_packed_bwd(x_shape, w.shape,
+                                     patches.dtype.itemsize, dx=dx))
+    return dxv, dw.astype(w.dtype), db.astype(b.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _conv2d_packed(x, w, b, activation):
+    return _packed_fwd(x, w, b, activation)[0]
+
+
+def _cp_fwd(x, w, b, activation):
+    y, patches = _packed_fwd(x.value, w.value, b.value, activation)
+    # whether x takes a gradient rides in the residuals' pytree structure
+    # (None or an empty tuple), never as a traced value
+    wants_dx = () if x.perturbed else None
+    return y, (patches, w.value, b.value, y if activation else None,
+               wants_dx)
+
+
+def _cp_bwd(activation, res, dy):
+    patches, w, b, y, wants_dx = res
+    return _packed_bwd(patches, w, b, y, dy, dx=wants_dx is not None)
+
+
+_conv2d_packed.defvjp(_cp_fwd, _cp_bwd, symbolic_zeros=True)
+
+
+# ---------------------------------------------------------------------------
 # Plain valid conv (no epilogue) — kept for callers that fuse nothing
 # ---------------------------------------------------------------------------
-@jax.custom_vjp
 def conv2d_valid(x, w):
-    """Valid conv, stride 1, NHWC x HWIO -> NHWC.  Pallas forward+backward,
-    autotuned block sizes, fused single-launch backward."""
+    """Valid conv, stride 1, NHWC x HWIO -> NHWC.  Pallas forward+backward:
+    the tap-packed pair when the taps fit one lane tile, else the tiled
+    kernels with autotuned block sizes and a fused single-launch
+    backward."""
+    if K.packs_taps(w.shape):
+        return _conv2d_packed(x, w, jnp.zeros((w.shape[3],), x.dtype), None)
+    return _conv2d_valid(x, w)
+
+
+@jax.custom_vjp
+def _conv2d_valid(x, w):
     return K.conv2d_fwd(x, w, interpret=_interpret(), **_fwd_cfg(x, w))
 
 
 def _cv_fwd(x, w):
-    return conv2d_valid(x, w), (x, w)
+    return _conv2d_valid(x, w), (x, w)
 
 
 def _cv_bwd(res, dy):
@@ -61,22 +115,29 @@ def _cv_bwd(res, dy):
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
-conv2d_valid.defvjp(_cv_fwd, _cv_bwd)
+_conv2d_valid.defvjp(_cv_fwd, _cv_bwd)
 
 
 # ---------------------------------------------------------------------------
 # Fused conv + bias + tanh — the CNN layer op (models/cnn.py hot path)
 # ---------------------------------------------------------------------------
-@jax.custom_vjp
 def conv2d_bias_tanh(x, w, b):
     """tanh(conv2d_valid(x, w) + b) in one forward launch; the backward is
-    one launch too (dtanh + dx + dw + db fused)."""
+    one launch too (dtanh + dx + dw + db fused; the tap-packed backward
+    leaves dx out when x takes no gradient)."""
+    if K.packs_taps(w.shape):
+        return _conv2d_packed(x, w, b, "tanh")
+    return _conv2d_bias_tanh(x, w, b)
+
+
+@jax.custom_vjp
+def _conv2d_bias_tanh(x, w, b):
     return K.conv2d_fwd(x, w, b, activation="tanh", interpret=_interpret(),
                         **_fwd_cfg(x, w, "bias_tanh"))
 
 
 def _cbt_fwd(x, w, b):
-    y = conv2d_bias_tanh(x, w, b)
+    y = _conv2d_bias_tanh(x, w, b)
     return y, (x, w, b, y)
 
 
@@ -87,7 +148,7 @@ def _cbt_bwd(res, dy):
     return dx.astype(x.dtype), dw.astype(w.dtype), db.astype(b.dtype)
 
 
-conv2d_bias_tanh.defvjp(_cbt_fwd, _cbt_bwd)
+_conv2d_bias_tanh.defvjp(_cbt_fwd, _cbt_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -180,12 +241,16 @@ fc_bias.defvjp(_fb_fwd, _fb_bwd)
 # configs, same casts — so the tape's gradients stay bit-comparable.
 
 
-def conv2d_bias_tanh_bwd(x, w, b, y, dy):
+def conv2d_bias_tanh_bwd(x, w, b, y, dy, *, dx: bool = True):
     """Fused (dx, dw, db) for ``conv2d_bias_tanh`` from the saved output
-    ``y`` — one launch, no forward recompute."""
-    dx, dw, db = K.conv2d_bwd_fused(x, dy, w, y, interpret=_interpret(),
-                                    **_bwd_cfg(x, w, "dtanh"))
-    return dx.astype(x.dtype), dw.astype(w.dtype), db.astype(b.dtype)
+    ``y`` — one launch, no forward recompute.  The tap-packed pair (same
+    shape rule as the forward) rebuilds the patch matrix from ``x`` and,
+    with ``dx=False``, returns None for dx."""
+    if K.packs_taps(w.shape):
+        return _packed_bwd(K.tap_patches(x, w.shape[0]), w, b, y, dy, dx=dx)
+    dxv, dw, db = K.conv2d_bwd_fused(x, dy, w, y, interpret=_interpret(),
+                                     **_bwd_cfg(x, w, "dtanh"))
+    return dxv.astype(x.dtype), dw.astype(w.dtype), db.astype(b.dtype)
 
 
 def fc_bias_tanh_bwd(x, w, b, y, dy):

@@ -191,9 +191,10 @@ def _layer_bwd_fns(cfg: ArchConfig, uk: bool):
     for i, (kind, k, _, cin, cout) in enumerate(shapes):
         if kind == "conv":
             if uk:
-                def bwd(p, x, y, g):
+                # layer 0's input is the images: it takes no dx
+                def bwd(p, x, y, g, first=i == 0):
                     dx, dw, db = kops.conv2d_bias_tanh_bwd(
-                        x, p["w"], p["b"], y, g)
+                        x, p["w"], p["b"], y, g, dx=not first)
                     return {"w": dw, "b": db}, dx
             else:
                 def bwd(p, x, y, g):

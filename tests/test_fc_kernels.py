@@ -172,8 +172,10 @@ def test_full_cnn_step_launch_count_with_fc_kernels():
     assert rec.count("fc_fwd") == n_fc
     assert rec.count("fc_bwd_fused") == n_fc
     assert rec.count("softmax_xent") == 1
-    assert rec.count("conv2d_fwd") == n_conv
-    assert rec.count("conv2d_bwd_fused") == n_conv
+    # both of chaos-small's conv layers pack their taps (16 and 125 of 128)
+    assert rec.count("conv2d_packed_fwd_tanh") == n_conv
+    assert (rec.count("conv2d_packed_bwd_tanh")
+            + rec.count("conv2d_packed_bwd_tanh_dx")) == n_conv
     assert rec.count("maxpool2d_fwd") == n_pool
     assert rec.count("maxpool2d_bwd") == n_pool
     assert len(rec) == 2 * (n_conv + n_pool + n_fc) + 1, rec

@@ -212,6 +212,112 @@ def test_fused_epilogue_mixed_precision_bias_grad():
     assert grads[2].dtype == jnp.float32
 
 
+# the tap-packed pair (K*K*Cin <= 128): small and large conv0, small conv2
+PACKED_SHAPES = [
+    (8, 29, 29, 1, 4, 5),      # small conv1
+    (32, 29, 29, 1, 4, 20),    # medium/large conv0, one micro-shard
+    (4, 13, 13, 5, 5, 10),     # small conv2: 5*5*5 = 125 taps
+]
+
+
+@pytest.mark.parametrize("B,H,W,Cin,Kk,Cout", PACKED_SHAPES)
+def test_packed_conv_fwd_and_grads_vs_xla(B, H, W, Cin, Kk, Cout):
+    """The tap-packed pair against XLA's convolution: forward, and
+    ``jax.grad`` w.r.t. x, w and b (x perturbed, so dx is computed)."""
+    k1, k2, k3 = jax.random.split(jax.random.key(34), 3)
+    x = jax.random.normal(k1, (B, H, W, Cin), jnp.float32)
+    w = jax.random.normal(k2, (Kk, Kk, Cin, Cout), jnp.float32) * 0.1
+    b = jax.random.normal(k3, (Cout,), jnp.float32) * 0.1
+    want = jnp.tanh(ref.conv2d_valid_ref(x, w) + b)
+    np.testing.assert_allclose(kops.conv2d_bias_tanh(x, w, b), want,
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(kops.conv2d_valid(x, w),
+                               ref.conv2d_valid_ref(x, w),
+                               atol=1e-4, rtol=1e-4)
+    f1 = lambda x, w, b: jnp.sum(jnp.cos(kops.conv2d_bias_tanh(x, w, b)))
+    f2 = lambda x, w, b: jnp.sum(jnp.cos(
+        jnp.tanh(ref.conv2d_valid_ref(x, w) + b)))
+    with K.launch_trace() as rec:
+        g1 = jax.grad(f1, (0, 1, 2))(x, w, b)
+    assert rec == ["conv2d_packed_fwd_tanh", "conv2d_packed_bwd_tanh_dx"]
+    g2 = jax.grad(f2, (0, 1, 2))(x, w, b)
+    for a, b_ in zip(g1, g2):
+        np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-4)
+    # without a gradient for x the backward skips dx, and dw/db agree
+    with K.launch_trace() as rec:
+        gw = jax.grad(f1, (1, 2))(x, w, b)
+    assert rec == ["conv2d_packed_fwd_tanh", "conv2d_packed_bwd_tanh"]
+    for a, b_ in zip(gw, g2[1:]):
+        np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("x_shape,w_shape,packed", [
+    ((8, 29, 29, 1), (4, 4, 1, 5), True),
+    ((32, 29, 29, 1), (4, 4, 1, 20), True),
+    ((4, 13, 13, 5), (5, 5, 5, 10), True),
+    ((32, 26, 26, 20), (5, 5, 20, 60), False),
+])
+def test_packed_selection_by_shape(x_shape, w_shape, packed):
+    """``kernels/ops.py`` runs the tap-packed pair exactly where K*K*Cin
+    fits one lane tile, and today's tiled kernels elsewhere."""
+    x = jnp.ones(x_shape, jnp.float32)
+    w = jnp.full(w_shape, 0.01, jnp.float32)
+    b = jnp.zeros(w_shape[3:], jnp.float32)
+    with K.launch_trace() as rec:
+        jax.eval_shape(jax.grad(lambda x, w, b: jnp.sum(
+            kops.conv2d_bias_tanh(x, w, b)), (0, 1, 2)), x, w, b)
+    assert K.packs_taps(w_shape) == packed
+    assert rec == (["conv2d_packed_fwd_tanh", "conv2d_packed_bwd_tanh_dx"]
+                   if packed else ["conv2d_fwd", "conv2d_bwd_fused"])
+
+
+def test_packed_bwd_dw_accumulates_over_batch_blocks():
+    """dw/db accumulate in VMEM scratch across the packed backward's grid
+    steps: every batch block gives the whole-batch gradients."""
+    k1, k2, k3 = jax.random.split(jax.random.key(35), 3)
+    x = jax.random.normal(k1, (8, 13, 13, 5), jnp.float32)
+    w = jax.random.normal(k2, (5, 5, 5, 10), jnp.float32) * 0.1
+    dy = jax.random.normal(k3, (8, 9, 9, 10), jnp.float32)
+    f = lambda x, w: jnp.sum(ref.conv2d_valid_ref(x, w) * dy)
+    gx, gw = jax.grad(f, (0, 1))(x, w)
+    patches = K.tap_patches(x, 5)
+    for bb in (1, 4, 8):
+        dx, dw, db = K.conv2d_packed_bwd(patches, dy, w, dx=True,
+                                         batch_block=bb, interpret=True)
+        np.testing.assert_allclose(dx, gx, atol=1e-3, rtol=1e-3)
+        np.testing.assert_allclose(dw, gw, atol=1e-3, rtol=1e-3)
+        np.testing.assert_allclose(db, jnp.sum(dy, (0, 1, 2)),
+                                   atol=1e-3, rtol=1e-3)
+
+
+def test_worker_step_conv0_backward_skips_dx():
+    """A params-only ``jax.grad`` of a chaos-large worker superstep (the
+    benchmark's route: micro-shards under ``lax.map``, kernels on) runs
+    conv0's packed backward without dx: the images take no gradient."""
+    import dataclasses
+
+    import repro.configs as C
+    from repro.core.chaos import SyncConfig
+    from repro.core.types import WorkerConfig
+    from repro.launch.mesh import make_host_mesh
+    from repro.train.step import (init_worker_state, make_optimizer,
+                                  make_worker_superstep)
+    cfg = dataclasses.replace(C.get("chaos-large"), use_kernel=True)
+    worker = WorkerConfig(workers=1, logical_shards=2)
+    sync = SyncConfig(mode="chaos", axis_name=worker.axis, staleness=1)
+    opt = make_optimizer(cfg, total_steps=4)
+    state = jax.eval_shape(lambda: init_worker_state(
+        cfg, jax.random.key(0), sync, worker, opt))
+    batch = {"images": jax.ShapeDtypeStruct((1, 4, 29, 29, 1), jnp.float32),
+             "labels": jax.ShapeDtypeStruct((1, 4), jnp.int32)}
+    step = make_worker_superstep(cfg, sync, worker, make_host_mesh(1), opt)
+    with K.launch_trace() as rec:
+        step.lower(state, batch)
+    packed = [r for r in rec if r.startswith("conv2d_packed")]
+    assert packed == ["conv2d_packed_fwd_tanh", "conv2d_packed_bwd_tanh"]
+    assert rec.count("conv2d_fwd") == rec.count("conv2d_bwd_fused") == 2
+
+
 def test_conv_launch_count_per_train_step():
     """The fusion acceptance criterion: with use_kernel=True, each conv
     layer of a train step issues exactly 2 Pallas launches (one fused
@@ -228,8 +334,10 @@ def test_conv_launch_count_per_train_step():
     with K.launch_trace() as rec:
         jax.grad(lambda p: cnn.loss_fn(p, batch, cfg, use_kernel=True)[0])(
             params)
-    assert rec.count("conv2d_fwd") == n_conv
-    assert rec.count("conv2d_bwd_fused") == n_conv
+    # both of chaos-small's conv layers pack their taps (16 and 125 of 128)
+    assert rec.count("conv2d_packed_fwd_tanh") == n_conv
+    assert (rec.count("conv2d_packed_bwd_tanh")
+            + rec.count("conv2d_packed_bwd_tanh_dx")) == n_conv
     conv_launches = [r for r in rec if r.startswith("conv2d")]
     assert len(conv_launches) == 2 * n_conv, conv_launches
 

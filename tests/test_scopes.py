@@ -230,6 +230,14 @@ def test_layerwise_worker_mesh_scopes():
                                                   interpret=True)),
     ("conv2d_dw", lambda K, x, w, dy: K.conv2d_dw(x, dy, w.shape,
                                                   interpret=True)),
+    ("conv2d_packed_fwd", lambda K, x, w, dy: K.conv2d_packed_fwd(
+        x, w, interpret=True)[0]),
+    ("conv2d_packed_fwd_tanh", lambda K, x, w, dy: K.conv2d_packed_fwd(
+        x, w, activation="tanh", interpret=True)[0]),
+    ("conv2d_packed_bwd", lambda K, x, w, dy: K.conv2d_packed_bwd(
+        K.tap_patches(x, 3), dy, w, dx=False, interpret=True)[1:]),
+    ("conv2d_packed_bwd_tanh_dx", lambda K, x, w, dy: K.conv2d_packed_bwd(
+        K.tap_patches(x, 3), dy, w, dy, dx=True, interpret=True)),
 ])
 def test_conv_kernel_names(name, call):
     """Each conv ``pallas_call`` variant carries its own name into the

@@ -37,6 +37,9 @@ B = 32                                   # one chaos-large micro-shard
 CONVS = {"conv0": ((B, 29, 29, 1), (4, 4, 1, 20)),
          "conv2": ((B, 26, 26, 20), (5, 5, 20, 60)),
          "conv4": ((B, 11, 11, 60), (6, 6, 60, 100))}
+#: the kernel each conv layer runs: conv0's 4*4*1 taps fit one lane tile
+#: (the tap-packed pair), conv2's 500 and conv4's 2,160 do not
+PACKED = {"conv0": True, "conv2": False, "conv4": False}
 POOLS = {"pool3": (B, 22, 22, 60), "pool5": (B, 6, 6, 100)}
 FCS = {"fc6": (900, 150, True), "fc7": (150, 10, False)}
 
@@ -94,9 +97,11 @@ def _shape(sharding, shape, dtype=jnp.float32):
 def test_conv_fwd_compiles(layer, precision, one_chip, compiled_kernels):
     x, w = CONVS[layer]
     S = lambda s: _shape(one_chip, s)
+    name = ("conv2d_packed_fwd_tanh" if PACKED[layer]
+            else "conv2d_fwd_tanh")
     with jax.default_matmul_precision(precision):
         _assert_kernel(compiled_kernels.conv2d_bias_tanh,
-                       S(x), S(w), S(w[3:]), names=["conv2d_fwd_tanh"])
+                       S(x), S(w), S(w[3:]), names=[name])
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
@@ -107,10 +112,18 @@ def test_conv_bwd_fused_compiles(layer, precision, one_chip,
     ho = x[1] - w[0] + 1
     y = (B, ho, ho, w[3])
     S = lambda s: _shape(one_chip, s)
+    k = compiled_kernels
     with jax.default_matmul_precision(precision):
-        _assert_kernel(compiled_kernels.conv2d_bias_tanh_bwd,
+        if not PACKED[layer]:
+            _assert_kernel(k.conv2d_bias_tanh_bwd, S(x), S(w), S(w[3:]),
+                           S(y), S(y), names=["conv2d_bwd_tanh"])
+            return
+        # the packed backward with and without the input gradient
+        _assert_kernel(k.conv2d_bias_tanh_bwd, S(x), S(w), S(w[3:]),
+                       S(y), S(y), names=["conv2d_packed_bwd_tanh_dx"])
+        _assert_kernel(lambda *a: k.conv2d_bias_tanh_bwd(*a, dx=False)[1:],
                        S(x), S(w), S(w[3:]), S(y), S(y),
-                       names=["conv2d_bwd_tanh"])
+                       names=["conv2d_packed_bwd_tanh"])
 
 
 @pytest.mark.parametrize("layer", sorted(POOLS))
@@ -195,10 +208,13 @@ def test_worker_superstep_compiles(topo, compiled_kernels):
     text = compiled.as_text()
     # 8 forward + 7 backward kernels per step, inside one scan body
     assert text.count("tpu_custom_call") >= 15
-    for name in ("conv2d_fwd_tanh", "conv2d_bwd_tanh", "maxpool_fwd",
+    for name in ("conv2d_packed_fwd_tanh", "conv2d_packed_bwd_tanh",
+                 "conv2d_fwd_tanh", "conv2d_bwd_tanh", "maxpool_fwd",
                  "maxpool_bwd", "fc_fwd_tanh", "fc_fwd", "fc_bwd_tanh",
                  "fc_bwd", "softmax_xent"):
         assert f"/{name}/pallas_call" in text, name
+    # conv0's input is the images: its backward computes no dx
+    assert "conv2d_packed_bwd_tanh_dx" not in text
     # every Pallas conv kernel is named by its layer and direction
     scopes = hlo_scopes(text)
     weights = [(k, k, ci, co) for kind, k, _, ci, co in
