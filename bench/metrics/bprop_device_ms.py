@@ -1,5 +1,5 @@
 """Device time per training step of the model's backward pass: the leaf
-operations the compiled step names as a Table-2 layer or the ``loss`` in
+operations the compiled step names as a layer of the model or the ``loss`` in
 the backward direction (``transpose(jvp(<layer>))`` or ``<layer>/bwd``,
 ``bench/scopes.py``), averaged over chips."""
 

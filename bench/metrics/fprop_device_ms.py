@@ -1,6 +1,6 @@
 """Device time per training step of the model's forward pass: the leaf
-operations the compiled step names as a Table-2 layer (``conv0`` ...
-``fc7``) or the ``loss``, in the forward direction (``models/cnn.py``'s
+operations the compiled step names as a layer of the model (``conv0``,
+``pool1``, ...) or the ``loss``, in the forward direction (the model's
 named scopes, ``bench/scopes.py``), averaged over chips."""
 
 import scopes

@@ -14,9 +14,9 @@ compares, against the float32 reference:
   (``bfloat16`` throughout, or ``int8`` products), put in the program's
   place;
 * ``fault:<name>``: the timed path with a fault planted under it
-  (``unchanged``, ``half_batch``, ``altered_loss``), or ``no_exchange``:
-  the reference with the exchange between workers left out, in the
-  program's place.
+  (``unchanged``, ``half_batch``, ``altered_loss``, ``no_exchange``);
+* ``reference:no_exchange``: the reference with the exchange between
+  workers left out, in the program's place.
 
 One JSON line per reading on standard output.
 """
@@ -32,26 +32,23 @@ import run
 
 def one(cell: dict, seed: int, what: str) -> dict:
     import check
-    import traffic
 
-    images, labels = traffic.render(cell["cfg"]["train_images"], seed)
+    data = cell["family"].inputs(cell["cfg"], seed)
     t = time.perf_counter()
-    if what == "program" or what in ("fault:unchanged", "fault:half_batch",
-                                     "fault:altered_loss"):
+    if what == "program" or what.startswith("fault:"):
         fault = None if what == "program" else what.split(":", 1)[1]
-        job = run.Job(cell, seed, images, labels, fault=fault)
+        job = run.Job(cell, seed, data, fault=fault)
         got = run.first_superstep(job)
         job.close()
         del job
     elif what.startswith("control:"):
-        got = run.reference_readings(cell, seed, images, labels,
+        got = run.reference_readings(cell, seed, data,
                                      precision=what.split(":", 1)[1])
-    elif what == "fault:no_exchange":
-        got = run.reference_readings(cell, seed, images, labels,
-                                     exchange=False)
+    elif what == "reference:no_exchange":
+        got = run.reference_readings(cell, seed, data, exchange=False)
     else:
         raise ValueError(f"unknown reading {what!r}")
-    ref = run.reference_readings(cell, seed, images, labels)
+    ref = run.reference_readings(cell, seed, data)
     values = check.readings(got, ref)
     return {"workload": cell["name"], "seed": seed, "what": what,
             "values": {k: v for k, (v, _) in values.items()},

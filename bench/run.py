@@ -4,24 +4,29 @@
     python3 bench/run.py --workload large-chaos-xla-1chip --seed 7 \
         --seconds 10 --trace 0
 
-A cell is a configuration (``bench/configs/<name>.json``, the sizes of a
-Table-2 CNN) under a traffic mix (``bench/traffic/<name>.json``, the
-training job).  The run drives the program's worker route as
-``launch/train.py::_train`` builds it, from the same objects:
-``make_optimizer``, ``init_worker_state`` and ``make_worker_superstep``
-over ``make_host_mesh``, fed by ``PrefetchFeed`` with
-``put_worker_sharded`` over an ``ImagePipeline`` in queue mode, one host
-sync per superstep on the loss vector.
+A cell is a configuration (``bench/configs/<name>.json``: a model's sizes
+and the name of its family) under a traffic mix
+(``bench/traffic/<name>.json``, the training job).  Everything that
+depends on the kind of model lives in the family's module
+(``bench/families/<family>.py``, whose docstring lists its functions): the
+program's model configuration, the inputs made from the seed, the pipeline
+the feed draws from, and the counts of operations and bytes.  The run
+drives the program's worker route as ``launch/train.py::_train`` builds
+it, from the same objects: ``make_optimizer``, ``init_worker_state`` and
+``make_worker_superstep`` over ``make_host_mesh``, fed by ``PrefetchFeed``
+with ``put_worker_sharded`` over the family's pipeline, one host sync per
+superstep on the loss vector.
 
 Set-up (``setup_s``, from the start of the process): imports, the chips,
-the images rendered from the seed (``bench/traffic.py``), the weights made
-on the chips from the seed, the first superstep (which compiles or loads
-the compiled step from the persistent cache), and warm-up supersteps until
-two in a row agree.  Then the window: supersteps until ``--seconds`` have
-passed.  With ``--trace 1`` the window is a profiler-traced one of at
-least ``TRACE_SECONDS`` and ``TRACE_SUPERSTEPS``, and the line carries the
-per-layer metrics (``bench/metrics/<name>.py``) instead of the end-to-end
-ones.
+the inputs made from the seed, the weights made on the chips from the
+seed, the first superstep (which compiles or loads the compiled step from
+the persistent cache), and warm-up supersteps until two in a row agree.
+Then the window: supersteps until ``--seconds`` have passed.  With
+``--trace 1`` the window is a profiler-traced one of at least
+``TRACE_SECONDS`` and ``TRACE_SUPERSTEPS``, and the line carries the
+per-layer metrics (``bench/metrics/<name>.py``, each handed the reduced
+trace, the compiled step's scopes and the family module) instead of the
+end-to-end ones.
 
 ``correct`` compares the first superstep of the timed path with the plain
 reference (``bench/reference/``), after the window, by ``bench/check.py``
@@ -82,11 +87,13 @@ def load_cell(name: str) -> dict:
         raise Failure(f"no workload {name!r} in BENCHMARK.json")
     cell = cells[name]
     conf = {c["name"]: c for c in spec["configs"]}[cell["config"]]
+    cfg = load_json(os.path.join(ROOT, conf["file"]))
     applies = lambda m: name in m.get("workloads", [name])
     return {
         "name": name,
         "chips": cell["chips"],
-        "cfg": load_json(os.path.join(ROOT, conf["file"])),
+        "cfg": cfg,
+        "family": load_family(cfg),
         "traffic": traffic_mod.load(cell["traffic"]),
         "limits": load_json(os.path.join(BENCH, "checks", f"{name}.json")),
         "end_to_end": [m for m in spec["end_to_end"] if applies(m)],
@@ -94,22 +101,33 @@ def load_cell(name: str) -> dict:
     }
 
 
-def load_reader(metric: str):
-    path = os.path.join(BENCH, "metrics", f"{metric}.py")
+def _load_module(kind: str, name: str):
+    """The module ``bench/<kind>/<name>.py``."""
+    path = os.path.join(BENCH, kind, f"{name}.py")
     mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
-
-
-def load_reference(name: str):
-    path = os.path.join(BENCH, "reference", f"{name}.py")
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{name}", path)
+        f"bench_{kind}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(metric: str):
+    return _load_module("metrics", metric).read
+
+
+def load_reference(name: str):
+    return _load_module("reference", name)
+
+
+def load_family(cfg: dict):
+    """The module of the family the configuration file names."""
+    name = cfg.get("family")
+    if not name:
+        raise Failure(f"configuration {cfg.get('name')!r} names no family")
+    if not os.path.exists(os.path.join(BENCH, "families", f"{name}.py")):
+        raise Failure(f"configuration {cfg.get('name')!r}: no module for "
+                      f"family {name!r} in bench/families")
+    return _load_module("families", name)
 
 
 def prepare_env() -> None:
@@ -144,19 +162,15 @@ class Job:
     tests and the control readings, never in a benchmark run):
     ``"unchanged"`` returns the state it was given, ``"half_batch"``
     feeds the first half of every batch twice, ``"altered_loss"`` reports
-    the first step's loss 1% high."""
+    the first step's loss 1% high, ``"no_exchange"`` leaves the exchange
+    between workers out of the state (its stale terms stay zero)."""
 
-    def __init__(self, cell: dict, seed: int, images, labels,
+    def __init__(self, cell: dict, seed: int, data,
                  fault: str | None = None):
-        import dataclasses
-
         import jax
 
-        import repro.configs as C
-        import traffic as traffic_mod
         from repro.core.chaos import SyncConfig
         from repro.core.types import WorkerConfig
-        from repro.data.pipeline import ImagePipeline
         from repro.launch.mesh import make_host_mesh
         from repro.launch.train import (PrefetchFeed, put_worker_sharded,
                                         superstep_schedule)
@@ -164,17 +178,14 @@ class Job:
                                       make_worker_superstep)
         from repro.train.sync import get_strategy
 
-        cfg, tr = cell["cfg"], cell["traffic"]
-        arch = C.get(cfg["arch"])
-        layers = [list(l) for l in arch.cnn_layers]
-        if layers != cfg["layers"] or arch.n_classes != cfg["classes"]:
-            raise Failure(f"the program's {cfg['arch']} ({layers}) is not "
-                          f"the configuration's ({cfg['layers']})")
-        if tr["use_kernel"]:
-            arch = dataclasses.replace(arch, use_kernel=True)
+        cfg, tr, family = cell["cfg"], cell["traffic"], cell["family"]
+        try:
+            arch = family.arch(cfg, tr)
+        except ValueError as e:
+            raise Failure(str(e)) from None
         self.k, self.batch = tr["superstep"], tr["batch"]
         optimizer = make_optimizer(
-            arch, total_steps=traffic_mod.total_steps(cfg, tr))
+            arch, total_steps=family.total_steps(cfg, tr))
         worker = WorkerConfig(workers=tr["workers"],
                               logical_shards=tr["logical_shards"])
         worker.validate_batch(self.batch)
@@ -186,11 +197,10 @@ class Job:
         self.stacked = get_strategy(sync).stacked_state
         self.state = init_worker_state(arch, jax.random.key(seed), sync,
                                        worker, optimizer)
-        pipe = ImagePipeline(images, labels, batch=self.batch, seed=seed,
-                             sample_mode="queue")
+        pipe = family.pipeline(cfg, tr, seed, data)
         if fault == "half_batch":
             pipe = _HalfBatch(pipe)
-        if fault in ("unchanged", "altered_loss"):
+        if fault in ("unchanged", "altered_loss", "no_exchange"):
             self.super_fn = _planted(self.super_fn, fault)
         elif fault not in (None, "half_batch"):
             raise ValueError(f"unknown fault {fault!r}")
@@ -257,6 +267,10 @@ def _planted(super_fn, fault: str):
             _, metrics = super_fn(state, batch)
             return kept, metrics
         new_state, metrics = super_fn(state, batch)
+        if fault == "no_exchange":
+            sync = new_state["sync"]
+            hist = jax.tree.map(jnp.zeros_like, sync["hist"])
+            return {**new_state, "sync": {**sync, "hist": hist}}, metrics
         loss = metrics["loss"]
         return new_state, {**metrics, "loss": loss.at[0].mul(1.01)}
     return fn
@@ -271,13 +285,20 @@ def first_superstep(job: Job) -> dict:
             "params": after["params"], "stale": after["stale"]}
 
 
-def reference_readings(cell: dict, seed: int, images, labels,
+def reference_readings(cell: dict, seed: int, data,
                        precision: str = "float32",
                        exchange: bool = True) -> dict:
+    """The reference's first superstep on the family's inputs ``data``."""
     ref = load_reference(cell["cfg"]["reference"])
-    return ref.follow(cell["cfg"], cell["traffic"], seed, images, labels,
+    return ref.follow(cell["cfg"], cell["traffic"], seed, *data,
                       cell["traffic"]["superstep"], precision=precision,
                       exchange=exchange)
+
+
+def compiled_hlo(job: Job) -> str:
+    """The HLO text of the step the job's supersteps run, which names every
+    operation the device trace shows."""
+    return job.super_fn.lower(job.state, job.last_batch).compile().as_text()
 
 
 def count_compiles():
@@ -299,10 +320,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     what goes to standard error)."""
     import jax
 
-    import counts
     import devtrace
     import peaks as peaks_mod
-    import traffic as traffic_mod
+    import scopes as scopes_mod
 
     parts = {"import": time.perf_counter() - T_START}
     t = time.perf_counter()
@@ -311,12 +331,13 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
             else jax.devices()[:cell["chips"]])
     parts["device_init"] = time.perf_counter() - t
 
+    family = cell["family"]
     t = time.perf_counter()
-    images, labels = traffic_mod.render(cell["cfg"]["train_images"], seed)
-    parts["render"] = time.perf_counter() - t
+    data = family.inputs(cell["cfg"], seed)
+    parts["inputs"] = time.perf_counter() - t
 
     t = time.perf_counter()
-    job = Job(cell, seed, images, labels, fault=fault)
+    job = Job(cell, seed, data, fault=fault)
     jax.block_until_ready(job.state)
     parts["init"] = time.perf_counter() - t
 
@@ -370,9 +391,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     mesh_devs = list(job.mesh.devices.flat)
     stats = [d.memory_stats() or {} for d in mesh_devs]
     memory_peak = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
-    # the compiled step's HLO names every operation the trace shows
-    hlo = (job.super_fn.lower(job.state, job.last_batch).compile().as_text()
-           if trace else "")
+    hlo = compiled_hlo(job) if trace else ""
     job.close()
     del job
 
@@ -381,16 +400,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         xplanes = [os.path.join(dp, f) for dp, _, fs in os.walk(trace_dir)
                    for f in fs if f.endswith(".xplane.pb")]
         if xplanes:
-            weights = [(l["k"], l["k"], l["c_in"], l["c_out"])
-                       for l in counts.layer_shapes(cell["cfg"])
-                       if l["kind"] == "conv"]
+            weights = family.conv_weight_shapes(cell["cfg"])
             reduced = devtrace.Reduced(
                 devtrace.load(xplanes[0]),
                 classes=devtrace.hlo_classes(hlo, weights))
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     t = time.perf_counter()
-    ref = reference_readings(cell, seed, images, labels)
+    ref = reference_readings(cell, seed, data)
     import check
     correct, checks = check.judge(check.readings(prog, ref), cell["limits"])
     reference_s = time.perf_counter() - t
@@ -423,9 +440,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         ctx = _Ctx(reduced=reduced, steps=steps, images=images_done,
                    window_s=window_s, feed_wait_s=feed_waits,
                    cfg=cell["cfg"], traffic=cell["traffic"],
-                   chips=len(mesh_devs), peaks=pk,
-                   flops_per_image=counts.train_flops_per_image(
-                       cell["cfg"]))
+                   chips=len(mesh_devs), peaks=pk, family=family,
+                   scopes=scopes_mod.of_hlo(hlo))
         for m in cell["per_layer"]:
             v = load_reader(m["name"])(ctx)
             if v is not None:
@@ -435,6 +451,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
             device["window_s"] = reduced.window_ns * 1e-9
             out["breakdown"] = {"device_ops": reduced.top_ops(),
                                 "idle_gaps": reduced.idle_gaps()}
+            log["device_scopes"] = scopes_mod.breakdown(ctx)
         out["traced_images_per_s"] = images_per_s
     out["checks"] = checks
     out["_log"] = log
@@ -442,7 +459,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
 
 
 class _Ctx:
-    """What a per-layer metric reader may read."""
+    """What a per-layer metric reader may read: the reduced trace
+    (``reduced``) and the compiled step's ``scopes`` (or None), the
+    window's ``steps``, ``images``, ``window_s`` and ``feed_wait_s``, the
+    cell's ``cfg``, ``traffic`` and ``family`` module, the ``chips`` and
+    their ``peaks``."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)

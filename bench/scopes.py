@@ -1,69 +1,50 @@
 """Device time by the program's named scopes.
 
 The compiled step names its work (``src/repro/obs/trace.py``): each
-Table-2 layer forward and backward (``conv2``/``fwd``, ``conv2``/``bwd``),
-the ``loss``, the ``update`` and each bucket's ``exchange/<bucket>``.
-``scopes_of`` maps every operation of the step's HLO to its
-``(scope, direction)`` with the program's own parser; the functions below
-sum the reduced trace's leaf operations by scope, as
-``devtrace.Reduced.class_ns`` sums them by class: the union of their
-intervals per chip, averaged over chips.
-
-The reader context a traced run builds holds the reduced trace but not the
-compiled step's HLO text.  Where it has no ``scopes``, ``scopes_of``
-compiles the cell's step again as ``run.Job`` builds it and ``run.run``
-lowers it (the persistent compile cache makes that a load) and keeps the
-result on the context.  A program without the naming contract, or a trace
-whose operations the compiled step does not name, gives None.
+layer of the model forward and backward (``conv2``/``fwd``,
+``conv2``/``bwd``), the ``loss``, the ``update`` and each bucket's
+``exchange/<bucket>``.  ``of_hlo`` maps every operation of the step's HLO
+text, which a traced run compiles for the trace's classes, to its
+``(scope, direction)`` with the program's own parser; the run hands the
+map to the readers as ``ctx.scopes``.  The functions below sum the reduced
+trace's leaf operations by scope, as ``devtrace.Reduced.class_ns`` sums
+them by class: the union of their intervals per chip, averaged over
+chips.  A program without the naming contract, or a trace whose operations
+the compiled step does not name, gives None.
 """
 from __future__ import annotations
 
-import re
-
 import devtrace
 
-#: the scopes that make up a forward or backward pass through the model
-MODEL = re.compile(r"(?:conv|pool|fc)\d+|loss")
 #: at most this share of leaf time may fall on operations the HLO lacks
 UNKNOWN_SHARE = 0.01
 
 
-def model_pass(direction: str):
-    """Scopes of the model's layers and loss in one direction."""
-    return lambda s: (s is not None and s[1] == direction
-                      and MODEL.fullmatch(s[0]) is not None)
+def of_hlo(hlo_text: str):
+    """{operation name: (scope, direction) or None} of a compiled step's
+    HLO text, or None where there is none or the program names no
+    scopes."""
+    try:
+        from repro.obs.trace import hlo_scopes
+    except ImportError:
+        return None
+    return hlo_scopes(hlo_text) if hlo_text else None
+
+
+def exchange(s) -> bool:
+    """Each bucket's exchange between workers."""
+    return s is not None and s[0].startswith("exchange/")
 
 
 def update(s) -> bool:
     """What the sync strategy does once the gradients exist."""
-    return s is not None and (s[0] == "update"
-                              or s[0].startswith("exchange/"))
+    return s is not None and (s[0] == "update" or exchange(s))
 
 
-def _compiled_hlo(cfg: dict, traffic: dict) -> str:
-    import run
-    import traffic as traffic_mod
-
-    images, labels = traffic_mod.render(traffic["batch"], 0)
-    job = run.Job({"cfg": cfg, "traffic": traffic}, 0, images, labels)
-    try:
-        job.superstep()
-        return job.super_fn.lower(job.state,
-                                  job.last_batch).compile().as_text()
-    finally:
-        job.close()
-
-
-def scopes_of(ctx):
-    """{operation name: (scope, direction) or None} of the cell's compiled
-    step, or None where the program names no scopes."""
-    if getattr(ctx, "scopes", None) is None:
-        try:
-            from repro.obs.trace import hlo_scopes
-        except ImportError:
-            return None
-        ctx.scopes = hlo_scopes(_compiled_hlo(ctx.cfg, ctx.traffic))
-    return ctx.scopes
+def model_pass(direction: str):
+    """Scopes of the model's layers and loss in one direction: every scope
+    but the strategy's."""
+    return lambda s: s is not None and s[1] == direction and not update(s)
 
 
 def _leaves(ctx):
@@ -71,7 +52,7 @@ def _leaves(ctx):
     r = getattr(ctx, "reduced", None)
     if r is None or not r.chips or ctx.steps <= 0:
         return None
-    scopes = scopes_of(ctx)
+    scopes = ctx.scopes
     if scopes is None:
         return None
     total = unknown = 0.0

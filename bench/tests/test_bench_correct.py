@@ -27,8 +27,7 @@ def cell():
 
 @pytest.fixture(scope="module")
 def data(cell):
-    import traffic
-    return traffic.render(cell["cfg"]["train_images"], SEED)
+    return cell["family"].inputs(cell["cfg"], SEED)
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -60,10 +59,8 @@ def test_unchanged_state_reads_one(cell):
 
 
 def test_control_is_not_correct(cell, data):
-    images, labels = data
-    ref = run.reference_readings(cell, SEED, images, labels)
-    control = run.reference_readings(cell, SEED, images, labels,
-                                     precision="bfloat16")
+    ref = run.reference_readings(cell, SEED, data)
+    control = run.reference_readings(cell, SEED, data, precision="bfloat16")
     ok, checks = check.judge(check.readings(control, ref), cell["limits"])
     assert not ok, checks
 
@@ -72,10 +69,8 @@ def test_exchange_left_out_reads_one(data):
     """Four workers with the exchange between them left out: the stale
     term that only the exchange fills reads 1."""
     cell = _small("large-chaos-xla-1chip", workers=4)
-    images, labels = data
-    ref = run.reference_readings(cell, SEED, images, labels)
-    alone = run.reference_readings(cell, SEED, images, labels,
-                                   exchange=False)
+    ref = run.reference_readings(cell, SEED, data)
+    alone = run.reference_readings(cell, SEED, data, exchange=False)
     values = check.readings(alone, ref)
     assert values["stale_gap"][0] == pytest.approx(1.0)
     assert values["change_gap"][0] > cell["limits"]["change_gap"]

@@ -37,6 +37,7 @@ def test_config_file_loads(conf):
         assert key in cfg and key in cfg["published"]
     assert os.path.exists(os.path.join(
         run.BENCH, "reference", f"{cfg['reference']}.py"))
+    assert callable(run.load_family(cfg).inputs)
 
 
 @pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda c: c["name"])
@@ -57,9 +58,18 @@ def test_metric_reader_loads_and_reads_nothing_from_nothing(metric):
     read = run.load_reader(metric["name"])
     empty = run._Ctx(reduced=None, steps=0, images=0, window_s=0.0,
                      feed_wait_s=[], cfg={}, traffic={}, chips=1,
-                     peaks={}, flops_per_image=0)
+                     peaks={}, family=None, scopes=None)
     assert read(empty) is None
     assert metric["moves"] in {m["name"] for m in SPEC["end_to_end"]}
+
+
+@pytest.mark.parametrize("cfg,named", [
+    ({"name": "x"}, "names no family"),
+    ({"name": "x", "family": "no_such_family"}, "'no_such_family'"),
+])
+def test_unknown_family_is_a_failure(cfg, named):
+    with pytest.raises(run.Failure, match=named):
+        run.load_family(cfg)
 
 
 def test_every_traffic_file_loads():
@@ -68,10 +78,15 @@ def test_every_traffic_file_loads():
 
 
 def test_render_is_a_function_of_the_seed():
-    a = traffic.render(8, 2**31 + 5)
-    b = traffic.render(8, 2**31 + 5)
-    c = traffic.render(8, 6)
+    cfg = run.load_json(os.path.join(run.BENCH, "configs",
+                                     "chaos-large.json"))
+    family = run.load_family(cfg)
+    a = family.render(8, 2**31 + 5)
+    b = family.render(8, 2**31 + 5)
+    c = family.render(8, 6)
     assert all((x == y).all() for x, y in zip(a, b))
     assert not (a[0] == c[0]).all()
     assert a[0].shape == (8, 29, 29, 1) and a[1].dtype.name == "int32"
+    d = family.inputs(dict(cfg, train_images=8), 2**31 + 5)
+    assert all((x == y).all() for x, y in zip(a, d))
     assert json.dumps(SPEC)
