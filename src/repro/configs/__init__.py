@@ -25,6 +25,8 @@ _ARCH_MODULES = [
     "chaos_small",
     "chaos_medium",
     "chaos_large",
+    # ImageNet-size image backbone
+    "convnext_tiny",
     # transformer-scale CHAOS bench net (DESIGN.md §10)
     "lm_bench",
 ]

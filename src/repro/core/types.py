@@ -18,7 +18,7 @@ def _round_up(x: int, m: int) -> int:
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str  # dense | mla | moe | hybrid | ssm | encdec | vlm | cnn
+    family: str  # dense | mla | moe | hybrid | ssm | encdec | vlm | cnn | convnext
 
     # transformer backbone
     n_layers: int = 0
@@ -61,8 +61,12 @@ class ArchConfig:
     # CNN (paper Table 2): tuples of layer specs
     # conv: ("conv", maps, kernel) / pool: ("pool", kernel) / fc: ("fc", n)
     cnn_layers: Tuple[tuple, ...] = ()
-    cnn_input: Tuple[int, int] = (29, 29)
+    cnn_input: Tuple[int, int] = (29, 29)   # input H, W (cnn, convnext)
     n_classes: int = 10
+
+    # ConvNeXt (Liu et al. 2022): channels and blocks of each stage
+    convnext_dims: Tuple[int, ...] = ()
+    convnext_depths: Tuple[int, ...] = ()
 
     # training knobs
     use_kernel: bool = False     # route hot path through Pallas kernels
@@ -90,13 +94,16 @@ class ArchConfig:
     @property
     def has_decoder(self) -> bool:
         """Whether the arch supports autoregressive decode shapes."""
-        return self.family != "cnn"
+        return self.family not in ("cnn", "convnext")
 
     def param_count(self) -> int:
         """Analytic parameter count (for 6ND model-FLOPs and sanity checks)."""
         if self.family == "cnn":
             from repro.models import cnn  # local import to avoid cycle
             return cnn.param_count(self)
+        if self.family == "convnext":
+            from repro.models import convnext
+            return convnext.param_count(self)
         d, L, ff, V = self.d_model, self.n_layers, self.d_ff, self.padded_vocab
         dh = self.d_head
         n = V * d  # embed

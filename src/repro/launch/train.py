@@ -12,10 +12,11 @@ Features (framework-scale runtime, DESIGN.md §3):
   - on-device prefetch: a double-buffered background feed builds the NEXT
     superstep's stacked (K, B, ...) batch and ships it to the device while
     the current superstep computes;
-  - data routing by family: CNN archs (the paper's Table-2 nets) feed from
-    ``ImagePipeline`` in the paper's shared-queue mode (each batch lane
-    takes every B-th sample of a per-epoch permutation — no static split),
-    token archs from ``TokenPipeline``;
+  - data routing by family: image archs (the paper's Table-2 nets,
+    ConvNeXt on uint8 RGB images) feed from ``ImagePipeline`` in the
+    paper's shared-queue mode (each batch lane takes every B-th sample of
+    a per-epoch permutation — no static split), token archs from
+    ``TokenPipeline``;
   - checkpoint/restart: atomic keep-N checkpoints, auto-resume from latest,
     deterministic data pipeline keyed by step (resume == replay, any K);
   - pluggable sync strategies (train/sync.py registry: bsp | chaos |
@@ -70,6 +71,8 @@ from repro.train.sync import get_strategy, sync_modes
 
 #: synthetic-MNIST pool size for CNN runs (offline container, DESIGN.md §6)
 CNN_DATASET_SIZE = 4096
+#: synthetic uint8 image pool size for ConvNeXt runs (308 MB at 224x224)
+IMAGE_DATASET_SIZE = 2048
 
 
 class StragglerWatchdog:
@@ -145,11 +148,19 @@ class StragglerWatchdog:
 
 
 def make_pipeline(cfg, batch: int, seq: int, seed: int = 0):
-    """Data pipeline for the arch family: CNN -> ImagePipeline with the
-    paper's shared-queue worker semantics; everything else -> TokenPipeline."""
+    """Data pipeline for the arch family: the image families (CNN on
+    synthetic digits, ConvNeXt on synthetic uint8 RGB images) ->
+    ImagePipeline with the paper's shared-queue worker semantics;
+    everything else -> TokenPipeline."""
     if cfg.family == "cnn":
         from repro.data.mnist import make_dataset
         imgs, labels = make_dataset(CNN_DATASET_SIZE, seed=seed)
+        return ImagePipeline(imgs, labels, batch=batch, seed=seed,
+                             sample_mode="queue")
+    if cfg.family == "convnext":
+        from repro.data.images import make_images
+        imgs, labels = make_images(IMAGE_DATASET_SIZE, cfg.cnn_input[0],
+                                   cfg.n_classes, seed=seed)
         return ImagePipeline(imgs, labels, batch=batch, seed=seed,
                              sample_mode="queue")
     return TokenPipeline(cfg.vocab_size, batch, seq, seed=seed)

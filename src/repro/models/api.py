@@ -78,6 +78,9 @@ def _mod(cfg: ArchConfig):
     if cfg.family == "cnn":
         from repro.models import cnn
         return cnn
+    if cfg.family == "convnext":
+        from repro.models import convnext
+        return convnext
     raise ValueError(cfg.family)
 
 

@@ -25,6 +25,12 @@ tids per thread name (``feed``, ``slot0..S``).
 ``{kind}{i}``           a Table-2 layer (``conv0``, ``pool1``, ... ``fc7``,
                         the names ``bucket_spec`` uses); forward under
                         autodiff reads ``jvp(conv2)/...``
+``stem``, ``down{i}``,  a ConvNeXt layer (``models/convnext.py``, the names
+``s{i}b{j}``, ``head``  ``bucket_spec`` uses): the stem, the downsample
+                        before stage i, block j of stage i, the head
+``s{i}b{j}/{sub}``      a ConvNeXt block's sublayer ``dwconv``, ``norm``
+                        or ``mlp`` (``jvp(s1b2)/dwconv/...``); what a
+                        block runs outside them is the block's own
 backward of a layer     ``transpose(jvp(conv2))/...`` (autodiff, custom
                         VJPs included) or ``conv2/bwd/...`` (the explicit
                         saved-activation tape)
@@ -50,7 +56,9 @@ from typing import Optional
 
 from jax.profiler import TraceAnnotation
 
-_LAYER = re.compile(r"(?:conv|pool|fc)\d+")
+_LAYER = re.compile(r"(?:conv|pool|fc|down)\d+|stem|head")
+_BLOCK = re.compile(r"s\d+b\d+")
+_SUBLAYERS = ("dwconv", "norm", "mlp")
 _WRAPPED = re.compile(r"(\w+)\((.*)\)")
 
 
@@ -70,8 +78,9 @@ def _unwrap(part: str) -> tuple[str, bool]:
 def scope_of(op_name: str) -> Optional[tuple[str, str]]:
     """``(scope, "fwd" | "bwd")`` of an HLO ``op_name``, or None when it
     carries no scope of the contract above.  The innermost scope wins
-    (``update/exchange/conv2/...`` is ``exchange/conv2``); of a fused
-    ``a;b`` name, the first part that has one."""
+    (``update/exchange/conv2/...`` is ``exchange/conv2``, and
+    ``s1b2/dwconv/...`` is ``s1b2/dwconv``); of a fused ``a;b`` name, the
+    first part that has one."""
     for name in op_name.split(";"):
         parts = [_unwrap(p) for p in name.split("/")]
         found, transposed, i = None, False, 0
@@ -82,7 +91,11 @@ def scope_of(op_name: str) -> Optional[tuple[str, str]]:
             if part == "exchange" and nxt is not None:
                 found = (f"exchange/{nxt}", transposed)
                 i += 1
-            elif _LAYER.fullmatch(part) or part in ("loss", "update"):
+            elif _BLOCK.fullmatch(part) and nxt in _SUBLAYERS:
+                found = (f"{part}/{nxt}", transposed or parts[i + 1][1])
+                i += 1
+            elif (_LAYER.fullmatch(part) or _BLOCK.fullmatch(part)
+                  or part in ("loss", "update")):
                 found = (part, transposed or nxt == "bwd")
             i += 1
         if found is not None:

@@ -1,9 +1,10 @@
 """The compiled step's named scopes (``obs/trace.py``'s naming contract):
 ``scope_of`` on op_names, the fusion rule of ``hlo_scopes``, every
 Table-2 layer's forward and backward named in the compiled worker
-superstep on both paths, each bucket's exchange named on the layerwise
-worker mesh, kernel names on every ``pallas_call``, and no host callback
-in a compiled step unless latency is injected."""
+superstep on both paths, every ConvNeXt layer and block sublayer both
+ways, each bucket's exchange named on the layerwise worker mesh, kernel
+names on every ``pallas_call``, and no host callback in a compiled step
+unless latency is injected."""
 import dataclasses
 import json
 import os
@@ -50,6 +51,22 @@ CALLBACK = re.compile(r'custom_call_target="[^"]*callback[^"]*"')
     ("jit(s)/while/body/dynamic_update_slice", None),
     ("reduce_sum", None),
     ("jit(conv2d_bias_tanh)/conv2d_fwd_tanh/pallas_call", None),
+    # ConvNeXt: layers, and the block sublayers the readers select
+    ("jit(s)/while/body/closed_call/jvp(s1b2)/dwconv/conv_general_dilated",
+     ("s1b2/dwconv", "fwd")),
+    ("jit(s)/while/body/transpose(jvp(s1b2))/dwconv/conv_general_dilated",
+     ("s1b2/dwconv", "bwd")),
+    ("jit(s)/jvp(s0b0)/mlp/dot_general", ("s0b0/mlp", "fwd")),
+    ("transpose(jvp(s0b0))/mlp/dot_general", ("s0b0/mlp", "bwd")),
+    ("jit(s)/jvp(s2b8)/norm/rsqrt", ("s2b8/norm", "fwd")),
+    ("jit(s)/jvp(s2b8)/add", ("s2b8", "fwd")),
+    ("transpose(jvp(s3b2))/mul", ("s3b2", "bwd")),
+    ("jit(s)/jvp(s0b0)/dwconvs/add", ("s0b0", "fwd")),
+    ("jit(s)/jvp(stem)/conv_general_dilated", ("stem", "fwd")),
+    ("jit(s)/transpose(jvp(down2))/conv_general_dilated", ("down2", "bwd")),
+    ("jit(s)/jvp(head)/dot_general", ("head", "fwd")),
+    ("jit(s)/update/exchange/s1b2/reduce_sum", ("exchange/s1b2", "fwd")),
+    ("jit(s)/while/body/norm/add", None),
 ])
 def test_scope_of(op_name, want):
     assert scope_of(op_name) == want
@@ -149,6 +166,38 @@ def test_worker_superstep_scopes(arch, use_kernel):
     finally:
         set_tracer(prev)
     _check_layers(cfg, hlo)
+    assert not CALLBACK.search(hlo)
+
+
+def test_convnext_worker_superstep_scopes():
+    """ConvNeXt's compiled worker superstep (chaos τ=1, one worker, two
+    micro-shards, the smoke config), built with a tracer installed: every
+    layer and every block's sublayers appear both ways, each bucket is a
+    layer, and no host callback is in the program."""
+    from repro.models import convnext
+    cfg = C.smoke("convnext-tiny")
+    worker = WorkerConfig(workers=1, logical_shards=2)
+    sync = SyncConfig(mode="chaos", axis_name=worker.axis, staleness=1)
+    opt = make_optimizer(cfg, total_steps=8)
+    prev = set_tracer(Tracer())
+    try:
+        fn = make_worker_superstep(cfg, sync, worker, make_host_mesh(1), opt)
+        state = jax.eval_shape(lambda: init_worker_state(
+            cfg, jax.random.key(0), sync, worker, opt))
+        batch = {"images": jax.ShapeDtypeStruct((1, 4, 64, 64, 3),
+                                                jnp.uint8),
+                 "labels": jax.ShapeDtypeStruct((1, 4), jnp.int32)}
+        hlo = fn.lower(state, batch).compile().as_text()
+    finally:
+        set_tracer(prev)
+    seen = set(hlo_scopes(hlo).values())
+    for bucket in convnext.bucket_spec(cfg):
+        subs = (["dwconv", "norm", "mlp"]
+                if re.fullmatch(r"s\d+b\d+", bucket.name) else [])
+        for name in [bucket.name] + [f"{bucket.name}/{s}" for s in subs]:
+            for direction in ("fwd", "bwd"):
+                assert (name, direction) in seen, (name, direction)
+    assert ("loss", "fwd") in seen and ("update", "fwd") in seen
     assert not CALLBACK.search(hlo)
 
 

@@ -6,7 +6,8 @@ multiples, more VMEM than a kernel may use, layouts Mosaic cannot lower.
 These tests compile each kernel of ``chaos-large``'s training step (and the
 ``lm-bench`` flash forward) for a v5e that is described, not attached, with
 the block configs the heuristic picks, and check that the compiled program
-holds the kernel (``tpu_custom_call``) under its ``pallas_call`` name.
+holds the kernel (``tpu_custom_call``) under its ``pallas_call`` name; and
+which operations of ConvNeXt's step the chip runs as convolutions.
 Nothing runs.
 
 The topology is described inside a fixture: only the process that runs
@@ -171,9 +172,10 @@ def test_flash_train_fwd_compiles(one_chip, compiled_kernels):
                    count=cfg.n_heads // cfg.n_kv_heads)
 
 
-def test_worker_superstep_compiles(topo, compiled_kernels):
-    """The whole jitted chaos-large worker superstep (sync chaos, τ=1,
-    K=8, batch 256 over 8 logical shards, kernels on) for one chip."""
+def _worker_superstep(topo, cfg, images, dtype):
+    """The jitted chaos τ=1 worker superstep on one described chip (one
+    worker, 8 logical shards), compiled for a stacked batch of ``images``
+    (K, B, H, W, C)."""
     from repro.core.chaos import SyncConfig
     from repro.core.types import WorkerConfig
     from repro.train.step import (init_worker_state, make_optimizer,
@@ -182,7 +184,6 @@ def test_worker_superstep_compiles(topo, compiled_kernels):
 
     mesh = Mesh(np.array(topo.devices[:1]), ("workers",),
                 axis_types=(AxisType.Auto,))
-    cfg = dataclasses.replace(C.get("chaos-large"), use_kernel=True)
     worker = WorkerConfig(workers=1, logical_shards=8)
     sync = SyncConfig(mode="chaos", axis_name=worker.axis, staleness=1)
     opt = make_optimizer(cfg, total_steps=32)
@@ -201,10 +202,18 @@ def test_worker_superstep_compiles(topo, compiled_kernels):
                               == "replicated" else P(worker.axis))
                      for k, v in abstract["sync"].items()}
     data = NamedSharding(mesh, P(None, worker.axis))
-    batch = {"images": _shape(data, (8, 256, 29, 29, 1)),
-             "labels": _shape(data, (8, 256), jnp.int32)}
-    compiled = make_worker_superstep(cfg, sync, worker, mesh,
-                                     opt).lower(state, batch).compile()
+    batch = {"images": _shape(data, images, dtype),
+             "labels": _shape(data, images[:2], jnp.int32)}
+    return make_worker_superstep(cfg, sync, worker, mesh,
+                                 opt).lower(state, batch).compile()
+
+
+def test_worker_superstep_compiles(topo, compiled_kernels):
+    """The whole jitted chaos-large worker superstep (sync chaos, τ=1,
+    K=8, batch 256 over 8 logical shards, kernels on) for one chip."""
+    cfg = dataclasses.replace(C.get("chaos-large"), use_kernel=True)
+    compiled = _worker_superstep(topo, cfg, (8, 256, 29, 29, 1),
+                                 jnp.float32)
     text = compiled.as_text()
     # 8 forward + 7 backward kernels per step, inside one scan body
     assert text.count("tpu_custom_call") >= 15
@@ -226,3 +235,25 @@ def test_worker_superstep_compiles(topo, compiled_kernels):
         (f"conv{i}", d) for i in (0, 2, 4) for d in ("fwd", "bwd")}
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_convnext_conv_classes(topo):
+    """ConvNeXt's worker superstep (the smoke config, uint8 images) for
+    one chip: the operations the benchmark classes ``conv`` (convolutions
+    with a window) are the stem, the downsamples, the depthwise
+    convolutions and the pointwise products, which XLA writes on 4-D
+    activations as 1x1-window convolutions, each forward and backward;
+    not the head's product.  ``bench/families/convnext.py::conv_work``
+    counts exactly these."""
+    cfg = C.smoke("convnext-tiny")
+    text = _worker_superstep(topo, cfg, (2, 16, 64, 64, 3),
+                             jnp.uint8).as_text()
+    scopes = hlo_scopes(text)
+    convs = [n for n, c in devtrace.hlo_classes(text).items()
+             if c == "conv"]
+    assert convs and all(scopes[n] for n in convs)
+    got = {(s[0].split("/")[-1] if "/" in s[0] else s[0], s[1])
+           for s in (scopes[n] for n in convs)}
+    want = {(name, d) for name in ("stem", "down1", "down2", "down3",
+                                   "dwconv", "mlp") for d in ("fwd", "bwd")}
+    assert got == want
